@@ -1,8 +1,5 @@
 import os, sys, time
 sys.path.insert(0, "/root/repo")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from dragonboat_tpu._jaxenv import maybe_pin_cpu
-maybe_pin_cpu()
 import tempfile, shutil
 from bench import _bench_sm_class
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig

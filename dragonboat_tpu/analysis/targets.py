@@ -514,9 +514,6 @@ def _default_targets() -> Targets:
             "make_multi_step_fn",
             # the sharded twin: shard_map + jit factory (same contract)
             "make_sharded_multi_step_fn",
-            # host-side backend/env probe deciding Pallas ring vs XLA
-            # all-gather — runs at trace time, not inside the kernel
-            "_pallas_route_active",
         },
         traced_functions={(VECTOR, "_make_activate_fn.apply")},
         # `steps` is the super-step scan length: a compile-time constant

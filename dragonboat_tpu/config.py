@@ -126,12 +126,11 @@ class EngineConfig:
     # (jax.sharding.Mesh along the group axis). Groups are independent
     # Raft instances, so at steps_per_sync=1 the kernel partitions with
     # no cross-device collectives on the hot path. Composed with
-    # steps_per_sync>1 the inter-step router exchanges candidate
-    # messages across shards inside the launch (Pallas async remote DMA
-    # ring on TPU, XLA all-gather elsewhere; DBTPU_PALLAS_ROUTE=0 forces
-    # the collective) so co-hosted replicas on different chips still
-    # talk without the host. max_groups is rounded up to a device
-    # multiple; the round-up is stamped in step_stats
+    # steps_per_sync>1 the inter-step router all-gathers candidate
+    # messages across shards inside the launch so co-hosted replicas on
+    # different chips still talk without the host. Needs more than one
+    # visible device (raises otherwise). max_groups is rounded up to a
+    # device multiple; the round-up is stamped in step_stats
     # (padded_groups/mesh_devices) and ghost lanes are never allocated.
     shard_over_mesh: bool = False
     # Max Raft groups per NodeHost; the G dimension of the kernel tensors.
@@ -173,12 +172,12 @@ class EngineConfig:
     steps_per_sync: int = 1
     # Pipeline the engine loop: dispatch kernel step t, then decode step
     # t-1's output while the device computes. Removes the device wait from
-    # the loop's critical path (a ~2x step rate on accelerators, where the
-    # wait is real idle time; on the cpu backend the "wait" is the host
-    # computing the kernel, so there is nothing to reclaim and the extra
-    # step of latency only hurts). None = auto: on for accelerators, off
-    # for cpu. Costs one extra step of pack staleness, which the window
-    # throttle accounts for.
+    # the loop's critical path on accelerators, where the wait is real
+    # idle time (gain on the chip: not measured); on the cpu backend the
+    # "wait" is the host computing the kernel, so there is nothing to
+    # reclaim and the extra step of latency only hurts. None = auto: on
+    # for accelerators, off for cpu. Costs one extra step of pack
+    # staleness, which the window throttle accounts for.
     overlap_decode: "Optional[bool]" = None
     # Stage-profiler sampling for the vector engine hot loop: 0 = sparse
     # default (1 in 32 iterations — steady-state cost is two clock reads

@@ -51,10 +51,8 @@ never O(messages) Python):
             touched WALs (storage/logdb.save_raft_state_deferred +
             storage/kv.sync_all), so a step pays max(fsync) not sum.
 
-This is what closed the 340x kernel-vs-e2e gap of the scalar-dispatch
-host loop (BENCH_r05: 7.9M kernel proposals/s vs 23k e2e): the kernel
-advances all groups in one compiled step, and the host now fans its
-output out in whole-plane numpy instead of per-(group, peer) Python.
+The kernel advances all groups in one compiled step, and the host fans
+its output out in whole-plane numpy instead of per-(group, peer) Python.
 
 Payload bytes never touch the device: the kernel works on (index, term,
 is_cc) metadata while the engine keeps an arena of Entry objects keyed by
@@ -139,13 +137,13 @@ from .node import Node
 _plog = get_logger("vectorengine")
 
 # One sharded collective program in flight per process: the K>1 mesh
-# kernel contains cross-shard exchanges (all-gather / Pallas ring), and
-# concurrent launches from co-hosted engines interleave their rendezvous
-# on the shared per-device executors — the CPU backend stalls its
-# participant threads outright. Production runs one engine per host, so
-# serializing launches costs nothing there; multi-NodeHost-in-process
-# tests pay a fair round-robin. K=1 sharded and every unsharded path
-# have no collectives and never take this lock.
+# kernel contains a cross-shard all-gather, and concurrent launches from
+# co-hosted engines interleave their rendezvous on the shared per-device
+# executors — the CPU backend stalls its participant threads outright.
+# Production runs one engine per host, so serializing launches costs
+# nothing there; multi-NodeHost-in-process tests pay a fair round-robin.
+# K=1 sharded and every unsharded path have no collectives and never
+# take this lock.
 _MESH_LAUNCH_MU = threading.Lock()
 
 
@@ -1024,15 +1022,19 @@ class VectorEngine:
         self._mesh = None
         self._mesh_devices = 0  # 0 = unsharded single-device engine
         groups_requested = self.kcfg.groups
-        if (
-            ecfg is not None
-            and getattr(ecfg, "shard_over_mesh", False)
-            and jax.device_count() > 1
-        ):
+        if ecfg is not None and getattr(ecfg, "shard_over_mesh", False):
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
             devs = jax.devices()
             n = len(devs)
+            if n < 2:
+                # quietly running unsharded would let a one-device run
+                # pass for a mesh run
+                raise ValueError(
+                    "EngineConfig.shard_over_mesh=True needs more than one "
+                    f"visible jax device; found {n} "
+                    f"({devs[0].platform}:{devs[0].device_kind})"
+                )
             if self.kcfg.groups % n:
                 # round UP to a device multiple so every shard holds the
                 # same block. NOT silent: the shortfall is stamped in
@@ -1119,6 +1121,10 @@ class VectorEngine:
             # padded sharded run from an exact one
             "padded_groups": self._padded_groups,
             "mesh_devices": self._mesh_devices,
+            # exceptions the loop caught from _run_once and survived: a
+            # kernel the compiler refuses would otherwise show up only as
+            # proposal time-outs (chip_smoke.py asserts this stays zero)
+            "loop_exceptions": 0,
         }
         # ---- tick-fairness watchdog (ROADMAP seed flake) -----------------
         # Inter-iteration latency vs the host's tick period, a starvation
@@ -1170,9 +1176,9 @@ class VectorEngine:
         if self._multi > 1:
             if self._mesh is not None:
                 # K-step kernel over the mesh: cross-shard lane traffic
-                # moves device-to-device inside the launch (Pallas ring
-                # on TPU, all-gather elsewhere); the host path stays the
-                # fallback for lanes the route table marks -1
+                # moves device-to-device inside the launch (all-gather);
+                # the host path stays the fallback for lanes the route
+                # table marks -1
                 self._multi_fn = make_sharded_multi_step_fn(
                     self.kcfg, self._multi, self._mesh
                 )
@@ -1655,6 +1661,7 @@ class VectorEngine:
             except Exception:
                 import traceback
 
+                self._sstats["loop_exceptions"] += 1
                 traceback.print_exc()
             wd.iter_end(t0, ticks=self._last_tick_burst, steps=self._multi)
         try:

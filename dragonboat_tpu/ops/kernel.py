@@ -25,7 +25,6 @@ update for every lane and reality is selected by masks. This trades FLOPs
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -1613,86 +1612,6 @@ def make_multi_step_fn(cfg: KernelConfig, steps: int, donate: bool = True):
 # ---------------------------------------------------------------------------
 
 
-def _pallas_route_active() -> bool:
-    """Whether the cross-shard candidate exchange should use the Pallas
-    async-remote-DMA ring instead of the XLA all-gather collective. On by
-    default on TPU backends; ``DBTPU_PALLAS_ROUTE=0`` is the escape hatch
-    back to the collective (e.g. a TPU generation where the ring kernel
-    misbehaves). Non-TPU backends always use the collective — Pallas
-    remote DMA is a TPU primitive."""
-    if os.environ.get("DBTPU_PALLAS_ROUTE", "auto") == "0":
-        return False
-    return jax.default_backend() == "tpu"
-
-
-def _pallas_ring_gather(x: jax.Array, axis_name: str, n_shards: int):
-    """All-gather the per-shard candidate slab ``x`` (C, M) over the mesh
-    ring with Pallas async remote DMA -> (n_shards, C, M). Follows the
-    distributed-guide ring all-gather: neighbor barrier, then n-1 hops of
-    double-buffered RDMA, each device forwarding the slab it just
-    received to its right neighbor. Byte-identical to lax.all_gather
-    (same values, same order) — only the transport differs."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = n_shards
-    C, M = x.shape
-
-    def kern(local_ref, out_ref, comm_ref, send_sem, recv_sem):
-        my = jax.lax.axis_index(axis_name)
-        left = jax.lax.rem(my + n - 1, n)
-        right = jax.lax.rem(my + 1, n)
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(left,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        pltpu.semaphore_wait(barrier, 2)
-        out_ref[pl.ds(my, 1)] = local_ref[:][None]
-        comm_ref[0] = local_ref[:]
-        for step in range(n - 1):
-            send_slot = step % 2
-            recv_slot = (step + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_ref.at[send_slot],
-                dst_ref=comm_ref.at[recv_slot],
-                send_sem=send_sem.at[send_slot],
-                recv_sem=recv_sem.at[recv_slot],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
-            rdma.start()
-            rdma.wait()
-            src = jax.lax.rem(my + n - step - 1, n)
-            out_ref[pl.ds(src, 1)] = comm_ref[recv_slot][None]
-
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((n, C, M), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, C, M), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-    )(x)
-
-
-def _gather_candidates(x: jax.Array, axis_name: str, n_shards: int):
-    """(C, M) per-shard slab -> (n_shards, C, M), shard-major. The Pallas
-    ring on TPU, the XLA collective everywhere else (and under the
-    DBTPU_PALLAS_ROUTE=0 escape hatch)."""
-    if _pallas_route_active():
-        return _pallas_ring_gather(x, axis_name, n_shards)
-    return jax.lax.all_gather(x, axis_name, axis=0, tiled=False)
-
-
 def _shard_route(
     s: RaftTensors,
     out: StepOutput,
@@ -1703,10 +1622,10 @@ def _shard_route(
     n_shards: int,
 ) -> Tuple[Inbox, RoutePlan]:
     """route_step_output for a LOCAL shard block running under shard_map:
-    every shard's candidate planes are exchanged across the mesh (Pallas
-    ring on TPU, all-gather otherwise), each shard replays the identical
-    global stable-sort scatter, then keeps only its own rows of the
-    resulting inbox and its own candidates' bits of the plan.
+    every shard's candidate planes are all-gathered across the mesh, each
+    shard replays the identical global stable-sort scatter, then keeps
+    only its own rows of the resulting inbox and its own candidates' bits
+    of the plan.
 
     ``route`` holds GLOBAL lane indexes, so a candidate whose destination
     lane lives on another shard lands in that shard's inbox rows without
@@ -1728,7 +1647,7 @@ def _shard_route(
     slab = jnp.concatenate(
         [jnp.stack(cols)] + [ef.astype(i32).T for ef in efields]
     )  # (C, Ml): dest, 10 scalar rows, then E entry_terms + E entry_cc rows
-    g = _gather_candidates(slab, axis_name, n)  # (n, C, Ml)
+    g = jax.lax.all_gather(slab, axis_name, axis=0, tiled=False)  # (n, C, Ml)
 
     # splice per-shard segments back into the GLOBAL kind-major layout:
     # within one kind, shard-major == global row-major because shards
@@ -1822,7 +1741,6 @@ def make_sharded_multi_step_fn(
     Cached per (cfg, steps, mesh, donate) — jax.sharding.Mesh hashes by
     device set + axis names, so engines on the same mesh share the
     executable exactly like the unsharded factories."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec
 
     axis = mesh.axis_names[0]
@@ -1833,12 +1751,12 @@ def make_sharded_multi_step_fn(
     )
     lane = PartitionSpec(axis)
     step_lane = PartitionSpec(None, axis)  # (K, G, ...) stacked outputs
-    sm = shard_map(
+    sm = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(lane,) * 6,
         out_specs=(lane, step_lane, step_lane, lane, lane),
-        check_rep=False,
+        check_vma=False,
     )
     in_sh = NamedSharding(mesh, lane)
     out_sh = NamedSharding(mesh, step_lane)

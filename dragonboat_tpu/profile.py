@@ -428,12 +428,17 @@ class DeviceCensus:
         out["planes"] = planes
         if last is None or active is None or W <= 0:
             return out
-        act = np.asarray(active, bool)
+        # PRIVATE copies: the caller's mirrors are written by the engine
+        # loop while exporters call this from other threads. numpy's
+        # boolean indexing counts the mask, allocates, then re-reads the
+        # mask while copying with the GIL released — a mask that gains a
+        # True in between (lane activation) overruns the output buffer
+        act = np.array(active, bool)
         n_act = int(act.sum())
         out["lanes_active"] = n_act
-        lastv = np.asarray(last)
+        lastv = np.array(last)
         first = (
-            np.asarray(devfirst) if devfirst is not None
+            np.array(devfirst) if devfirst is not None
             else np.ones_like(lastv)
         )
         # logical slots a lane holds in the ring: indexes
@@ -454,21 +459,24 @@ class DeviceCensus:
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class CompileWatch:
-    """XLA compile-event accounting: a global ``jax.monitoring`` duration
-    listener counts every backend compile (and its seconds), and jitted
-    functions registered by their owners expose ``_cache_size()`` so
-    growth is attributable per function. ``install()`` is idempotent;
-    the listener cannot be unregistered (jax.monitoring has no removal
-    API short of clearing everyone's), so it stays cheap: two adds per
-    compile, nothing per step."""
+    """XLA compile-event accounting: global ``jax.monitoring`` listeners
+    count every compile request (and its seconds) and how many of them
+    the persistent compilation cache answered, and jitted functions
+    registered by their owners expose ``_cache_size()`` so growth is
+    attributable per function. ``total - cache_hits`` is what the
+    backend really compiled. ``install()`` is idempotent; the listeners
+    stay registered for the life of the process and stay cheap: two
+    adds per compile, nothing per step."""
 
     def __init__(self) -> None:
         self._mu = threading.Lock()
         self.total = 0
         self.total_s = 0.0
+        self.cache_hits = 0
         self._fns: Dict[str, list] = {}
         self.installed = False
 
@@ -483,7 +491,13 @@ class CompileWatch:
                     self.total += 1
                     self.total_s += duration
 
+        def _on_event(event, **kw):
+            if event == _CACHE_HIT_EVENT:
+                with self._mu:
+                    self.cache_hits += 1
+
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         self.installed = True
         return self
 
@@ -538,6 +552,7 @@ class CompileWatch:
         return {
             "total": self.total,
             "total_s": round(self.total_s, 4),
+            "cache_hits": self.cache_hits,
             "per_function": self.per_function(),
         }
 
@@ -545,6 +560,7 @@ class CompileWatch:
         with self._mu:
             self.total = 0
             self.total_s = 0.0
+            self.cache_hits = 0
 
 
 def diff_compiles(before: dict, after: dict) -> dict:
@@ -558,6 +574,7 @@ def diff_compiles(before: dict, after: dict) -> dict:
     return {
         "total": after["total"] - before["total"],
         "total_s": round(after["total_s"] - before["total_s"], 4),
+        "cache_hits": after["cache_hits"] - before["cache_hits"],
         "per_function": per,
     }
 

@@ -3,19 +3,12 @@ profiles (not part of the driver bench; see bench.py for the headline)."""
 from __future__ import annotations
 
 import json
-import os
 import shutil
 import sys
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from dragonboat_tpu._jaxenv import maybe_pin_cpu  # noqa: E402
-
-maybe_pin_cpu()
-
-from bench import bench_e2e, _bench_sm_class  # noqa: E402
+from bench import bench_e2e
 
 
 def main() -> None:

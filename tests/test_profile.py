@@ -292,6 +292,53 @@ def test_vector_scenario_runtime_audit_clean(vec_host):
     assert "step_batch[g8]" in text
 
 
+def test_census_snapshot_survives_concurrent_lane_activation():
+    """Exporter threads (NodeHost tick workers, the history sampler) call
+    DeviceCensus.snapshot on the engine's LIVE mirrors while the loop
+    thread activates lanes. Indexing with the live mask let numpy count
+    few Trues, then copy many, past the end of its output buffer (found
+    on the TPU host as `malloc(): invalid size`); the snapshot must work
+    on private copies."""
+    import sys
+    import threading
+
+    import numpy as np
+
+    from dragonboat_tpu.profile import DeviceCensus
+
+    G, W = 200_000, 64
+    census = DeviceCensus()
+    census.set_planes(
+        {"state.log_term": G * W * 4}, log_planes=("state.log_term",),
+        log_window=W,
+    )
+    active = np.zeros(G, bool)
+    last = np.full(G, 10, np.int64)
+    first = np.ones(G, np.int64)
+    stop = threading.Event()
+
+    def activate_and_clear():
+        while not stop.is_set():
+            active[:] = True
+            active[:] = False
+
+    t = threading.Thread(target=activate_and_clear, daemon=True)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t.start()
+        deadline = time.monotonic() + 0.5
+        while time.monotonic() < deadline:
+            c = census.snapshot(last=last, devfirst=first, active=active)
+            assert 0 <= c["lanes_active"] <= G
+            assert 0.0 <= c["hbm_waste_ratio"] <= 1.0
+    finally:
+        stop.set()
+        t.join(timeout=10)
+        sys.setswitchinterval(old)
+    assert not t.is_alive()
+
+
 @pytest.mark.perf
 def test_census_and_counters_add_zero_syncs(vec_host):
     """Acceptance (ISSUE 18): reading the HBM census and the counter

@@ -82,8 +82,6 @@ def test_shard_route_matches_reference(seed, monkeypatch):
     columns, replay the global scatter, keep the local slice — must
     reproduce the reference router bit for bit, including candidates
     whose destination lane lives on another shard."""
-    from jax.experimental.shard_map import shard_map
-
     # reuse test_multistep's randomized state/output generator at this
     # file's lane count (it reads the module-global KCFG)
     monkeypatch.setattr(tm, "KCFG", SKCFG)
@@ -102,14 +100,14 @@ def test_shard_route_matches_reference(seed, monkeypatch):
                 rdelta[g, p] = rng.choice([0, 0, 0, 2, -2, -40])
 
     lane = PartitionSpec("groups")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _shard_route, cfg=SKCFG, axis_name="groups", n_shards=N_DEV
         ),
         mesh=_mesh(),
         in_specs=(lane,) * 4,
         out_specs=(lane, lane),
-        check_rep=False,
+        check_vma=False,
     )
     nxt, plan = jax.jit(fn)(s, out, jnp.asarray(route), jnp.asarray(rdelta))
     nxt = _np_tree(nxt)._asdict()
