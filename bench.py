@@ -1000,9 +1000,11 @@ def _host_stage_report(hosts) -> dict:
     if not totals_raw:
         return {}
     totals = {name: round(v, 4) for name, v in totals_raw.items()}
-    # "deliver" is a sub-span of the send/apply/reads phases: keep it out
-    # of the wall sum or its seconds would count twice
-    wall = sum(v for n, v in totals_raw.items() if n != "deliver")
+    # sub-spans lie inside a phase: keep them out of the wall sum or
+    # their seconds would count twice
+    from dragonboat_tpu.profile import VECTOR_SUBSPANS
+
+    wall = sum(v for n, v in totals_raw.items() if n not in VECTOR_SUBSPANS)
     fanout = sum(totals_raw.get(n, 0.0) for n in _FANOUT_STAGES)
     pack = totals_raw.get("pack", 0.0)
     out = {"host_stage_total_s": totals}

@@ -195,6 +195,7 @@ def _default_targets() -> Targets:
         # stage per step — they must stay inside the `if self.sampling`
         # gate or every step pays histogram/recorder work
         (TRACE, "Profiler.end"),
+        (TRACE, "Profiler.begin"),
         (TRACE, "Profiler.add"),
         (PROFILE, "PhasePlane.on_phase"),
     }

@@ -122,6 +122,16 @@ class Node:
         # (EngineConfig.profile_sample_ratio); unsampled requests pay one
         # increment and allocate nothing
         self._req_sampler = getattr(engine, "request_sampler", None)
+        # where a sampled request's path folds at its end: the vector
+        # engine's stage profiler (the scalar engine has one per worker
+        # and stamps no pack, so nothing folds there)
+        self._req_profiler = getattr(engine, "profiler", None)
+        # who owns the launch ordinal: a VectorEngineHandle's core
+        self._launch_src = getattr(engine, "core", engine)
+        # the instant the apply worker took up the ready nodes it is
+        # handling, this one among them (the vector engine's worker sets
+        # it): t_apply0 of the sampled entries this node's task holds
+        self._apply_t0 = 0.0
         self.quiesce_mgr = QuiesceManager(
             enabled=cfg.quiesce, election_tick=cfg.election_rtt
         )
@@ -208,28 +218,59 @@ class Node:
         ev = self.events
         return getattr(ev, "metrics", None) if ev is not None else None
 
+    def _launch_no(self) -> int:
+        """The engine's launch ordinal now (0 on an engine without one)."""
+        return getattr(self._launch_src, "launch_no", 0)
+
+    def _trace_proposal(self, entry, batch: int = 0) -> None:
+        """A sampled proposal: the propose-enqueue stamp. The trace rides
+        the Entry through arena -> commit -> apply and back to the
+        histograms and the profiler; the trace id additionally rides the
+        wire (Entry/Message codec) so remote hops stamp the same causal
+        key. `batch` is the size of the submission this entry is the
+        last of: one sampled entry per batch keeps the sampler's 1-in-N
+        meaning "1-in-N submissions", not "N samples per wave"."""
+        n0 = self._launch_no()
+        entry.lat = LatencyTrace(
+            self, time.monotonic(), trace_id=mint_trace_id(), n0=n0
+        )
+        entry.trace_id = entry.lat.trace_id
+        fields = {"batch": batch} if batch else {}
+        flight_recorder().record(
+            "propose_enqueue", cluster=self.cluster_id, node=self._node_id,
+            trace=entry.trace_id, launch=n0, **fields,
+        )
+
     def _observe_entry_latency(self, lt: LatencyTrace) -> None:
-        """A sampled proposal finished its apply: fold the lifecycle into
-        the proposing node's latency histograms. Owner-pinned (co-hosted
-        replicas apply the identical Entry objects) and once-only."""
+        """A sampled proposal is applied and its waiter notified (t_done):
+        fold the lifecycle into the proposing node's latency histograms
+        and its path into the engine's profiler, from the same stamps.
+        Owner-pinned (co-hosted replicas apply the identical Entry
+        objects) and once-only."""
         if lt.owner is not self or lt.done:
             return
         lt.done = True
+        now = lt.t_done = time.monotonic()
+        lt.n_done = self._launch_no()
+        # a missing commit stamp (engine variant without one) degrades to
+        # commit==apply rather than dropping the sample
+        commit_t = lt.t_commit or now
+        # a worker already at work on this node when the commit arrived
+        # picked the entry up without waiting
+        lt.t_apply0 = max(self._apply_t0, commit_t)
         if lt.trace_id:
             # final causal stage: the sampled proposal applied + notified
             # on its proposing node
             flight_recorder().record(
                 "proposal_applied", cluster=self.cluster_id,
-                node=self._node_id, trace=lt.trace_id,
+                node=self._node_id, trace=lt.trace_id, launch=lt.n_done,
             )
+        if self._req_profiler is not None:
+            lt.fold(self._req_profiler, "w")
         m = self._metrics_registry()
         if m is None:
             return
-        now = time.monotonic()
         key = (self.cluster_id, self._node_id)
-        # a missing commit stamp (engine variant without one) degrades to
-        # commit==apply rather than dropping the sample
-        commit_t = lt.t_commit or now
         m.observe(
             "proposal_commit_latency_seconds", key, max(commit_t - lt.t0, 0.0)
         )
@@ -238,27 +279,33 @@ class Node:
         )
 
     def _read_latency_done(self, rs: RequestState) -> None:
-        t0 = rs.lat
+        """on_complete of a sampled read, on the completing thread."""
+        lt = rs.lat
         r = rs.result
-        if t0 is None or r is None or not r.completed:
+        if lt is None or lt.done or r is None or not r.completed:
             return  # timed-out/dropped reads are not read latencies
+        lt.done = True
+        now = lt.t_done = time.monotonic()
+        lt.n_done = self._launch_no()
+        if self._req_profiler is not None:
+            lt.fold(self._req_profiler, "r")
         m = self._metrics_registry()
         if m is not None:
             m.observe(
                 "readindex_latency_seconds",
                 (self.cluster_id, self._node_id),
-                max(time.monotonic() - t0, 0.0),
+                max(now - lt.t0, 0.0),
             )
 
     def apply_update(self, entry, result, rejected, ignored, notify_read) -> None:
-        if entry.lat is not None:
-            self._observe_entry_latency(entry.lat)
         if entry.key & BATCH_KEY_BIT:
             self._batch_applied(batch_id_of(entry.key), 1)
         else:
             self.pending_proposals.applied(
                 entry.key, entry.client_id, entry.series_id, result, rejected
             )
+        if entry.lat is not None:
+            self._observe_entry_latency(entry.lat)
         if notify_read:
             self.pending_read_indexes.applied(entry.index)
 
@@ -271,17 +318,20 @@ class Node:
         counts: dict = {}
         if results is None and not self._batches:
             return  # replica apply with no locally-tracked batches
+        sampled = None  # observed last: t_done is after the notify
         if results is None:
             for e in entries:
                 if e.lat is not None:
-                    self._observe_entry_latency(e.lat)
+                    sampled = sampled or []
+                    sampled.append(e.lat)
                 if e.key & BATCH_KEY_BIT:
                     bid = batch_id_of(e.key)
                     counts[bid] = counts.get(bid, 0) + 1
         else:
             for e, r in zip(entries, results):
                 if e.lat is not None:
-                    self._observe_entry_latency(e.lat)
+                    sampled = sampled or []
+                    sampled.append(e.lat)
                 if e.key & BATCH_KEY_BIT:
                     bid = batch_id_of(e.key)
                     counts[bid] = counts.get(bid, 0) + 1
@@ -291,6 +341,9 @@ class Node:
                     )
         for bid, n in counts.items():
             self._batch_applied(bid, n)
+        if sampled:
+            for lt in sampled:
+                self._observe_entry_latency(lt)
 
     def _batch_applied(self, batch_id: int, n: int) -> None:
         with self._batch_mu:
@@ -370,18 +423,7 @@ class Node:
         rs, entry = self.pending_proposals.propose(session, cmd, timeout_ticks)
         s = self._req_sampler
         if s is not None and s.sample():
-            # propose-enqueue timestamp; the trace rides the Entry through
-            # arena -> commit -> apply and back to the histograms. The
-            # trace id additionally rides the wire (Entry/Message codec)
-            # so remote hops stamp the same causal key.
-            entry.lat = LatencyTrace(
-                self, time.monotonic(), trace_id=mint_trace_id()
-            )
-            entry.trace_id = entry.lat.trace_id
-            flight_recorder().record(
-                "propose_enqueue", cluster=self.cluster_id,
-                node=self._node_id, trace=entry.trace_id,
-            )
+            self._trace_proposal(entry)
         # optional payload compression at the propose boundary: the wire,
         # logdb and apply queue all carry the compressed form; replicas
         # decompress once at apply time (cf. rsm/encoded.go:47-176)
@@ -416,17 +458,7 @@ class Node:
         )
         s = self._req_sampler
         if entries and s is not None and s.sample():
-            # one sampled entry per batch keeps the sampler's 1-in-N
-            # meaning "1-in-N submissions", not "N samples per wave"
-            e = entries[-1]
-            e.lat = LatencyTrace(
-                self, time.monotonic(), trace_id=mint_trace_id()
-            )
-            e.trace_id = e.lat.trace_id
-            flight_recorder().record(
-                "propose_enqueue", cluster=self.cluster_id,
-                node=self._node_id, trace=e.trace_id, batch=len(entries),
-            )
+            self._trace_proposal(entries[-1], batch=len(entries))
         for entry in entries:
             maybe_encode_entry(self.config.entry_compression_type, entry)
         accepted = self.incoming_proposals.add_many(entries)
@@ -479,15 +511,7 @@ class Node:
         ]
         s = self._req_sampler
         if entries and s is not None and s.sample():
-            e = entries[-1]
-            e.lat = LatencyTrace(
-                self, time.monotonic(), trace_id=mint_trace_id()
-            )
-            e.trace_id = e.lat.trace_id
-            flight_recorder().record(
-                "propose_enqueue", cluster=self.cluster_id,
-                node=self._node_id, trace=e.trace_id, batch=len(entries),
-            )
+            self._trace_proposal(entries[-1], batch=len(entries))
         if self.config.entry_compression_type:
             for entry in entries:
                 maybe_encode_entry(self.config.entry_compression_type, entry)
@@ -561,7 +585,9 @@ class Node:
         rs = self.pending_read_indexes.read(timeout_ticks)
         s = self._req_sampler
         if s is not None and s.sample():
-            rs.lat = time.monotonic()
+            rs.lat = LatencyTrace(
+                self, time.monotonic(), n0=self._launch_no()
+            )
             rs.on_complete(self._read_latency_done)
         if not self.incoming_reads.add(rs):
             raise ErrSystemBusy()

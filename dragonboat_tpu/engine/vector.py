@@ -147,6 +147,11 @@ _plog = get_logger("vectorengine")
 _MESH_LAUNCH_MU = threading.Lock()
 
 
+# the request sampler is never denser than 1 in this many, whatever the
+# stage profiler's ratio (see VectorEngine.request_sampler)
+REQUEST_SAMPLE_FLOOR = 8
+
+
 class _NoLock:
     def __enter__(self):
         return self
@@ -560,6 +565,7 @@ class _Lane:
         "pack_info",
         "packed_pending",
         "ri_pending",
+        "ri_lat",
         "recovering",
         "adopted_term",
         "catchup",
@@ -586,6 +592,9 @@ class _Lane:
         self.pack_info: Dict[int, tuple] = {}
         self.packed_pending = 0  # entries packed into not-yet-decoded steps
         self.ri_pending: Dict[Tuple[int, int], SystemCtx] = {}  # (lo,hi)->ctx
+        # (lo,hi) -> the LatencyTraces of the sampled reads bound to that
+        # ctx; empty unless a read of the ctx was sampled
+        self.ri_lat: Dict[Tuple[int, int], list] = {}
         self.recovering = False
         # term adopted from an InstallSnapshot sender; the restore ack must
         # carry it or the leader drops the ack as stale. Kept on the lane
@@ -691,9 +700,10 @@ def _send_target(lane_by_g, g: int, p: int):
 
 
 def gather_replicate_sends(
-    o: dict, base, lane_by_g, fetch_from_log=None
+    o: dict, base, lane_by_g, fetch_from_log=None, launch: int = 0
 ) -> List[Tuple[_Lane, Message]]:
-    """Phase-1 Replicate materialization (these leave BEFORE the fsync)."""
+    """Phase-1 Replicate materialization (these leave BEFORE the fsync).
+    `launch` is the engine's launch ordinal, for the chain events."""
     sends: List[Tuple[_Lane, Message]] = []
     gs, ps = np.nonzero(o["send_flags"] & SEND_REPLICATE)
     if not gs.size:
@@ -742,6 +752,7 @@ def gather_replicate_sends(
             flight_recorder().record(
                 "replicate_send", cluster=lane.node.cluster_id,
                 node=lane.node.node_id(), to=to_nid, trace=trace_id,
+                launch=launch,
             )
         sends.append(
             (
@@ -863,8 +874,11 @@ def gather_post_sends(o: dict, base, lane_by_g) -> List[Tuple[_Lane, Message]]:
     return sends
 
 
-def gather_resp_sends(o: dict, base, lane_by_g) -> List[Tuple[_Lane, Message]]:
-    """Phase-3 response-plane sends: one reply per consumed inbox slot."""
+def gather_resp_sends(
+    o: dict, base, lane_by_g, launch: int = 0
+) -> List[Tuple[_Lane, Message]]:
+    """Phase-3 response-plane sends: one reply per consumed inbox slot.
+    `launch` is the engine's launch ordinal, for the chain events."""
     sends: List[Tuple[_Lane, Message]] = []
     resp_type = o["resp_type"]
     gs, ks = np.nonzero(resp_type != MSG.NONE)
@@ -911,7 +925,7 @@ def gather_resp_sends(o: dict, base, lane_by_g) -> List[Tuple[_Lane, Message]]:
                 flight_recorder().record(
                     "replicate_ack", cluster=lane.node.cluster_id,
                     node=lane.node.node_id(), to=to_nid, trace=trace_id,
-                    index=log_index,
+                    index=log_index, launch=launch,
                 )
         sends.append(
             (
@@ -1091,14 +1105,30 @@ class VectorEngine:
         ratio = (getattr(ecfg, "profile_sample_ratio", 0) or 0) if ecfg else 0
         self.profiler = Profiler(sample_ratio=ratio if ratio > 0 else 32)
         # sampled stage durations also land in the process-global phase
-        # plane (engine_phase_seconds{engine="vector",phase=...} + flight-
-        # recorder spans); unsampled steps never reach it
-        self.profiler.attach_phase_plane(phase_plane(), "vector")
+        # plane (engine_phase_seconds{engine="vector",phase=...}) and, at
+        # ratio 1, in the flight recorder's span store; unsampled steps
+        # never reach either. Every iteration starts with wait, prepare
+        # and pack whether or not it will launch.
+        self.profiler.attach_phase_plane(
+            phase_plane(), "vector", idle_head=("wait", "prepare", "pack")
+        )
+        # kernel launches dispatched so far: the ordinal that the request
+        # path's stamps carry (trace.LatencyTrace). Written by the loop
+        # thread only; other threads read it as a plain int.
+        self.launch_no = 0
         # request-lifecycle latency sampling shares the profiler's ratio
-        # knob: 1-in-N proposals/reads carry a LatencyTrace into the
-        # proposal_commit/apply and readindex latency histograms; the
-        # other N-1 stay allocation-free (see trace.LatencySampler)
-        self.request_sampler = LatencySampler(ratio if ratio > 0 else 32)
+        # knob down to a floor of its own: 1-in-N proposals/reads carry a
+        # LatencyTrace into the proposal_commit/apply and readindex
+        # latency histograms and the profiler's req.* samples; the other
+        # N-1 stay allocation-free (see trace.LatencySampler). Tracing
+        # EVERY request (a trace, some nine chain events and a fold
+        # each) cost the upstream write cell four times what the stage
+        # profiler at ratio 1 did (PERF.md, PR 23), which made the traced
+        # run another regime than the one it explains; a test that wants
+        # every request traced sets request_sampler.ratio = 1.
+        self.request_sampler = LatencySampler(
+            max(ratio if ratio > 0 else 32, REQUEST_SAMPLE_FLOOR)
+        )
         # per-step counters accumulated inline by the decode phases on
         # objects they already materialize (no extra device syncs, no
         # extra numpy reductions); exported via step_stats() and folded
@@ -1125,6 +1155,11 @@ class VectorEngine:
             # kernel the compiler refuses would otherwise show up only as
             # proposal time-outs (chip_smoke.py asserts this stays zero)
             "loop_exceptions": 0,
+            # ReadIndex contexts the kernel dropped for want of a slot
+            # (StepOutput.dropped_readindex, summed as fetched). Counted,
+            # not repaired: the reads behind them still time out at the
+            # client.
+            "readindex_dropped": 0,
         }
         # ---- tick-fairness watchdog (ROADMAP seed flake) -----------------
         # Inter-iteration latency vs the host's tick period, a starvation
@@ -1649,7 +1684,10 @@ class VectorEngine:
     def _loop(self) -> None:
         period = 0.002
         wd = self.watchdog
+        prof = self.profiler
         while not self._stopped.is_set():
+            prof.new_iteration()
+            prof.begin("wait")
             self._ready.wait(period)
             self._ready.clear()
             if self._stopped.is_set():
@@ -1677,6 +1715,7 @@ class VectorEngine:
             import traceback
 
             traceback.print_exc()
+        prof.close()
 
     def snapshot_status_ready(self, node) -> None:
         with self._snap_status_mu:
@@ -1688,8 +1727,12 @@ class VectorEngine:
         # mirrors (_m_base/_m_last/_lane_by_g); an undecoded in-flight step
         # would later clobber them with stale device output, so these rare
         # paths drain the pipeline first
+        prof = self.profiler
+        prof.begin("prepare")
         if self._reconq or self._snap_status or self._rebase_due:
-            self._flush_pending()
+            if self._pending is not None:
+                self._flush_pending()  # times its own fetch and decode
+                prof.begin("prepare")
             if self._rebase_due:
                 self._rebase_due = False
                 self._do_rebase()
@@ -1724,8 +1767,6 @@ class VectorEngine:
                     if lane is not None and lane.active:
                         work.add(lane)
         work |= self._catchups
-        prof = self.profiler
-        prof.new_iteration(len(work))
         # swap to the idle buffer set BEFORE packing: the other set may
         # still be read by the in-flight step
         if self._overlap:
@@ -1733,9 +1774,8 @@ class VectorEngine:
             self._buf, self._ticks, self._host_inbox = self._bufsets[
                 self._buf_idx
             ]
-        prof.start()
+        prof.begin("pack")
         had, packs = self._pack(work)
-        prof.end("pack")
         if not had:
             skip = False
             if ticks == 0:
@@ -1761,6 +1801,7 @@ class VectorEngine:
                 # undecoded step indefinitely
                 self._flush_pending()
                 return
+        prof.begin("dispatch")
         if ticks:
             # per-lane tick counts come from the OWNING host's counter (a
             # shared core serves several NodeHosts, each with its own tick
@@ -1790,8 +1831,12 @@ class VectorEngine:
         # ONE device_put over the (inbox, ticks) pytree: 12 small host
         # arrays ship in a single batched transfer instead of 12 dispatch
         # round-trips (per-call overhead dominates at these sizes); the
-        # Inbox views and sharding pytree were built once at allocation
-        prof.start()
+        # Inbox views and sharding pytree were built once at allocation.
+        # On sampled iterations the put and the jitted call (which
+        # returns futures) are timed apart, as sub-spans of dispatch.
+        self.launch_no += 1
+        sampling = prof.sampling
+        t0 = time.monotonic() if sampling else 0.0
         if self._multi > 1:
             # K protocol steps per launch: the route/delta planes ride
             # the same batched transfer (small G x P arrays; rebuilt
@@ -1808,12 +1853,14 @@ class VectorEngine:
                     )
                 else:
                     inbox, tarr, route, rdelta = jax.device_put(payload)
+                t1 = time.monotonic() if sampling else 0.0
                 self._state, outs, plans, self._resid, resid_count = (
                     self._multi_fn(
                         self._state, inbox, tarr, self._resid, route, rdelta
                     )
                 )
-                prof.end("dispatch")
+                if sampling:
+                    self._add_seam("put", "launch", t0, t1)
                 o, pl, rc = self._fetch_super(outs, plans, resid_count)
             self._m_resid = rc
             self._decode_super(work, packs, o, pl)
@@ -1824,8 +1871,10 @@ class VectorEngine:
             )
         else:
             inbox, tarr = jax.device_put((self._host_inbox, self._ticks))
+        t1 = time.monotonic() if sampling else 0.0
         self._state, out = self._step_fn(self._state, inbox, tarr)
-        prof.end("dispatch")
+        if sampling:
+            self._add_seam("put", "launch", t0, t1)
         if self._overlap:
             # pipeline: decode step t-1 while the device computes step t
             # (jax dispatch is async — `out` is a future). Ordering
@@ -1838,18 +1887,35 @@ class VectorEngine:
         else:
             self._decode(work, packs, self._fetch_output(out))
 
+    def _add_seam(self, first: str, second: str, t0: float, t1: float) -> None:
+        """Two consecutive sub-spans of the host<->device seam, begun at
+        t0 and t1 and ending now (sampled iterations only)."""
+        t2 = time.monotonic()
+        self.profiler.add(first, t1 - t0)
+        self.profiler.add(second, t2 - t1)
+
     def _fetch_output(self, out) -> dict:
         """ONE consolidated device->host transfer for the whole StepOutput,
         shared by the overlap and non-overlap paths. The planes ship as a
         single batched fetch rather than per-plane masked gets: every plane
         is G- or GxP-sized, so per-dispatch overhead dominates transfer
         cost, and each decode phase masks its own work list host-side from
-        send_flags/dirty lanes."""
+        send_flags/dirty lanes. On sampled iterations the wait for the
+        kernel and the copy down are timed apart (device_wait, copy): the
+        extra block_until_ready, on one plane of the output, waits for
+        what the device_get would have waited for, inside this blessed
+        seam."""
         prof = self.profiler
-        prof.start()
-        o = jax.device_get(out)._asdict()
+        prof.begin("fetch")
+        if prof.sampling:
+            t0 = time.monotonic()
+            jax.block_until_ready(out[0])  # one program: ready together
+            t1 = time.monotonic()
+            o = jax.device_get(out)._asdict()
+            self._add_seam("device_wait", "copy", t0, t1)
+        else:
+            o = jax.device_get(out)._asdict()
         note_seam_sync()  # runtime sync audit: the ONE blessed transfer
-        prof.end("fetch")
         return o
 
     def _fetch_super(self, outs, plans, resid_count):
@@ -1859,10 +1925,16 @@ class VectorEngine:
         the residual-inbox occupancy ship together). This is the other
         blessed sync seam — it fires once per K protocol steps."""
         prof = self.profiler
-        prof.start()
-        o, pl, rc = jax.device_get((outs, plans, resid_count))
+        prof.begin("fetch")
+        if prof.sampling:
+            t0 = time.monotonic()
+            jax.block_until_ready(resid_count)  # ready with the rest
+            t1 = time.monotonic()
+            o, pl, rc = jax.device_get((outs, plans, resid_count))
+            self._add_seam("device_wait", "copy", t0, t1)
+        else:
+            o, pl, rc = jax.device_get((outs, plans, resid_count))
         note_seam_sync()  # runtime sync audit: one transfer per K steps
-        prof.end("fetch")
         return o._asdict(), pl._asdict(), np.array(rc, np.int32)
 
     def _flush_pending(self) -> None:
@@ -1905,6 +1977,7 @@ class VectorEngine:
                 ]
                 for enc in dead:
                     del lane.ri_pending[enc]
+                    lane.ri_lat.pop(enc, None)
             if not (
                 node.pending_proposals.has_pending()
                 or node.pending_read_indexes.has_pending()
@@ -2056,7 +2129,13 @@ class VectorEngine:
                         ents = []
                         cap = min(E, free)
                         while lane.staged_props and len(ents) < cap:
-                            ents.append(lane.staged_props.popleft())
+                            e = lane.staged_props.popleft()
+                            if e.lat is not None:
+                                # sampled: it leaves the queue for the
+                                # launch this pack is building
+                                e.lat.t_pack = time.monotonic()
+                                e.lat.n_pack = self.launch_no + 1
+                            ents.append(e)
                         free -= len(ents)
                         lane.packed_pending += len(ents)
                         self._stage_row(
@@ -2091,6 +2170,7 @@ class VectorEngine:
                         ):
                             enc = _enc_ctx(lane.self_slot(), ctx.low)
                             lane.ri_pending[enc] = ctx
+                            self._stamp_reads_packed(lane, enc, states)
                             self._stage_row(
                                 g, k, MSG.READ_INDEX,
                                 from_slot=lane.self_slot(), hint=enc[0],
@@ -2105,6 +2185,7 @@ class VectorEngine:
                     if node.pending_read_indexes.bind_queued_states(states, ctx):
                         enc = _enc_ctx(lane.self_slot(), ctx.low)
                         lane.ri_pending[enc] = ctx
+                        self._stamp_reads_packed(lane, enc, states)
                         node._send_message(
                             Message(
                                 type=MT.READ_INDEX,
@@ -2149,6 +2230,24 @@ class VectorEngine:
         self._p_staged_backlog = backlog
         self._flush_staged_rows()
         return had, packs
+
+    def _stamp_reads_packed(self, lane: _Lane, enc, states) -> None:
+        """The sampled reads among `states` leave the node's queue under
+        the context `enc`: on the leader into the launch this pack is
+        building, on a follower toward the leader. Their traces wait in
+        lane.ri_lat for the reads phase that confirms the context."""
+        lts = None
+        for rs in states:
+            lt = rs.lat
+            if lt is not None:
+                if lts is None:
+                    lts = []
+                    now = time.monotonic()
+                lt.t_pack = now
+                lt.n_pack = self.launch_no + 1
+                lts.append(lt)
+        if lts is not None:
+            lane.ri_lat[enc] = lts
 
     def _stage_row(
         self, g: int, k: int, mtype: int, from_slot: int = 0, term: int = 0,
@@ -2247,7 +2346,7 @@ class VectorEngine:
                 flight_recorder().record(
                     "replicate_recv", cluster=lane.node.cluster_id,
                     node=lane.node.node_id(), from_node=m.from_,
-                    trace=trace_id,
+                    trace=trace_id, launch=self.launch_no + 1,
                 )
             self._stage_row(
                 g, k, MSG.REPLICATE, from_slot=from_slot, term=m.term,
@@ -2404,37 +2503,30 @@ class VectorEngine:
         self.last_output = o  # numpy snapshot for diagnostics/tools
         note_engine_steps(1)
         prof = self.profiler
-        prof.start()
+        prof.begin("place")
         self._decode_place(o, packs)
         self._refresh_mirrors(o)
-        prof.end("place")
         # ---- phase 1: Replicate messages leave BEFORE the fsync ----------
-        prof.start()
+        prof.begin("send_rep")
         self._decode_send_rep(o)
-        prof.end("send_rep")
         # ---- phase 2: one batched fsynced write for every lane -----------
-        prof.start()
+        prof.begin("save")
         updates, lane_saves = build_save_updates(
             o, self._m_base, self._lane_by_g
         )
         self._commit_saves(updates, lane_saves)
-        prof.end("save")
         # ---- phase 3: post-fsync sends (votes, responses, heartbeats) ----
-        prof.start()
+        prof.begin("send_resp")
         self._decode_send_post(o)
-        prof.end("send_resp")
         # ---- phase 4: hand committed entries to the RSM ------------------
-        prof.start()
+        prof.begin("apply")
         self._decode_apply(o)
-        prof.end("apply")
         # ---- phase 5: confirmed reads ------------------------------------
-        prof.start()
+        prof.begin("reads")
         self._decode_reads(o)
-        prof.end("reads")
         # ---- phase 6: maintenance ----------------------------------------
-        prof.start()
+        prof.begin("maintain")
         self._maintain(o)
-        prof.end("maintain")
 
     def _decode_super(self, worked: Set[_Lane], packs, o: dict, pl: dict) -> None:
         """Decode one K-step super-step (the multi-step path): the
@@ -2456,6 +2548,8 @@ class VectorEngine:
             inner step in order.
         """
         K = self._multi
+        prof = self.profiler
+        prof.begin("place")
         steps = []
         for t in range(K):
             ot = {k: v[t] for k, v in o.items()}
@@ -2463,13 +2557,12 @@ class VectorEngine:
             steps.append((ot, plt))
         self.last_output = steps[-1][0]
         note_engine_steps(K)
-        prof = self.profiler
         st = self._sstats
         base = self._m_base
         lane_by_g = self._lane_by_g
         # ---- place + phase 1, per inner step in order --------------------
         for t, (ot, plt) in enumerate(steps):
-            prof.start()
+            prof.begin("place")
             # routed Replicates consumed by THIS inner step: acceptance
             # (rep_base) is in ot; the candidate plan was staged by the
             # previous inner step (or the previous super-step's last one)
@@ -2479,13 +2572,12 @@ class VectorEngine:
             for kind in ("rep", "vote", "hb", "tn", "resp", "rir"):
                 st["msgs_routed_device"] += int(plt[kind].sum())
             self._mask_routed(ot, plt)
-            prof.end("place")
-            prof.start()
+            prof.begin("send_rep")
             self._decode_send_rep(ot)
-            prof.end("send_rep")
+        prof.begin("place")  # as at K=1, the mirror refresh is place's
         self._refresh_mirrors(steps[-1][0])
         # ---- phase 2: ONE merged save wave for the whole window ----------
-        prof.start()
+        prof.begin("save")
         updates: List[Update] = []
         lane_saves: List[Tuple[_Lane, List[Entry], State]] = []
         for ot, _plt in steps:
@@ -2493,24 +2585,19 @@ class VectorEngine:
             updates.extend(u)
             lane_saves.extend(ls)
         self._commit_saves(updates, lane_saves)
-        prof.end("save")
         # ---- phases 3-5 per inner step in order --------------------------
-        prof.start()
+        prof.begin("send_resp")
         for ot, _plt in steps:
             self._decode_send_post(ot)
-        prof.end("send_resp")
-        prof.start()
+        prof.begin("apply")
         for ot, _plt in steps:
             self._decode_apply(ot)
-        prof.end("apply")
-        prof.start()
+        prof.begin("reads")
         for ot, plt in steps:
             self._decode_reads(ot, skip_routed=plt["rir"])
-        prof.end("reads")
         # ---- phase 6: maintenance on the window's final state ------------
-        prof.start()
+        prof.begin("maintain")
         self._maintain(steps[-1][0])
-        prof.end("maintain")
 
     # ------------------------------------------------ multi-step routing
     def _rebuild_routes(self) -> None:
@@ -2655,6 +2742,10 @@ class VectorEngine:
         # already fetched — zero extra device syncs)
         self._lease_local += int(o["lease_served"].sum())
         self._lease_fb += int(o["lease_fallback"].sum())
+        ri_dropped = int(o["dropped_readindex"].sum())
+        self._sstats["readindex_dropped"] += ri_dropped
+        if self.profiler.sampling:
+            self.profiler.fold("n.readindex_dropped", ri_dropped)
         # on-device event-counter plane: one (G, CTR.COUNT) u32 delta
         # block per protocol step, accumulated where the events happened
         # (inside step_batch / the K-step scan) and folded here into the
@@ -2809,7 +2900,7 @@ class VectorEngine:
         base = self._m_base
         lane_by_g = self._lane_by_g
         rep_sends = gather_replicate_sends(
-            o, base, lane_by_g, self._fetch_from_log
+            o, base, lane_by_g, self._fetch_from_log, self.launch_no
         )
         st["msgs_replicate"] += len(rep_sends)
         self._dispatch_sends(rep_sends)
@@ -2834,7 +2925,7 @@ class VectorEngine:
         lane_by_g = self._lane_by_g
         post = gather_post_sends(o, base, lane_by_g)
         st["msgs_broadcast"] += len(post)
-        resp_sends = gather_resp_sends(o, base, lane_by_g)
+        resp_sends = gather_resp_sends(o, base, lane_by_g, self.launch_no)
         st["msgs_resp"] += len(resp_sends)
         post.extend(resp_sends)
         self._dispatch_sends(post)
@@ -2900,12 +2991,14 @@ class VectorEngine:
                     if lt is not None and lt.t_commit == 0.0:
                         # sampled proposal reached quorum commit this step
                         lt.t_commit = t_commit
+                        lt.n_commit = self.launch_no
                         if lt.trace_id:
                             flight_recorder().record(
                                 "quorum_commit",
                                 cluster=lane.node.cluster_id,
                                 node=lane.node.node_id(),
                                 trace=lt.trace_id, index=e.index,
+                                launch=self.launch_no,
                             )
                 if has_cc:
                     lane.cc_inflight = False
@@ -2952,6 +3045,12 @@ class VectorEngine:
                 origin = _ctx_origin(enc_lo)
                 if origin == lane.self_slot():
                     ctx = lane.ri_pending.pop(enc, None)
+                    if lane.ri_lat:
+                        # sampled reads of this context: confirmed now
+                        now = time.monotonic()
+                        for lt in lane.ri_lat.pop(enc, ()):
+                            lt.t_commit = now
+                            lt.n_commit = self.launch_no
                     if ctx is not None:
                         node.pending_read_indexes.add_ready_to_read(
                             [ReadyToRead(index=idx, system_ctx=ctx)]
@@ -4036,16 +4135,27 @@ class VectorEngine:
     def _task_worker_main(self, worker: int) -> None:
         batch: list = []
         apply: list = []
+        prof = self.profiler
         while not self._stopped.is_set():
             cids = self.task_ready.wait_and_take(worker)
             if not cids:
                 continue
+            # one span a wake-up, `rsm.handle`: this worker's time over the
+            # ready nodes it took. Its start is t_apply0 of the sampled
+            # entries they hold (read always: a request's sampling is its
+            # own). The loop's flag of the moment samples the span: at
+            # ratio N about one wake-up in N is timed.
+            t0 = time.monotonic()
+            sampled = prof.sampling
+            if sampled:
+                c0 = time.thread_time()
             for cid in cids:
                 node = self.get_node(cid)
                 if node is None or node.stopped:
                     continue
                 if not node.sm.loaded(OffloadFrom.COMMIT_WORKER):
                     continue  # lost the race with NodeHost close
+                node._apply_t0 = t0
                 try:
                     node.handle_task(batch, apply)
                 except Exception:
@@ -4056,6 +4166,11 @@ class VectorEngine:
                     node.sm.offloaded(OffloadFrom.COMMIT_WORKER)
                 if node.sm.task_queue.size() > 0:
                     self.set_task_ready(cid)
+            if sampled:
+                prof.observe(
+                    "rsm.handle", time.monotonic() - t0,
+                    time.thread_time() - c0, engine="rsm",
+                )
 
     def _snapshot_worker_main(self, worker: int) -> None:
         while not self._stopped.is_set():
@@ -4093,8 +4208,9 @@ class VectorEngine:
         """Cumulative per-step columnar counters (kernel steps, outbound
         messages by plane, lanes with commit advance, elections started,
         entries handed to the RSM) — derived host-side from the decoded
-        StepOutput, so reading them costs nothing on the device."""
-        return dict(self._sstats)
+        StepOutput, so reading them costs nothing on the device. Also
+        the kernel launches dispatched."""
+        return dict(self._sstats, launches=self.launch_no)
 
     def lease_stats(self) -> dict:
         """Cumulative lease read counters across all lanes: 'local' =
@@ -4327,9 +4443,6 @@ class VectorEngine:
         ev.wait(timeout)
 
     def stop(self, flush: bool = True) -> None:
-        rep = self.profiler.report()
-        if rep:
-            _plog.infof("vector engine stage profile:\n%s", rep)
         self.watchdog.close()
         if not flush:
             self._discard_pending = True

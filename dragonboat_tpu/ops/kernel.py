@@ -324,511 +324,514 @@ def _handle_message(s: RaftTensors, m, out, cfg: KernelConfig):
     from_slot = m["from_slot"]
     mterm = m["term"]
 
-    # ---- term preamble -----------------------------------------------------
-    local = mterm == 0
-    higher = present & ~local & (mterm > s.term)
-    lower = present & ~local & (mterm < s.term)
-    is_pv = mtype == MSG.REQUEST_PREVOTE
-    is_pvr = mtype == MSG.REQUEST_PREVOTE_RESP
-    # disruption defense (raft.go:1387-1409); a live leader's lease
-    # refuses a pre-vote poll the same way it refuses the vote
-    drop_rv = (
-        higher
-        & ((mtype == MSG.REQUEST_VOTE) | is_pv)
-        & s.check_quorum
-        & (m["hint"] != from_slot + 1)
-        & (s.leader != 0)
-        & (s.election_tick < s.election_timeout)
-    )
-    # a pre-vote poll never changes our term, and a GRANTED poll response
-    # echoes our prospective term back (the real bump happens only when
-    # the poll wins and the real campaign runs)
-    step_down = higher & ~drop_rv & ~is_pv & ~(is_pvr & ~m["reject"])
-    new_leader = jnp.where(_is_leader_msg(mtype), from_slot + 1, 0)
-    s = _become_follower(s, step_down, mterm, jnp.where(step_down, new_leader, s.leader))
-    # lower-term leader msg + check-quorum => NOOP response to free a stuck
-    # candidate (raft.go:1441-1447); a lower-term pre-vote poll is answered
-    # with a reject at OUR term so the poller abandons it; everything
-    # lower-term is then dropped
-    noop_resp = lower & _is_leader_msg(mtype) & s.check_quorum
-    pv_stale = lower & is_pv
-    dropped = lower | drop_rv
-    act = present & ~dropped
-
-    is_leader = s.role == ROLE.LEADER
-    is_cand = s.role == ROLE.CANDIDATE
-    is_precand = s.role == ROLE.PRE_CANDIDATE
-    is_obs = s.role == ROLE.OBSERVER
-    is_wit = s.role == ROLE.WITNESS
-    is_fol = s.role == ROLE.FOLLOWER
-
-    resp_type = jnp.where(noop_resp, MSG.NOOP, MSG.NONE)
-    resp_type = jnp.where(pv_stale, MSG.REQUEST_PREVOTE_RESP, resp_type)
-    resp_to = from_slot
-    resp_log_index = jnp.zeros_like(mterm)
-    resp_reject = pv_stale
-    resp_hint = jnp.zeros_like(mterm)
-    resp_hint2 = jnp.zeros_like(mterm)
-    # per-slot response term override (0 = stamp the lane's current term):
-    # pre-vote grants echo the poll's prospective term
-    pv_resp_term = jnp.zeros_like(mterm)
-
-    selfm = _self_mask(s)
-    from_onehot = jax.nn.one_hot(from_slot, P, dtype=bool)
-    known_from = jnp.any(s.member & from_onehot, axis=1)
-
-    # ---- RequestVote (any state) ------------------------------------------
-    rv = act & (mtype == MSG.REQUEST_VOTE) & (
-        is_fol | is_cand | is_precand | is_leader | is_wit
-    )
-    can_grant = (s.vote == 0) | (s.vote == from_slot + 1)
-    last_term = _term_at(s, s.last_index)
-    utd = (m["log_term"] > last_term) | (
-        (m["log_term"] == last_term) & (m["log_index"] >= s.last_index)
-    )
-    grant = rv & can_grant & utd
-    s = s._replace(
-        vote=jnp.where(grant, from_slot + 1, s.vote),
-        election_tick=jnp.where(grant, 0, s.election_tick),
-    )
-    resp_type = jnp.where(rv, MSG.REQUEST_VOTE_RESP, resp_type)
-    resp_reject = jnp.where(rv, ~grant, resp_reject)
-
-    # ---- RequestPreVote (voting states, cf. scalar handler tables) --------
-    # grant iff the poll's prospective term beats ours AND the poller's log
-    # is up to date; NOTHING in our state changes either way (no vote, no
-    # term adoption, no election-timer reset) — that is the phase's point
-    pv = act & is_pv & (is_fol | is_cand | is_precand | is_leader | is_wit)
-    grant_pv = pv & (mterm > s.term) & utd
-    resp_type = jnp.where(pv, MSG.REQUEST_PREVOTE_RESP, resp_type)
-    resp_reject = jnp.where(pv, ~grant_pv, resp_reject)
-    pv_resp_term = jnp.where(grant_pv, mterm, pv_resp_term)
-
-    # ---- RequestVoteResp (candidate) --------------------------------------
-    rvr = act & (mtype == MSG.REQUEST_VOTE_RESP) & is_cand & known_from
-    first_resp = rvr & ~jnp.any(s.vresp & from_onehot, axis=1)
-    s = s._replace(
-        vresp=jnp.where(first_resp[:, None] & from_onehot, True, s.vresp),
-        vgrant=jnp.where(
-            first_resp[:, None] & from_onehot, ~m["reject"][:, None], s.vgrant
-        ),
-    )
-    granted = jnp.sum(s.vgrant & s.voting, axis=1).astype(i32)
-    rejected = jnp.sum(s.vresp & ~s.vgrant & s.voting, axis=1).astype(i32)
-    q = _quorum(s)
-    win = rvr & (granted >= q)
-    lose = rvr & ~win & (rejected >= q)
-    noop_at = jnp.where(win, s.last_index + 1, 0)
-    s = _become_leader(s, win)
-    out["ctr_elections_won"] = out["ctr_elections_won"] + jnp.where(win, 1, 0)
-    out["noop_appended"] = jnp.maximum(out["noop_appended"], noop_at)
-    out["noop_term"] = jnp.maximum(out["noop_term"], jnp.where(win, s.term, 0))
-    s = _become_follower(s, lose, s.term, jnp.zeros_like(s.leader))
-
-    # ---- RequestPreVoteResp (pre-candidate) -------------------------------
-    # same tally planes as the real election (a lane is never candidate
-    # and pre-candidate at once); a won poll runs the REAL campaign, a
-    # lost one falls back to follower at the UNCHANGED term
-    pvr = act & is_pvr & is_precand & known_from
-    first_pvr = pvr & ~jnp.any(s.vresp & from_onehot, axis=1)
-    s = s._replace(
-        vresp=jnp.where(first_pvr[:, None] & from_onehot, True, s.vresp),
-        vgrant=jnp.where(
-            first_pvr[:, None] & from_onehot, ~m["reject"][:, None], s.vgrant
-        ),
-    )
-    granted_pv = jnp.sum(s.vgrant & s.voting, axis=1).astype(i32)
-    rejected_pv = jnp.sum(s.vresp & ~s.vgrant & s.voting, axis=1).astype(i32)
-    q = _quorum(s)
-    win_pv = pvr & (granted_pv >= q)
-    lose_pv = pvr & ~win_pv & (rejected_pv >= q)
-    s, out = _campaign(
-        s, win_pv, out, jnp.zeros_like(win_pv), force_real=win_pv
-    )
-    s = _become_follower(s, lose_pv, s.term, jnp.zeros_like(s.leader))
-
-    # ---- Election / TimeoutNow --------------------------------------------
-    ele = act & (mtype == MSG.ELECTION)
-    tno = act & (mtype == MSG.TIMEOUT_NOW) & is_fol
-    s, out = _campaign(s, ele | tno, out, transfer_hint=tno)
-
-    # per-slot append bases reported to the engine so the host can place
-    # payload bytes at the device-assigned indexes without guessing
-    prop_base = jnp.zeros_like(mterm)
-    rep_base = jnp.zeros_like(mterm)
-
-    # ---- Replicate (non-leader) -------------------------------------------
-    rep = act & (mtype == MSG.REPLICATE) & (
-        is_fol | is_obs | is_wit | is_cand | is_precand
-    )
-    # (pre-)candidate at same term: a leader exists -> become follower
-    # (raft.go:1944)
-    rep_demote = rep & (is_cand | is_precand)
-    s = _become_follower(
-        s, rep_demote, s.term, jnp.where(rep_demote, from_slot + 1, s.leader)
-    )
-    s = s._replace(
-        leader=jnp.where(rep, from_slot + 1, s.leader),
-        election_tick=jnp.where(rep, 0, s.election_tick),
-    )
-    prev = m["log_index"]
-    nent = m["n_entries"]
-    stale = rep & (prev < s.committed)
-    match_prev = _term_at(s, prev) == m["log_term"]
-    in_window = (prev >= s.first_index - 1) & (prev <= s.last_index)
-    ok = rep & ~stale & match_prev & in_window
-    rej = rep & ~stale & ~ok
-    out["ctr_replicate_rejects"] = out["ctr_replicate_rejects"] + jnp.where(
-        rej, 1, 0
-    )
-    # conflict scan over the E attached entries
-    if E > 0:
-        e_idx = prev[:, None] + 1 + jnp.arange(E, dtype=i32)[None, :]
-        e_valid = jnp.arange(E, dtype=i32)[None, :] < nent[:, None]
-        have = e_idx <= s.last_index[:, None]
-        exist_term = jnp.take_along_axis(s.log_term, e_idx % W, axis=1)
-        conflict = e_valid & (~have | (exist_term != m["entry_terms"]))
-        first_conf = jnp.min(
-            jnp.where(conflict, e_idx, jnp.iinfo(jnp.int32).max), axis=1
+    with jax.named_scope("election"):
+        # ---- term preamble -----------------------------------------------------
+        local = mterm == 0
+        higher = present & ~local & (mterm > s.term)
+        lower = present & ~local & (mterm < s.term)
+        is_pv = mtype == MSG.REQUEST_PREVOTE
+        is_pvr = mtype == MSG.REQUEST_PREVOTE_RESP
+        # disruption defense (raft.go:1387-1409); a live leader's lease
+        # refuses a pre-vote poll the same way it refuses the vote
+        drop_rv = (
+            higher
+            & ((mtype == MSG.REQUEST_VOTE) | is_pv)
+            & s.check_quorum
+            & (m["hint"] != from_slot + 1)
+            & (s.leader != 0)
+            & (s.election_tick < s.election_timeout)
         )
-        any_conf = jnp.any(conflict, axis=1)
-        do_append = ok & any_conf
-        # ring-slot write WITHOUT a per-entry loop: slot w receives absolute
-        # index i(w) = lo + ((w - lo) mod W) — the unique index in the
-        # written span congruent to w (nent <= E <= W guarantees at most
-        # one) — so the whole scatter is one (G,W) gather+select and the
-        # kernel cost is independent of E (the old form unrolled E one-hot
-        # scatters, which capped how many entries a message could carry)
-        w_idx = jnp.arange(W, dtype=i32)[None, :]
-        lo = jnp.where(do_append, first_conf, 1)
-        hi = prev + nent
-        i_w = lo[:, None] + jnp.mod(w_idx - lo[:, None], W)
-        written = do_append[:, None] & (i_w <= hi[:, None])
-        e_pos = jnp.clip(i_w - (prev[:, None] + 1), 0, E - 1)
-        terms_w = jnp.take_along_axis(m["entry_terms"], e_pos, axis=1)
-        cc_w = jnp.take_along_axis(m["entry_cc"], e_pos, axis=1)
-        log_term = jnp.where(written, terms_w, s.log_term)
-        log_cc = jnp.where(written, cc_w, s.log_is_cc)
-        new_last = jnp.where(do_append, prev + nent, s.last_index)
+        # a pre-vote poll never changes our term, and a GRANTED poll response
+        # echoes our prospective term back (the real bump happens only when
+        # the poll wins and the real campaign runs)
+        step_down = higher & ~drop_rv & ~is_pv & ~(is_pvr & ~m["reject"])
+        new_leader = jnp.where(_is_leader_msg(mtype), from_slot + 1, 0)
+        s = _become_follower(s, step_down, mterm, jnp.where(step_down, new_leader, s.leader))
+        # lower-term leader msg + check-quorum => NOOP response to free a stuck
+        # candidate (raft.go:1441-1447); a lower-term pre-vote poll is answered
+        # with a reject at OUR term so the poller abandons it; everything
+        # lower-term is then dropped
+        noop_resp = lower & _is_leader_msg(mtype) & s.check_quorum
+        pv_stale = lower & is_pv
+        dropped = lower | drop_rv
+        act = present & ~dropped
+
+        is_leader = s.role == ROLE.LEADER
+        is_cand = s.role == ROLE.CANDIDATE
+        is_precand = s.role == ROLE.PRE_CANDIDATE
+        is_obs = s.role == ROLE.OBSERVER
+        is_wit = s.role == ROLE.WITNESS
+        is_fol = s.role == ROLE.FOLLOWER
+
+        resp_type = jnp.where(noop_resp, MSG.NOOP, MSG.NONE)
+        resp_type = jnp.where(pv_stale, MSG.REQUEST_PREVOTE_RESP, resp_type)
+        resp_to = from_slot
+        resp_log_index = jnp.zeros_like(mterm)
+        resp_reject = pv_stale
+        resp_hint = jnp.zeros_like(mterm)
+        resp_hint2 = jnp.zeros_like(mterm)
+        # per-slot response term override (0 = stamp the lane's current term):
+        # pre-vote grants echo the poll's prospective term
+        pv_resp_term = jnp.zeros_like(mterm)
+
+        selfm = _self_mask(s)
+        from_onehot = jax.nn.one_hot(from_slot, P, dtype=bool)
+        known_from = jnp.any(s.member & from_onehot, axis=1)
+
+        # ---- RequestVote (any state) ------------------------------------------
+        rv = act & (mtype == MSG.REQUEST_VOTE) & (
+            is_fol | is_cand | is_precand | is_leader | is_wit
+        )
+        can_grant = (s.vote == 0) | (s.vote == from_slot + 1)
+        last_term = _term_at(s, s.last_index)
+        utd = (m["log_term"] > last_term) | (
+            (m["log_term"] == last_term) & (m["log_index"] >= s.last_index)
+        )
+        grant = rv & can_grant & utd
         s = s._replace(
-            log_term=log_term,
-            log_is_cc=log_cc,
-            last_index=new_last,
-            unsaved_from=jnp.where(
-                do_append, jnp.minimum(s.unsaved_from, first_conf), s.unsaved_from
+            vote=jnp.where(grant, from_slot + 1, s.vote),
+            election_tick=jnp.where(grant, 0, s.election_tick),
+        )
+        resp_type = jnp.where(rv, MSG.REQUEST_VOTE_RESP, resp_type)
+        resp_reject = jnp.where(rv, ~grant, resp_reject)
+
+        # ---- RequestPreVote (voting states, cf. scalar handler tables) --------
+        # grant iff the poll's prospective term beats ours AND the poller's log
+        # is up to date; NOTHING in our state changes either way (no vote, no
+        # term adoption, no election-timer reset) — that is the phase's point
+        pv = act & is_pv & (is_fol | is_cand | is_precand | is_leader | is_wit)
+        grant_pv = pv & (mterm > s.term) & utd
+        resp_type = jnp.where(pv, MSG.REQUEST_PREVOTE_RESP, resp_type)
+        resp_reject = jnp.where(pv, ~grant_pv, resp_reject)
+        pv_resp_term = jnp.where(grant_pv, mterm, pv_resp_term)
+
+        # ---- RequestVoteResp (candidate) --------------------------------------
+        rvr = act & (mtype == MSG.REQUEST_VOTE_RESP) & is_cand & known_from
+        first_resp = rvr & ~jnp.any(s.vresp & from_onehot, axis=1)
+        s = s._replace(
+            vresp=jnp.where(first_resp[:, None] & from_onehot, True, s.vresp),
+            vgrant=jnp.where(
+                first_resp[:, None] & from_onehot, ~m["reject"][:, None], s.vgrant
             ),
         )
-    ack_to = prev + nent
-    new_commit = jnp.clip(jnp.minimum(ack_to, m["commit"]), s.committed, s.last_index)
-    s = s._replace(committed=jnp.where(ok, new_commit, s.committed))
-    rep_base = jnp.where(ok, prev + 1, rep_base)
-    resp_type = jnp.where(rep, MSG.REPLICATE_RESP, resp_type)
-    resp_log_index = jnp.where(
-        stale, s.committed, jnp.where(ok, ack_to, jnp.where(rej, prev, resp_log_index))
-    )
-    resp_reject = jnp.where(rej, True, resp_reject)
-    resp_hint = jnp.where(rej, s.last_index, resp_hint)
+        granted = jnp.sum(s.vgrant & s.voting, axis=1).astype(i32)
+        rejected = jnp.sum(s.vresp & ~s.vgrant & s.voting, axis=1).astype(i32)
+        q = _quorum(s)
+        win = rvr & (granted >= q)
+        lose = rvr & ~win & (rejected >= q)
+        noop_at = jnp.where(win, s.last_index + 1, 0)
+        s = _become_leader(s, win)
+        out["ctr_elections_won"] = out["ctr_elections_won"] + jnp.where(win, 1, 0)
+        out["noop_appended"] = jnp.maximum(out["noop_appended"], noop_at)
+        out["noop_term"] = jnp.maximum(out["noop_term"], jnp.where(win, s.term, 0))
+        s = _become_follower(s, lose, s.term, jnp.zeros_like(s.leader))
 
-    # ---- Heartbeat (non-leader) -------------------------------------------
-    hb = act & (mtype == MSG.HEARTBEAT) & (
-        is_fol | is_obs | is_wit | is_cand | is_precand
-    )
-    hb_demote = hb & (is_cand | is_precand)
-    s = _become_follower(
-        s, hb_demote, s.term, jnp.where(hb_demote, from_slot + 1, s.leader)
-    )
-    s = s._replace(
-        leader=jnp.where(hb, from_slot + 1, s.leader),
-        election_tick=jnp.where(hb, 0, s.election_tick),
-        committed=jnp.where(
-            hb, jnp.clip(m["commit"], s.committed, s.last_index), s.committed
-        ),
-    )
-    resp_type = jnp.where(hb, MSG.HEARTBEAT_RESP, resp_type)
-    # echo the leader's lease round tag (log_index, 0 when leases off)
-    resp_log_index = jnp.where(hb, m["log_index"], resp_log_index)
-    resp_hint = jnp.where(hb, m["hint"], resp_hint)
-    resp_hint2 = jnp.where(hb, m["hint_high"], resp_hint2)
+        # ---- RequestPreVoteResp (pre-candidate) -------------------------------
+        # same tally planes as the real election (a lane is never candidate
+        # and pre-candidate at once); a won poll runs the REAL campaign, a
+        # lost one falls back to follower at the UNCHANGED term
+        pvr = act & is_pvr & is_precand & known_from
+        first_pvr = pvr & ~jnp.any(s.vresp & from_onehot, axis=1)
+        s = s._replace(
+            vresp=jnp.where(first_pvr[:, None] & from_onehot, True, s.vresp),
+            vgrant=jnp.where(
+                first_pvr[:, None] & from_onehot, ~m["reject"][:, None], s.vgrant
+            ),
+        )
+        granted_pv = jnp.sum(s.vgrant & s.voting, axis=1).astype(i32)
+        rejected_pv = jnp.sum(s.vresp & ~s.vgrant & s.voting, axis=1).astype(i32)
+        q = _quorum(s)
+        win_pv = pvr & (granted_pv >= q)
+        lose_pv = pvr & ~win_pv & (rejected_pv >= q)
+        s, out = _campaign(
+            s, win_pv, out, jnp.zeros_like(win_pv), force_real=win_pv
+        )
+        s = _become_follower(s, lose_pv, s.term, jnp.zeros_like(s.leader))
 
-    # ---- ReplicateResp (leader) -------------------------------------------
-    rr = act & (mtype == MSG.REPLICATE_RESP) & (s.role == ROLE.LEADER) & known_from
-    fr = from_onehot  # [G,P]
-    prev_rstate = s.rstate
-    racc = rr & ~m["reject"]
-    moved = racc & (m["log_index"] > jnp.sum(jnp.where(fr, s.match, 0), axis=1))
-    s = s._replace(
-        ract=jnp.where(rr[:, None] & fr, True, s.ract),
-        match=jnp.where(
-            racc[:, None] & fr, jnp.maximum(s.match, m["log_index"][:, None]), s.match
-        ),
-        next=jnp.where(
-            racc[:, None] & fr,
-            jnp.maximum(s.next, m["log_index"][:, None] + 1),
-            s.next,
-        ),
-    )
-    # respondedTo(): RETRY -> REPLICATE; SNAPSHOT -> RETRY once caught up
-    # (remote.go:145-153); WAIT -> RETRY on movement (tryUpdate)
-    st = s.rstate
-    st = jnp.where(
-        moved[:, None] & fr & (st == RSTATE.WAIT), RSTATE.RETRY, st
-    )
-    st = jnp.where(moved[:, None] & fr & (st == RSTATE.RETRY), RSTATE.REPLICATE, st)
-    caught = s.match >= s.snap_sent
-    st = jnp.where(
-        moved[:, None] & fr & (st == RSTATE.SNAPSHOT) & caught, RSTATE.RETRY, st
-    )
-    s = s._replace(rstate=st)
-    # rejection: flow-control backoff (remote.go:155-171)
-    rrej = rr & m["reject"]
-    in_repl = jnp.any(fr & (prev_rstate == RSTATE.REPLICATE), axis=1)
-    cur_match = jnp.sum(jnp.where(fr, s.match, 0), axis=1)
-    cur_next = jnp.sum(jnp.where(fr, s.next, 0), axis=1)
-    valid_repl = rrej & in_repl & (m["log_index"] > cur_match)
-    valid_probe = rrej & ~in_repl & (cur_next - 1 == m["log_index"])
-    nn = jnp.where(
-        valid_repl,
-        cur_match + 1,
-        jnp.maximum(1, jnp.minimum(m["log_index"], m["hint"] + 1)),
-    )
-    dec = valid_repl | valid_probe
-    s = s._replace(
-        next=jnp.where(dec[:, None] & fr, nn[:, None], s.next),
-        rstate=jnp.where(
-            dec[:, None] & fr, RSTATE.RETRY, s.rstate
-        ),
-    )
-    # transfer fast path: target caught up => TimeoutNow (raft.go:1679-1684)
-    tt = s.transfer_to
-    t_caught = (
-        racc
-        & (tt != 0)
-        & (from_slot + 1 == tt)
-        & (jnp.sum(jnp.where(fr, s.match, 0), axis=1) == s.last_index)
-    )
-    out["send_flags"] = jnp.where(
-        t_caught[:, None] & fr, out["send_flags"] | SEND_TIMEOUT_NOW, out["send_flags"]
-    )
+        # ---- Election / TimeoutNow --------------------------------------------
+        ele = act & (mtype == MSG.ELECTION)
+        tno = act & (mtype == MSG.TIMEOUT_NOW) & is_fol
+        s, out = _campaign(s, ele | tno, out, transfer_hint=tno)
 
-    # ---- HeartbeatResp (leader) -------------------------------------------
-    hr = act & (mtype == MSG.HEARTBEAT_RESP) & (s.role == ROLE.LEADER) & known_from
-    s = s._replace(
-        ract=jnp.where(hr[:, None] & fr, True, s.ract),
-        rstate=jnp.where(
-            hr[:, None] & fr & (s.rstate == RSTATE.WAIT), RSTATE.RETRY, s.rstate
-        ),
-    )
-    # a peer whose match lags gets a (possibly empty) Replicate probe; the
-    # reject/backoff cycle then recovers lost optimistic sends
-    # (cf. raft.go:1794-1800 handleLeaderHeartbeatResp)
-    out["force_probe"] = out["force_probe"] | (
-        hr[:, None] & fr & (s.match < s.last_index[:, None])
-    )
-    # readindex leadership confirmation (raft.go:1736-1756)
-    R = s.ri_ctx.shape[1]
-    hint_match = (
-        hr[:, None]
-        & (s.ri_ctx == m["hint"][:, None])
-        & (s.ri_ctx2 == m["hint_high"][:, None])
-        & (s.ri_ctx != 0)
-    )
-    frombit = (jnp.int32(1) << from_slot)[:, None]
-    s = s._replace(ri_acks=jnp.where(hint_match, s.ri_acks | frombit, s.ri_acks))
-    # lease round ack (scalar: _handle_leader_heartbeat_resp): the follower
-    # echoed the open round's tick tag in log_index; collect voting acks and
-    # at quorum extend the lease to round-start + election_timeout - margin —
-    # strictly inside the window in which no other node can win an election
-    tag_match = (
-        hr
-        & s.lease_on
-        & (m["log_index"] != 0)
-        & (m["log_index"] == s.hb_round_tick)
-        & jnp.any(fr & s.voting, axis=1)
-    )
-    new_bits = jnp.where(tag_match, s.hb_ack_bits | frombit[:, 0], s.hb_ack_bits)
-    ackn = _popcount(new_bits)
-    grant = (
-        hr
-        & s.lease_on
-        & s.clock_ok
-        & (s.hb_round_tick != 0)
-        & (ackn + 1 >= _quorum(s))
-    )
-    s = s._replace(
-        hb_ack_bits=new_bits,
-        lease_until=jnp.where(
-            grant,
-            jnp.maximum(
+        # per-slot append bases reported to the engine so the host can place
+        # payload bytes at the device-assigned indexes without guessing
+        prop_base = jnp.zeros_like(mterm)
+        rep_base = jnp.zeros_like(mterm)
+
+    with jax.named_scope("follower_append"):
+        # ---- Replicate (non-leader) -------------------------------------------
+        rep = act & (mtype == MSG.REPLICATE) & (
+            is_fol | is_obs | is_wit | is_cand | is_precand
+        )
+        # (pre-)candidate at same term: a leader exists -> become follower
+        # (raft.go:1944)
+        rep_demote = rep & (is_cand | is_precand)
+        s = _become_follower(
+            s, rep_demote, s.term, jnp.where(rep_demote, from_slot + 1, s.leader)
+        )
+        s = s._replace(
+            leader=jnp.where(rep, from_slot + 1, s.leader),
+            election_tick=jnp.where(rep, 0, s.election_tick),
+        )
+        prev = m["log_index"]
+        nent = m["n_entries"]
+        stale = rep & (prev < s.committed)
+        match_prev = _term_at(s, prev) == m["log_term"]
+        in_window = (prev >= s.first_index - 1) & (prev <= s.last_index)
+        ok = rep & ~stale & match_prev & in_window
+        rej = rep & ~stale & ~ok
+        out["ctr_replicate_rejects"] = out["ctr_replicate_rejects"] + jnp.where(
+            rej, 1, 0
+        )
+        # conflict scan over the E attached entries
+        if E > 0:
+            e_idx = prev[:, None] + 1 + jnp.arange(E, dtype=i32)[None, :]
+            e_valid = jnp.arange(E, dtype=i32)[None, :] < nent[:, None]
+            have = e_idx <= s.last_index[:, None]
+            exist_term = jnp.take_along_axis(s.log_term, e_idx % W, axis=1)
+            conflict = e_valid & (~have | (exist_term != m["entry_terms"]))
+            first_conf = jnp.min(
+                jnp.where(conflict, e_idx, jnp.iinfo(jnp.int32).max), axis=1
+            )
+            any_conf = jnp.any(conflict, axis=1)
+            do_append = ok & any_conf
+            # ring-slot write WITHOUT a per-entry loop: slot w receives absolute
+            # index i(w) = lo + ((w - lo) mod W) — the unique index in the
+            # written span congruent to w (nent <= E <= W guarantees at most
+            # one) — so the whole scatter is one (G,W) gather+select and the
+            # kernel cost is independent of E (the old form unrolled E one-hot
+            # scatters, which capped how many entries a message could carry)
+            w_idx = jnp.arange(W, dtype=i32)[None, :]
+            lo = jnp.where(do_append, first_conf, 1)
+            hi = prev + nent
+            i_w = lo[:, None] + jnp.mod(w_idx - lo[:, None], W)
+            written = do_append[:, None] & (i_w <= hi[:, None])
+            e_pos = jnp.clip(i_w - (prev[:, None] + 1), 0, E - 1)
+            terms_w = jnp.take_along_axis(m["entry_terms"], e_pos, axis=1)
+            cc_w = jnp.take_along_axis(m["entry_cc"], e_pos, axis=1)
+            log_term = jnp.where(written, terms_w, s.log_term)
+            log_cc = jnp.where(written, cc_w, s.log_is_cc)
+            new_last = jnp.where(do_append, prev + nent, s.last_index)
+            s = s._replace(
+                log_term=log_term,
+                log_is_cc=log_cc,
+                last_index=new_last,
+                unsaved_from=jnp.where(
+                    do_append, jnp.minimum(s.unsaved_from, first_conf), s.unsaved_from
+                ),
+            )
+        ack_to = prev + nent
+        new_commit = jnp.clip(jnp.minimum(ack_to, m["commit"]), s.committed, s.last_index)
+        s = s._replace(committed=jnp.where(ok, new_commit, s.committed))
+        rep_base = jnp.where(ok, prev + 1, rep_base)
+        resp_type = jnp.where(rep, MSG.REPLICATE_RESP, resp_type)
+        resp_log_index = jnp.where(
+            stale, s.committed, jnp.where(ok, ack_to, jnp.where(rej, prev, resp_log_index))
+        )
+        resp_reject = jnp.where(rej, True, resp_reject)
+        resp_hint = jnp.where(rej, s.last_index, resp_hint)
+
+        # ---- Heartbeat (non-leader) -------------------------------------------
+        hb = act & (mtype == MSG.HEARTBEAT) & (
+            is_fol | is_obs | is_wit | is_cand | is_precand
+        )
+        hb_demote = hb & (is_cand | is_precand)
+        s = _become_follower(
+            s, hb_demote, s.term, jnp.where(hb_demote, from_slot + 1, s.leader)
+        )
+        s = s._replace(
+            leader=jnp.where(hb, from_slot + 1, s.leader),
+            election_tick=jnp.where(hb, 0, s.election_tick),
+            committed=jnp.where(
+                hb, jnp.clip(m["commit"], s.committed, s.last_index), s.committed
+            ),
+        )
+        resp_type = jnp.where(hb, MSG.HEARTBEAT_RESP, resp_type)
+        # echo the leader's lease round tag (log_index, 0 when leases off)
+        resp_log_index = jnp.where(hb, m["log_index"], resp_log_index)
+        resp_hint = jnp.where(hb, m["hint"], resp_hint)
+        resp_hint2 = jnp.where(hb, m["hint_high"], resp_hint2)
+
+    with jax.named_scope("leader_acks"):
+        # ---- ReplicateResp (leader) -------------------------------------------
+        rr = act & (mtype == MSG.REPLICATE_RESP) & (s.role == ROLE.LEADER) & known_from
+        fr = from_onehot  # [G,P]
+        prev_rstate = s.rstate
+        racc = rr & ~m["reject"]
+        moved = racc & (m["log_index"] > jnp.sum(jnp.where(fr, s.match, 0), axis=1))
+        s = s._replace(
+            ract=jnp.where(rr[:, None] & fr, True, s.ract),
+            match=jnp.where(
+                racc[:, None] & fr, jnp.maximum(s.match, m["log_index"][:, None]), s.match
+            ),
+            next=jnp.where(
+                racc[:, None] & fr,
+                jnp.maximum(s.next, m["log_index"][:, None] + 1),
+                s.next,
+            ),
+        )
+        # respondedTo(): RETRY -> REPLICATE; SNAPSHOT -> RETRY once caught up
+        # (remote.go:145-153); WAIT -> RETRY on movement (tryUpdate)
+        st = s.rstate
+        st = jnp.where(
+            moved[:, None] & fr & (st == RSTATE.WAIT), RSTATE.RETRY, st
+        )
+        st = jnp.where(moved[:, None] & fr & (st == RSTATE.RETRY), RSTATE.REPLICATE, st)
+        caught = s.match >= s.snap_sent
+        st = jnp.where(
+            moved[:, None] & fr & (st == RSTATE.SNAPSHOT) & caught, RSTATE.RETRY, st
+        )
+        s = s._replace(rstate=st)
+        # rejection: flow-control backoff (remote.go:155-171)
+        rrej = rr & m["reject"]
+        in_repl = jnp.any(fr & (prev_rstate == RSTATE.REPLICATE), axis=1)
+        cur_match = jnp.sum(jnp.where(fr, s.match, 0), axis=1)
+        cur_next = jnp.sum(jnp.where(fr, s.next, 0), axis=1)
+        valid_repl = rrej & in_repl & (m["log_index"] > cur_match)
+        valid_probe = rrej & ~in_repl & (cur_next - 1 == m["log_index"])
+        nn = jnp.where(
+            valid_repl,
+            cur_match + 1,
+            jnp.maximum(1, jnp.minimum(m["log_index"], m["hint"] + 1)),
+        )
+        dec = valid_repl | valid_probe
+        s = s._replace(
+            next=jnp.where(dec[:, None] & fr, nn[:, None], s.next),
+            rstate=jnp.where(
+                dec[:, None] & fr, RSTATE.RETRY, s.rstate
+            ),
+        )
+        # transfer fast path: target caught up => TimeoutNow (raft.go:1679-1684)
+        tt = s.transfer_to
+        t_caught = (
+            racc
+            & (tt != 0)
+            & (from_slot + 1 == tt)
+            & (jnp.sum(jnp.where(fr, s.match, 0), axis=1) == s.last_index)
+        )
+        out["send_flags"] = jnp.where(
+            t_caught[:, None] & fr, out["send_flags"] | SEND_TIMEOUT_NOW, out["send_flags"]
+        )
+
+        # ---- HeartbeatResp (leader) -------------------------------------------
+        hr = act & (mtype == MSG.HEARTBEAT_RESP) & (s.role == ROLE.LEADER) & known_from
+        s = s._replace(
+            ract=jnp.where(hr[:, None] & fr, True, s.ract),
+            rstate=jnp.where(
+                hr[:, None] & fr & (s.rstate == RSTATE.WAIT), RSTATE.RETRY, s.rstate
+            ),
+        )
+        # a peer whose match lags gets a (possibly empty) Replicate probe; the
+        # reject/backoff cycle then recovers lost optimistic sends
+        # (cf. raft.go:1794-1800 handleLeaderHeartbeatResp)
+        out["force_probe"] = out["force_probe"] | (
+            hr[:, None] & fr & (s.match < s.last_index[:, None])
+        )
+        # readindex leadership confirmation (raft.go:1736-1756)
+        R = s.ri_ctx.shape[1]
+        hint_match = (
+            hr[:, None]
+            & (s.ri_ctx == m["hint"][:, None])
+            & (s.ri_ctx2 == m["hint_high"][:, None])
+            & (s.ri_ctx != 0)
+        )
+        frombit = (jnp.int32(1) << from_slot)[:, None]
+        s = s._replace(ri_acks=jnp.where(hint_match, s.ri_acks | frombit, s.ri_acks))
+        # lease round ack (scalar: _handle_leader_heartbeat_resp): the follower
+        # echoed the open round's tick tag in log_index; collect voting acks and
+        # at quorum extend the lease to round-start + election_timeout - margin —
+        # strictly inside the window in which no other node can win an election
+        tag_match = (
+            hr
+            & s.lease_on
+            & (m["log_index"] != 0)
+            & (m["log_index"] == s.hb_round_tick)
+            & jnp.any(fr & s.voting, axis=1)
+        )
+        new_bits = jnp.where(tag_match, s.hb_ack_bits | frombit[:, 0], s.hb_ack_bits)
+        ackn = _popcount(new_bits)
+        grant = (
+            hr
+            & s.lease_on
+            & s.clock_ok
+            & (s.hb_round_tick != 0)
+            & (ackn + 1 >= _quorum(s))
+        )
+        s = s._replace(
+            hb_ack_bits=new_bits,
+            lease_until=jnp.where(
+                grant,
+                jnp.maximum(
+                    s.lease_until,
+                    s.hb_round_tick + s.election_timeout - s.lease_margin,
+                ),
                 s.lease_until,
-                s.hb_round_tick + s.election_timeout - s.lease_margin,
             ),
-            s.lease_until,
-        ),
-    )
+        )
 
-    # ---- ReadIndex (leader) ------------------------------------------------
-    ri = act & (mtype == MSG.READ_INDEX) & (s.role == ROLE.LEADER)
-    qq = _quorum(s)
-    single = _num_voting(s) == 1
-    committed_this_term = _term_at(s, s.committed) == s.term
-    ok_ri = ri & (single | committed_this_term)
-    slot_free = s.ri_count < R
-    # lease fast path: a live lease makes the local committed index the
-    # linearization point — the read rides the immediate-ready mechanism
-    # (acks = -1) instead of opening a quorum heartbeat round. Expired /
-    # revoked / suspect lanes fall through to the quorum path below
-    # (degradation, not danger).
-    lease_valid = (
-        s.lease_on
-        & s.clock_ok
-        & (s.tick_count < s.lease_until)
-        & (s.transfer_to == 0)
-    )
-    imm_lease = ok_ri & ~single & lease_valid & slot_free
-    enq = ok_ri & ~single & ~lease_valid & slot_free
-    pos = s.ri_count
-    posm = jax.nn.one_hot(pos, R, dtype=bool) & enq[:, None]
-    s = s._replace(
-        ri_ctx=jnp.where(posm, m["hint"][:, None], s.ri_ctx),
-        ri_ctx2=jnp.where(posm, m["hint_high"][:, None], s.ri_ctx2),
-        ri_index=jnp.where(posm, s.committed[:, None], s.ri_index),
-        ri_acks=jnp.where(posm, 0, s.ri_acks),
-        ri_count=jnp.where(enq, s.ri_count + 1, s.ri_count),
-    )
-    # heartbeat broadcast with ctx hint
-    others_v = s.voting & ~selfm
-    out["send_flags"] = jnp.where(
-        enq[:, None] & others_v, out["send_flags"] | SEND_HEARTBEAT, out["send_flags"]
-    )
-    # counted at the send decision (the scalar core's per-target
-    # broadcast_heartbeat_message(ctx)), not at end-of-step gating
-    out["ctr_heartbeats_sent"] = out["ctr_heartbeats_sent"] + jnp.sum(
-        enq[:, None] & others_v, axis=1
-    ).astype(i32)
-    out["send_hint"] = jnp.where(
-        enq[:, None] & others_v, m["hint"][:, None], out["send_hint"]
-    )
-    out["send_hint2"] = jnp.where(
-        enq[:, None] & others_v, m["hint_high"][:, None], out["send_hint2"]
-    )
-    # single-node or lease-served: instantly ready (delivered via the ready
-    # queue at step end)
-    imm = (ok_ri & single) | imm_lease
-    posm2 = jax.nn.one_hot(s.ri_count, R, dtype=bool) & imm[:, None]
-    s = s._replace(
-        ri_ctx=jnp.where(posm2, m["hint"][:, None], s.ri_ctx),
-        ri_ctx2=jnp.where(posm2, m["hint_high"][:, None], s.ri_ctx2),
-        ri_index=jnp.where(posm2, s.committed[:, None], s.ri_index),
-        ri_acks=jnp.where(posm2, jnp.int32(-1), s.ri_acks),
-        ri_count=jnp.where(imm, s.ri_count + 1, s.ri_count),
-    )
-    out["dropped_readindex"] = out["dropped_readindex"] + jnp.where(
-        (ri & ~ok_ri) | (ok_ri & ~single & ~slot_free), 1, 0
-    )
-    out["lease_served"] = out["lease_served"] + jnp.where(imm_lease, 1, 0)
-    out["lease_fallback"] = out["lease_fallback"] + jnp.where(
-        enq & s.lease_on, 1, 0
-    )
-
-    # ---- Propose (leader) --------------------------------------------------
-    # Host routes proposals to the group's leader replica; a lane that is not
-    # leader reports the forward target instead (host-side forwarding
-    # replaces the reference's follower Propose relay, raft.go:1839-1851).
-    pp = act & (mtype == MSG.PROPOSE)
-    pok = pp & (s.role == ROLE.LEADER) & (s.transfer_to == 0)
-    # config-change entries: at most one pending (raft.go:1587-1606).
-    # HOST INVARIANT: the engine packs a config-change entry alone in its own
-    # single-entry PROPOSE message (never mixed with regular entries), so the
-    # pending check is all-or-nothing per message.
-    e_in_msg = jnp.arange(E, dtype=i32)[None, :] < nent[:, None]
-    has_cc = jnp.any(m["entry_cc"] & e_in_msg, axis=1)
-    cc_allowed = pok & has_cc & ~s.pending_cc
-    cc_stripped = pok & has_cc & s.pending_cc
-    s = s._replace(pending_cc=jnp.where(cc_allowed, True, s.pending_cc))
-    out["dropped_cc"] = out["dropped_cc"] | cc_stripped
-    room = s.last_index - s.first_index + 1 + nent <= W
-    can_append = pok & room
-    prop_base = jnp.where(can_append, s.last_index + 1, prop_base)
-    # append up to E entries at the current term — same loop-free ring-slot
-    # scatter as the Replicate path: slot w gets index lo + ((w - lo) mod W)
-    if E > 0:
-        eff_cc = m["entry_cc"] & cc_allowed[:, None]
-        w_idx = jnp.arange(W, dtype=i32)[None, :]
-        a_lo = s.last_index + 1
-        a_hi = s.last_index + nent
-        i_w = a_lo[:, None] + jnp.mod(w_idx - a_lo[:, None], W)
-        written = can_append[:, None] & (i_w <= a_hi[:, None])
-        e_pos = jnp.clip(i_w - a_lo[:, None], 0, E - 1)
-        cc_w = jnp.take_along_axis(eff_cc, e_pos, axis=1)
-        log_term = jnp.where(written, s.term[:, None], s.log_term)
-        log_cc = jnp.where(written, cc_w, s.log_is_cc)
-        new_last = jnp.where(can_append, s.last_index + nent, s.last_index)
+    with jax.named_scope("readindex"):
+        # ---- ReadIndex (leader) ------------------------------------------------
+        ri = act & (mtype == MSG.READ_INDEX) & (s.role == ROLE.LEADER)
+        qq = _quorum(s)
+        single = _num_voting(s) == 1
+        committed_this_term = _term_at(s, s.committed) == s.term
+        ok_ri = ri & (single | committed_this_term)
+        slot_free = s.ri_count < R
+        # lease fast path: a live lease makes the local committed index the
+        # linearization point — the read rides the immediate-ready mechanism
+        # (acks = -1) instead of opening a quorum heartbeat round. Expired /
+        # revoked / suspect lanes fall through to the quorum path below
+        # (degradation, not danger).
+        lease_valid = (
+            s.lease_on
+            & s.clock_ok
+            & (s.tick_count < s.lease_until)
+            & (s.transfer_to == 0)
+        )
+        imm_lease = ok_ri & ~single & lease_valid & slot_free
+        enq = ok_ri & ~single & ~lease_valid & slot_free
+        pos = s.ri_count
+        posm = jax.nn.one_hot(pos, R, dtype=bool) & enq[:, None]
         s = s._replace(
-            log_term=log_term,
-            log_is_cc=log_cc,
-            last_index=new_last,
-            match=jnp.where(selfm & can_append[:, None], new_last[:, None], s.match),
+            ri_ctx=jnp.where(posm, m["hint"][:, None], s.ri_ctx),
+            ri_ctx2=jnp.where(posm, m["hint_high"][:, None], s.ri_ctx2),
+            ri_index=jnp.where(posm, s.committed[:, None], s.ri_index),
+            ri_acks=jnp.where(posm, 0, s.ri_acks),
+            ri_count=jnp.where(enq, s.ri_count + 1, s.ri_count),
         )
-    out["dropped_propose"] = out["dropped_propose"] + jnp.where(
-        pp & ~can_append, nent, 0
-    )
-    out["fwd_leader"] = jnp.where(pp & ~pok, s.leader, out["fwd_leader"])
-    out["log_full"] = out["log_full"] | (pok & ~room)
-
-    # ---- ReadIndexResp (follower/observer) --------------------------------
-    rir = act & (mtype == MSG.READ_INDEX_RESP) & (is_fol | is_obs)
-    s = s._replace(
-        leader=jnp.where(rir, from_slot + 1, s.leader),
-        election_tick=jnp.where(rir, 0, s.election_tick),
-    )
-    # deliver through the ready queue
-    posm3 = jax.nn.one_hot(s.ri_count, R, dtype=bool) & (
-        rir & (s.ri_count < R)
-    )[:, None]
-    s = s._replace(
-        ri_ctx=jnp.where(posm3, m["hint"][:, None], s.ri_ctx),
-        ri_ctx2=jnp.where(posm3, m["hint_high"][:, None], s.ri_ctx2),
-        ri_index=jnp.where(posm3, m["log_index"][:, None], s.ri_index),
-        ri_acks=jnp.where(posm3, jnp.int32(-1), s.ri_acks),
-        ri_count=jnp.where(rir & (s.ri_count < R), s.ri_count + 1, s.ri_count),
-    )
-
-    # ---- LeaderTransfer (leader) ------------------------------------------
-    lt = act & (mtype == MSG.LEADER_TRANSFER) & (s.role == ROLE.LEADER)
-    target = m["hint"]  # slot+1
-    lt_ok = lt & (s.transfer_to == 0) & (target != s.self_slot + 1) & (target != 0)
-    s = s._replace(
-        transfer_to=jnp.where(lt_ok, target, s.transfer_to),
-        election_tick=jnp.where(lt_ok, 0, s.election_tick),
-    )
-    t_oh = jax.nn.one_hot(jnp.maximum(target - 1, 0), P, dtype=bool)
-    t_match = jnp.sum(jnp.where(t_oh, s.match, 0), axis=1)
-    fast = lt_ok & (t_match == s.last_index)
-    out["send_flags"] = jnp.where(
-        fast[:, None] & t_oh, out["send_flags"] | SEND_TIMEOUT_NOW, out["send_flags"]
-    )
-
-    # ---- Unreachable / SnapshotStatus (leader) -----------------------------
-    un = act & (mtype == MSG.UNREACHABLE) & (s.role == ROLE.LEADER) & known_from
-    s = s._replace(
-        rstate=jnp.where(
-            un[:, None] & fr & (s.rstate == RSTATE.REPLICATE), RSTATE.RETRY, s.rstate
+        # heartbeat broadcast with ctx hint
+        others_v = s.voting & ~selfm
+        out["send_flags"] = jnp.where(
+            enq[:, None] & others_v, out["send_flags"] | SEND_HEARTBEAT, out["send_flags"]
         )
-    )
-    st2 = act & (mtype == MSG.SNAPSHOT_STATUS) & (s.role == ROLE.LEADER) & known_from
-    in_snap = fr & (s.rstate == RSTATE.SNAPSHOT)
-    s = s._replace(
-        snap_sent=jnp.where(
-            st2[:, None] & in_snap & m["reject"][:, None], 0, s.snap_sent
-        ),
-        # becomeWait: next = max(match+1, snap_sent+1), state WAIT
-        next=jnp.where(
-            st2[:, None] & in_snap,
-            jnp.maximum(s.match + 1, s.snap_sent + 1),
-            s.next,
-        ),
-        rstate=jnp.where(st2[:, None] & in_snap, RSTATE.WAIT, s.rstate),
-    )
+        # counted at the send decision (the scalar core's per-target
+        # broadcast_heartbeat_message(ctx)), not at end-of-step gating
+        out["ctr_heartbeats_sent"] = out["ctr_heartbeats_sent"] + jnp.sum(
+            enq[:, None] & others_v, axis=1
+        ).astype(i32)
+        out["send_hint"] = jnp.where(
+            enq[:, None] & others_v, m["hint"][:, None], out["send_hint"]
+        )
+        out["send_hint2"] = jnp.where(
+            enq[:, None] & others_v, m["hint_high"][:, None], out["send_hint2"]
+        )
+        # single-node or lease-served: instantly ready (delivered via the ready
+        # queue at step end)
+        imm = (ok_ri & single) | imm_lease
+        posm2 = jax.nn.one_hot(s.ri_count, R, dtype=bool) & imm[:, None]
+        s = s._replace(
+            ri_ctx=jnp.where(posm2, m["hint"][:, None], s.ri_ctx),
+            ri_ctx2=jnp.where(posm2, m["hint_high"][:, None], s.ri_ctx2),
+            ri_index=jnp.where(posm2, s.committed[:, None], s.ri_index),
+            ri_acks=jnp.where(posm2, jnp.int32(-1), s.ri_acks),
+            ri_count=jnp.where(imm, s.ri_count + 1, s.ri_count),
+        )
+        out["dropped_readindex"] = out["dropped_readindex"] + jnp.where(
+            (ri & ~ok_ri) | (ok_ri & ~single & ~slot_free), 1, 0
+        )
+        out["lease_served"] = out["lease_served"] + jnp.where(imm_lease, 1, 0)
+        out["lease_fallback"] = out["lease_fallback"] + jnp.where(
+            enq & s.lease_on, 1, 0
+        )
+
+    with jax.named_scope("propose_append"):
+        # ---- Propose (leader) --------------------------------------------------
+        # Host routes proposals to the group's leader replica; a lane that is not
+        # leader reports the forward target instead (host-side forwarding
+        # replaces the reference's follower Propose relay, raft.go:1839-1851).
+        pp = act & (mtype == MSG.PROPOSE)
+        pok = pp & (s.role == ROLE.LEADER) & (s.transfer_to == 0)
+        # config-change entries: at most one pending (raft.go:1587-1606).
+        # HOST INVARIANT: the engine packs a config-change entry alone in its own
+        # single-entry PROPOSE message (never mixed with regular entries), so the
+        # pending check is all-or-nothing per message.
+        e_in_msg = jnp.arange(E, dtype=i32)[None, :] < nent[:, None]
+        has_cc = jnp.any(m["entry_cc"] & e_in_msg, axis=1)
+        cc_allowed = pok & has_cc & ~s.pending_cc
+        cc_stripped = pok & has_cc & s.pending_cc
+        s = s._replace(pending_cc=jnp.where(cc_allowed, True, s.pending_cc))
+        out["dropped_cc"] = out["dropped_cc"] | cc_stripped
+        room = s.last_index - s.first_index + 1 + nent <= W
+        can_append = pok & room
+        prop_base = jnp.where(can_append, s.last_index + 1, prop_base)
+        # append up to E entries at the current term — same loop-free ring-slot
+        # scatter as the Replicate path: slot w gets index lo + ((w - lo) mod W)
+        if E > 0:
+            eff_cc = m["entry_cc"] & cc_allowed[:, None]
+            w_idx = jnp.arange(W, dtype=i32)[None, :]
+            a_lo = s.last_index + 1
+            a_hi = s.last_index + nent
+            i_w = a_lo[:, None] + jnp.mod(w_idx - a_lo[:, None], W)
+            written = can_append[:, None] & (i_w <= a_hi[:, None])
+            e_pos = jnp.clip(i_w - a_lo[:, None], 0, E - 1)
+            cc_w = jnp.take_along_axis(eff_cc, e_pos, axis=1)
+            log_term = jnp.where(written, s.term[:, None], s.log_term)
+            log_cc = jnp.where(written, cc_w, s.log_is_cc)
+            new_last = jnp.where(can_append, s.last_index + nent, s.last_index)
+            s = s._replace(
+                log_term=log_term,
+                log_is_cc=log_cc,
+                last_index=new_last,
+                match=jnp.where(selfm & can_append[:, None], new_last[:, None], s.match),
+            )
+        out["fwd_leader"] = jnp.where(pp & ~pok, s.leader, out["fwd_leader"])
+        out["log_full"] = out["log_full"] | (pok & ~room)
+
+    with jax.named_scope("misc"):
+        # ---- ReadIndexResp (follower/observer) --------------------------------
+        rir = act & (mtype == MSG.READ_INDEX_RESP) & (is_fol | is_obs)
+        s = s._replace(
+            leader=jnp.where(rir, from_slot + 1, s.leader),
+            election_tick=jnp.where(rir, 0, s.election_tick),
+        )
+        # deliver through the ready queue
+        posm3 = jax.nn.one_hot(s.ri_count, R, dtype=bool) & (
+            rir & (s.ri_count < R)
+        )[:, None]
+        s = s._replace(
+            ri_ctx=jnp.where(posm3, m["hint"][:, None], s.ri_ctx),
+            ri_ctx2=jnp.where(posm3, m["hint_high"][:, None], s.ri_ctx2),
+            ri_index=jnp.where(posm3, m["log_index"][:, None], s.ri_index),
+            ri_acks=jnp.where(posm3, jnp.int32(-1), s.ri_acks),
+            ri_count=jnp.where(rir & (s.ri_count < R), s.ri_count + 1, s.ri_count),
+        )
+
+        # ---- LeaderTransfer (leader) ------------------------------------------
+        lt = act & (mtype == MSG.LEADER_TRANSFER) & (s.role == ROLE.LEADER)
+        target = m["hint"]  # slot+1
+        lt_ok = lt & (s.transfer_to == 0) & (target != s.self_slot + 1) & (target != 0)
+        s = s._replace(
+            transfer_to=jnp.where(lt_ok, target, s.transfer_to),
+            election_tick=jnp.where(lt_ok, 0, s.election_tick),
+        )
+        t_oh = jax.nn.one_hot(jnp.maximum(target - 1, 0), P, dtype=bool)
+        t_match = jnp.sum(jnp.where(t_oh, s.match, 0), axis=1)
+        fast = lt_ok & (t_match == s.last_index)
+        out["send_flags"] = jnp.where(
+            fast[:, None] & t_oh, out["send_flags"] | SEND_TIMEOUT_NOW, out["send_flags"]
+        )
+
+        # ---- Unreachable / SnapshotStatus (leader) -----------------------------
+        un = act & (mtype == MSG.UNREACHABLE) & (s.role == ROLE.LEADER) & known_from
+        s = s._replace(
+            rstate=jnp.where(
+                un[:, None] & fr & (s.rstate == RSTATE.REPLICATE), RSTATE.RETRY, s.rstate
+            )
+        )
+        st2 = act & (mtype == MSG.SNAPSHOT_STATUS) & (s.role == ROLE.LEADER) & known_from
+        in_snap = fr & (s.rstate == RSTATE.SNAPSHOT)
+        s = s._replace(
+            snap_sent=jnp.where(
+                st2[:, None] & in_snap & m["reject"][:, None], 0, s.snap_sent
+            ),
+            # becomeWait: next = max(match+1, snap_sent+1), state WAIT
+            next=jnp.where(
+                st2[:, None] & in_snap,
+                jnp.maximum(s.match + 1, s.snap_sent + 1),
+                s.next,
+            ),
+            rstate=jnp.where(st2[:, None] & in_snap, RSTATE.WAIT, s.rstate),
+        )
 
     resps = {
         "resp_type": jnp.where(act | noop_resp | pv_stale, resp_type, MSG.NONE),
@@ -976,7 +979,6 @@ def step_batch(
         "send_hint2": jnp.zeros((G, P), i32),
         "noop_appended": jnp.zeros((G,), i32),
         "noop_term": jnp.zeros((G,), i32),
-        "dropped_propose": jnp.zeros((G,), i32),
         "dropped_readindex": jnp.zeros((G,), i32),
         "lease_served": jnp.zeros((G,), i32),
         "lease_fallback": jnp.zeros((G,), i32),
@@ -993,8 +995,9 @@ def step_batch(
         "ctr_replicate_rejects": jnp.zeros((G,), i32),
     }
 
-    s = _quiesce(s, inbox, ticks)
-    s, out = _tick(s, ticks, out)
+    with jax.named_scope("tick"):
+        s = _quiesce(s, inbox, ticks)
+        s, out = _tick(s, ticks, out)
 
     # drain inbox via scan: iteration k applies slot k for every group
     def body(carry, slot):
@@ -1031,240 +1034,247 @@ def step_batch(
         jnp.moveaxis(inbox.entry_terms, 1, 0),
         jnp.moveaxis(inbox.entry_cc.astype(i32), 1, 0),
     )
-    (s, out), resps = jax.lax.scan(body, (s, out), slots)
+    with jax.named_scope("inbox"):
+        (s, out), resps = jax.lax.scan(body, (s, out), slots)
     resps = {k: jnp.moveaxis(v, 0, 1) for k, v in resps.items()}
 
-    # ---- quorum commit (leader lanes), cf. raft.go:859-907 -----------------
-    is_leader = s.role == ROLE.LEADER
-    nv = _num_voting(s)
-    q = _quorum(s)
-    masked_match = jnp.where(s.voting, s.match, jnp.iinfo(jnp.int32).max)
-    sorted_match = jnp.sort(masked_match, axis=1)  # ascending; non-voting = +inf last
-    # k-th smallest with k = nv - q gives the quorum-replicated index
-    qpos = jnp.clip(nv - q, 0, P - 1)
-    qidx = jnp.take_along_axis(sorted_match, qpos[:, None], axis=1)[:, 0]
-    qterm = _term_at(s, qidx)
-    can_commit = (
-        is_leader & (nv > 0) & (qidx > s.committed) & (qterm == s.term)
-    )
-    s = s._replace(committed=jnp.where(can_commit, qidx, s.committed))
-
-    # ---- replication fan-out ----------------------------------------------
-    # invariant: a peer parked for a snapshot un-parks as soon as its match
-    # covers the snapshot watermark, regardless of WHICH message moved it
-    # (the restore ack can arrive as a ReplicateResp the host already
-    # folded, or the watermark can be lowered by the host reconciling the
-    # actually-sent snapshot index; cf. remote.go:145-153 respondedTo)
-    s = s._replace(
-        rstate=jnp.where(
-            (s.rstate == RSTATE.SNAPSHOT) & (s.match >= s.snap_sent),
-            RSTATE.RETRY,
-            s.rstate,
+    with jax.named_scope("commit"):
+        # ---- quorum commit (leader lanes), cf. raft.go:859-907 -----------------
+        is_leader = s.role == ROLE.LEADER
+        nv = _num_voting(s)
+        q = _quorum(s)
+        masked_match = jnp.where(s.voting, s.match, jnp.iinfo(jnp.int32).max)
+        sorted_match = jnp.sort(masked_match, axis=1)  # ascending; non-voting = +inf last
+        # k-th smallest with k = nv - q gives the quorum-replicated index
+        qpos = jnp.clip(nv - q, 0, P - 1)
+        qidx = jnp.take_along_axis(sorted_match, qpos[:, None], axis=1)[:, 0]
+        qterm = _term_at(s, qidx)
+        can_commit = (
+            is_leader & (nv > 0) & (qidx > s.committed) & (qterm == s.term)
         )
-    )
-    # send to every lagging, unpaused peer; optimistically advance next for
-    # peers in REPLICATE state (pipelining, remote.go progress())
-    selfm = _self_mask(s)
-    peer_tgt = s.member & ~selfm
-    lag = s.next <= s.last_index[:, None]
-    # commit advanced this step: also ping up-to-date peers with an empty
-    # Replicate so their commit index stays fresh (the reference gets this
-    # from broadcastReplicateMessage after tryCommit, raft.go:1675-1677)
-    commit_moved = (s.committed != prev_commit)[:, None]
-    paused = (s.rstate == RSTATE.WAIT) | (s.rstate == RSTATE.SNAPSHOT)
-    # peers whose next has been compacted away need a snapshot (host path)
-    compacted = s.next < s.first_index[:, None]
-    send = (
-        is_leader[:, None]
-        & peer_tgt
-        & (lag | commit_moved | out["force_probe"])
-        & ~paused
-        & ~compacted
-    )
-    need_snap = is_leader[:, None] & peer_tgt & lag & ~paused & compacted & s.ract
-    n_send = jnp.clip(s.last_index[:, None] - s.next + 1, 0, E)
-    prev_idx = s.next - 1
-    W = s.log_term.shape[1]
-    prev_term_pp = jnp.where(
-        prev_idx == s.first_index[:, None] - 1,
-        s.marker_term[:, None],
-        jnp.take_along_axis(s.log_term, prev_idx % W, axis=1),
-    )
-    out["send_flags"] = jnp.where(
-        send, out["send_flags"] | SEND_REPLICATE, out["send_flags"]
-    )
-    out["send_flags"] = jnp.where(
-        need_snap, out["send_flags"] | NEED_SNAPSHOT, out["send_flags"]
-    )
-    s = s._replace(
-        snap_sent=jnp.where(need_snap, s.last_index[:, None], s.snap_sent),
-        rstate=jnp.where(need_snap, RSTATE.SNAPSHOT, s.rstate),
-    )
-    send_prev_index = jnp.where(send, prev_idx, 0)
-    send_n = jnp.where(send, n_send, 0)
-    # optimistic next advance (REPLICATE state); a RETRY probe carrying
-    # entries transitions to WAIT until acked (remote.go progress()); empty
-    # commit-refresh sends leave flow-control state untouched
-    adv = send & (s.rstate == RSTATE.REPLICATE) & (n_send > 0)
-    probe = send & (s.rstate == RSTATE.RETRY) & (n_send > 0)
-    s = s._replace(
-        next=jnp.where(adv, s.next + n_send, s.next),
-        rstate=jnp.where(probe, RSTATE.WAIT, s.rstate),
-    )
-    send_commit = jnp.where(send, s.committed[:, None], 0)
-    send_hb_commit = jnp.minimum(s.match, s.committed[:, None])
+        s = s._replace(committed=jnp.where(can_commit, qidx, s.committed))
 
-    # ---- readindex ready queue pop ----------------------------------------
-    # ack bits only ever come from voting peers' HeartbeatResp; +1 counts the
-    # leader itself. acks == -1 marks an immediately-ready entry.
-    acks = s.ri_acks
-    popc = _popcount(acks)
-    confirmed = (popc + 1 >= q[:, None]) | (acks == -1)
-    live = (jnp.arange(R, dtype=i32)[None, :] < s.ri_count[:, None]) & (
-        s.ri_ctx != 0
-    )
-    confirmed = confirmed & live
-    # pop the longest confirmed prefix... any confirmed slot releases all
-    # earlier slots (readindex.go:77-116)
-    idxs = jnp.arange(R, dtype=i32)[None, :]
-    last_conf = jnp.max(jnp.where(confirmed, idxs + 1, 0), axis=1)  # count to pop
-    popmask = idxs < last_conf[:, None]
-    ready_ctx = jnp.where(popmask, s.ri_ctx, 0)
-    ready_ctx2 = jnp.where(popmask, s.ri_ctx2, 0)
-    # released entries read at the confirming slot's index
-    conf_idx = jnp.max(jnp.where(confirmed, s.ri_index, 0), axis=1)
-    ready_index = jnp.where(popmask, jnp.minimum(s.ri_index, conf_idx[:, None]), 0)
-    ready_count = last_conf
-    # compact the queue
-    shift = last_conf
-    new_pos = idxs - shift[:, None]
-    def shift_left(a, fill):
-        take = jnp.clip(idxs + shift[:, None], 0, R - 1)
-        v = jnp.take_along_axis(a, take, axis=1)
-        return jnp.where(idxs < (s.ri_count - shift)[:, None], v, fill)
-    s = s._replace(
-        ri_ctx=shift_left(s.ri_ctx, 0),
-        ri_ctx2=shift_left(s.ri_ctx2, 0),
-        ri_index=shift_left(s.ri_index, 0),
-        ri_acks=shift_left(s.ri_acks, 0),
-        ri_count=s.ri_count - shift,
-    )
+    with jax.named_scope("replicate_fanout"):
+        # ---- replication fan-out ----------------------------------------------
+        # invariant: a peer parked for a snapshot un-parks as soon as its match
+        # covers the snapshot watermark, regardless of WHICH message moved it
+        # (the restore ack can arrive as a ReplicateResp the host already
+        # folded, or the watermark can be lowered by the host reconciling the
+        # actually-sent snapshot index; cf. remote.go:145-153 respondedTo)
+        s = s._replace(
+            rstate=jnp.where(
+                (s.rstate == RSTATE.SNAPSHOT) & (s.match >= s.snap_sent),
+                RSTATE.RETRY,
+                s.rstate,
+            )
+        )
+        # send to every lagging, unpaused peer; optimistically advance next for
+        # peers in REPLICATE state (pipelining, remote.go progress())
+        selfm = _self_mask(s)
+        peer_tgt = s.member & ~selfm
+        lag = s.next <= s.last_index[:, None]
+        # commit advanced this step: also ping up-to-date peers with an empty
+        # Replicate so their commit index stays fresh (the reference gets this
+        # from broadcastReplicateMessage after tryCommit, raft.go:1675-1677)
+        commit_moved = (s.committed != prev_commit)[:, None]
+        paused = (s.rstate == RSTATE.WAIT) | (s.rstate == RSTATE.SNAPSHOT)
+        # peers whose next has been compacted away need a snapshot (host path)
+        compacted = s.next < s.first_index[:, None]
+        send = (
+            is_leader[:, None]
+            & peer_tgt
+            & (lag | commit_moved | out["force_probe"])
+            & ~paused
+            & ~compacted
+        )
+        need_snap = is_leader[:, None] & peer_tgt & lag & ~paused & compacted & s.ract
+        n_send = jnp.clip(s.last_index[:, None] - s.next + 1, 0, E)
+        prev_idx = s.next - 1
+        W = s.log_term.shape[1]
+        prev_term_pp = jnp.where(
+            prev_idx == s.first_index[:, None] - 1,
+            s.marker_term[:, None],
+            jnp.take_along_axis(s.log_term, prev_idx % W, axis=1),
+        )
+        out["send_flags"] = jnp.where(
+            send, out["send_flags"] | SEND_REPLICATE, out["send_flags"]
+        )
+        out["send_flags"] = jnp.where(
+            need_snap, out["send_flags"] | NEED_SNAPSHOT, out["send_flags"]
+        )
+        s = s._replace(
+            snap_sent=jnp.where(need_snap, s.last_index[:, None], s.snap_sent),
+            rstate=jnp.where(need_snap, RSTATE.SNAPSHOT, s.rstate),
+        )
+        send_prev_index = jnp.where(send, prev_idx, 0)
+        send_n = jnp.where(send, n_send, 0)
+        # optimistic next advance (REPLICATE state); a RETRY probe carrying
+        # entries transitions to WAIT until acked (remote.go progress()); empty
+        # commit-refresh sends leave flow-control state untouched
+        adv = send & (s.rstate == RSTATE.REPLICATE) & (n_send > 0)
+        probe = send & (s.rstate == RSTATE.RETRY) & (n_send > 0)
+        s = s._replace(
+            next=jnp.where(adv, s.next + n_send, s.next),
+            rstate=jnp.where(probe, RSTATE.WAIT, s.rstate),
+        )
+        send_commit = jnp.where(send, s.committed[:, None], 0)
+        send_hb_commit = jnp.minimum(s.match, s.committed[:, None])
 
-    # ---- engine directives -------------------------------------------------
-    save_from = jnp.minimum(save_base_floor, s.unsaved_from)
-    has_save = s.last_index >= save_from
-    out_save_from = jnp.where(has_save & s.active, save_from, 0)
-    out_save_to = jnp.where(has_save & s.active, s.last_index, 0)
-    s = s._replace(unsaved_from=s.last_index + 1)
+    with jax.named_scope("readindex_pop"):
+        # ---- readindex ready queue pop ----------------------------------------
+        # ack bits only ever come from voting peers' HeartbeatResp; +1 counts the
+        # leader itself. acks == -1 marks an immediately-ready entry.
+        acks = s.ri_acks
+        popc = _popcount(acks)
+        confirmed = (popc + 1 >= q[:, None]) | (acks == -1)
+        live = (jnp.arange(R, dtype=i32)[None, :] < s.ri_count[:, None]) & (
+            s.ri_ctx != 0
+        )
+        confirmed = confirmed & live
+        # pop the longest confirmed prefix... any confirmed slot releases all
+        # earlier slots (readindex.go:77-116)
+        idxs = jnp.arange(R, dtype=i32)[None, :]
+        last_conf = jnp.max(jnp.where(confirmed, idxs + 1, 0), axis=1)  # count to pop
+        popmask = idxs < last_conf[:, None]
+        ready_ctx = jnp.where(popmask, s.ri_ctx, 0)
+        ready_ctx2 = jnp.where(popmask, s.ri_ctx2, 0)
+        # released entries read at the confirming slot's index
+        conf_idx = jnp.max(jnp.where(confirmed, s.ri_index, 0), axis=1)
+        ready_index = jnp.where(popmask, jnp.minimum(s.ri_index, conf_idx[:, None]), 0)
+        ready_count = last_conf
+        # compact the queue
+        shift = last_conf
+        new_pos = idxs - shift[:, None]
+        def shift_left(a, fill):
+            take = jnp.clip(idxs + shift[:, None], 0, R - 1)
+            v = jnp.take_along_axis(a, take, axis=1)
+            return jnp.where(idxs < (s.ri_count - shift)[:, None], v, fill)
+        s = s._replace(
+            ri_ctx=shift_left(s.ri_ctx, 0),
+            ri_ctx2=shift_left(s.ri_ctx2, 0),
+            ri_index=shift_left(s.ri_index, 0),
+            ri_acks=shift_left(s.ri_acks, 0),
+            ri_count=s.ri_count - shift,
+        )
 
-    apply_from = s.processed + 1
-    apply_to = s.committed
-    has_apply = apply_to >= apply_from
-    out_apply_from = jnp.where(has_apply & s.active, apply_from, 0)
-    out_apply_to = jnp.where(has_apply & s.active, apply_to, 0)
-    s = s._replace(processed=jnp.maximum(s.processed, s.committed))
-    # entries handed to the engine are applied synchronously by the engine
-    # loop this round; mirror the reference's applied cursor via engine
-    # notifications (host may override through reconcile).
-    s = s._replace(applied=jnp.maximum(s.applied, out_apply_to))
+    with jax.named_scope("directives"):
+        # ---- engine directives -------------------------------------------------
+        save_from = jnp.minimum(save_base_floor, s.unsaved_from)
+        has_save = s.last_index >= save_from
+        out_save_from = jnp.where(has_save & s.active, save_from, 0)
+        out_save_to = jnp.where(has_save & s.active, s.last_index, 0)
+        s = s._replace(unsaved_from=s.last_index + 1)
 
-    hard_changed = (
-        (s.term != prev_term) | (s.vote != prev_vote) | (s.committed != prev_commit)
-    )
+        apply_from = s.processed + 1
+        apply_to = s.committed
+        has_apply = apply_to >= apply_from
+        out_apply_from = jnp.where(has_apply & s.active, apply_from, 0)
+        out_apply_to = jnp.where(has_apply & s.active, apply_to, 0)
+        s = s._replace(processed=jnp.maximum(s.processed, s.committed))
+        # entries handed to the engine are applied synchronously by the engine
+        # loop this round; mirror the reference's applied cursor via engine
+        # notifications (host may override through reconcile).
+        s = s._replace(applied=jnp.maximum(s.applied, out_apply_to))
 
-    last_term_out = _term_at(s, s.last_index)
+        hard_changed = (
+            (s.term != prev_term) | (s.vote != prev_vote) | (s.committed != prev_commit)
+        )
 
-    # counter plane assembly, one column per CTR slot. Commit advances
-    # are the step-end commit delta (INDEX UNITS — see state.CTR), which
-    # folds the leader quorum fold and every follower commit move into
-    # the one number that is lockstep-comparable to the scalar core.
-    counters = jnp.stack(
-        [
-            out["ctr_elections_started"],
-            out["ctr_elections_won"],
-            out["ctr_heartbeats_sent"],
-            out["ctr_replicate_rejects"],
-            s.committed - prev_commit,
-            out["lease_served"],
-            out["lease_fallback"],
-            ready_count * s.active,
-        ],
-        axis=1,
-    ).astype(jnp.uint32)
+        last_term_out = _term_at(s, s.last_index)
 
-    # suppress send directives whose issuing role died mid-step: a lane that
-    # was leader during the tick phase but stepped down while draining the
-    # inbox must not emit leader traffic stamped with its new term (the
-    # scalar core sequences message creation with state changes; here the
-    # planes are assembled at step end, so the end-of-step role gates them)
-    leader_bits = SEND_REPLICATE | SEND_HEARTBEAT | SEND_TIMEOUT_NOW | NEED_SNAPSHOT
-    end_leader = (s.role == ROLE.LEADER)[:, None]
-    # the shared vote plane serves both election phases: candidates send
-    # REQUEST_VOTE, pre-candidates REQUEST_PREVOTE (type/term selected
-    # downstream from the end-of-step role)
-    end_cand = (
-        (s.role == ROLE.CANDIDATE) | (s.role == ROLE.PRE_CANDIDATE)
-    )[:, None]
-    flags = out["send_flags"]
-    flags = jnp.where(end_leader, flags, flags & ~leader_bits)
-    flags = jnp.where(end_cand, flags, flags & ~SEND_VOTE_REQ)
-    out["send_flags"] = flags
+    with jax.named_scope("counters"):
+        # counter plane assembly, one column per CTR slot. Commit advances
+        # are the step-end commit delta (INDEX UNITS — see state.CTR), which
+        # folds the leader quorum fold and every follower commit move into
+        # the one number that is lockstep-comparable to the scalar core.
+        counters = jnp.stack(
+            [
+                out["ctr_elections_started"],
+                out["ctr_elections_won"],
+                out["ctr_heartbeats_sent"],
+                out["ctr_replicate_rejects"],
+                s.committed - prev_commit,
+                out["lease_served"],
+                out["lease_fallback"],
+                ready_count * s.active,
+            ],
+            axis=1,
+        ).astype(jnp.uint32)
 
-    output = StepOutput(
-        send_flags=out["send_flags"] * s.active[:, None],
-        send_prev_index=send_prev_index,
-        send_prev_term=jnp.where(send, prev_term_pp, 0),
-        send_n_entries=send_n,
-        send_commit=send_commit,
-        send_hb_commit=send_hb_commit,
-        send_hint=out["send_hint"],
-        send_hint2=out["send_hint2"],
-        vote_last_index=s.last_index,
-        vote_last_term=last_term_out,
-        resp_type=resps["resp_type"],
-        resp_to=resps["resp_to"],
-        resp_term=resps["resp_term"],
-        resp_log_index=resps["resp_log_index"],
-        resp_reject=resps["resp_reject"],
-        resp_hint=resps["resp_hint"],
-        resp_hint2=resps["resp_hint2"],
-        save_from=out_save_from,
-        save_to=out_save_to,
-        apply_from=out_apply_from,
-        apply_to=out_apply_to,
-        commit_index=s.committed,
-        hard_changed=hard_changed & s.active,
-        ready_ctx=ready_ctx,
-        ready_ctx2=ready_ctx2,
-        ready_index=ready_index,
-        ready_count=ready_count * s.active,
-        dropped_propose=out["dropped_propose"],
-        dropped_cc=out["dropped_cc"],
-        fwd_leader=out["fwd_leader"],
-        noop_appended=out["noop_appended"],
-        noop_term=out["noop_term"],
-        log_full=out["log_full"],
-        prop_base=resps["prop_base"],
-        rep_base=resps["rep_base"],
-        leader=s.leader,
-        term=s.term,
-        vote=s.vote,
-        role=s.role,
-        match=s.match,
-        rstate=s.rstate,
-        last_index=s.last_index,
-        quiesced=s.quiesced,
-        lease_round=jnp.where(
-            s.lease_on & (s.role == ROLE.LEADER), s.hb_round_tick, 0
-        ),
-        lease_served=out["lease_served"],
-        lease_fallback=out["lease_fallback"],
-        lease_ok=(
-            s.lease_on & s.clock_ok & (s.role == ROLE.LEADER)
-            & (s.tick_count < s.lease_until) & (s.transfer_to == 0)
-        ),
-        counters=counters,
-    )
+    with jax.named_scope("directives"):
+        # suppress send directives whose issuing role died mid-step: a lane that
+        # was leader during the tick phase but stepped down while draining the
+        # inbox must not emit leader traffic stamped with its new term (the
+        # scalar core sequences message creation with state changes; here the
+        # planes are assembled at step end, so the end-of-step role gates them)
+        leader_bits = SEND_REPLICATE | SEND_HEARTBEAT | SEND_TIMEOUT_NOW | NEED_SNAPSHOT
+        end_leader = (s.role == ROLE.LEADER)[:, None]
+        # the shared vote plane serves both election phases: candidates send
+        # REQUEST_VOTE, pre-candidates REQUEST_PREVOTE (type/term selected
+        # downstream from the end-of-step role)
+        end_cand = (
+            (s.role == ROLE.CANDIDATE) | (s.role == ROLE.PRE_CANDIDATE)
+        )[:, None]
+        flags = out["send_flags"]
+        flags = jnp.where(end_leader, flags, flags & ~leader_bits)
+        flags = jnp.where(end_cand, flags, flags & ~SEND_VOTE_REQ)
+        out["send_flags"] = flags
+
+        output = StepOutput(
+            send_flags=out["send_flags"] * s.active[:, None],
+            send_prev_index=send_prev_index,
+            send_prev_term=jnp.where(send, prev_term_pp, 0),
+            send_n_entries=send_n,
+            send_commit=send_commit,
+            send_hb_commit=send_hb_commit,
+            send_hint=out["send_hint"],
+            send_hint2=out["send_hint2"],
+            vote_last_index=s.last_index,
+            vote_last_term=last_term_out,
+            resp_type=resps["resp_type"],
+            resp_to=resps["resp_to"],
+            resp_term=resps["resp_term"],
+            resp_log_index=resps["resp_log_index"],
+            resp_reject=resps["resp_reject"],
+            resp_hint=resps["resp_hint"],
+            resp_hint2=resps["resp_hint2"],
+            save_from=out_save_from,
+            save_to=out_save_to,
+            apply_from=out_apply_from,
+            apply_to=out_apply_to,
+            commit_index=s.committed,
+            hard_changed=hard_changed & s.active,
+            ready_ctx=ready_ctx,
+            ready_ctx2=ready_ctx2,
+            ready_index=ready_index,
+            ready_count=ready_count * s.active,
+            dropped_readindex=out["dropped_readindex"],
+            dropped_cc=out["dropped_cc"],
+            fwd_leader=out["fwd_leader"],
+            noop_appended=out["noop_appended"],
+            noop_term=out["noop_term"],
+            log_full=out["log_full"],
+            prop_base=resps["prop_base"],
+            rep_base=resps["rep_base"],
+            leader=s.leader,
+            term=s.term,
+            vote=s.vote,
+            role=s.role,
+            match=s.match,
+            rstate=s.rstate,
+            last_index=s.last_index,
+            quiesced=s.quiesced,
+            lease_round=jnp.where(
+                s.lease_on & (s.role == ROLE.LEADER), s.hb_round_tick, 0
+            ),
+            lease_served=out["lease_served"],
+            lease_fallback=out["lease_fallback"],
+            lease_ok=(
+                s.lease_on & s.clock_ok & (s.role == ROLE.LEADER)
+                & (s.tick_count < s.lease_until) & (s.transfer_to == 0)
+            ),
+            counters=counters,
+        )
     return s, output
 
 
@@ -1272,12 +1282,20 @@ def _popcount(x):
     return jax.lax.population_count(x.astype(jnp.uint32)).astype(i32)
 
 
+def _named(fn, name: str):
+    """jax names a compiled program after its function (`jit_<name>` in a
+    device trace); a functools.partial or a shard_map has no name of its
+    own and shows as `jit__unknown`."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 @functools.lru_cache(maxsize=None)
 def make_step_fn(cfg: KernelConfig, donate: bool = True):
     """Return a jitted step(state, inbox, ticks) -> (state, output).
     Cached per (cfg, donate) so every engine/cluster with the same static
     shapes shares one compiled executable."""
-    f = functools.partial(step_batch, cfg=cfg)
+    f = _named(functools.partial(step_batch, cfg=cfg), "step_batch")
     if donate:
         return jax.jit(f, donate_argnums=(0,))
     return jax.jit(f)
@@ -1289,6 +1307,7 @@ def make_step_fn(cfg: KernelConfig, donate: bool = True):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("router")
 def route_step_output(
     s: RaftTensors,
     out: StepOutput,
@@ -1600,7 +1619,10 @@ def make_multi_step_fn(cfg: KernelConfig, steps: int, donate: bool = True):
     the executable as a static scan length (K is a compile-time
     constant by design: the recompilation-hazard rules treat a traced
     K as a finding). Cached per (cfg, steps, donate)."""
-    f = functools.partial(multi_step_batch, cfg=cfg, steps=steps)
+    f = _named(
+        functools.partial(multi_step_batch, cfg=cfg, steps=steps),
+        "multi_step_batch",
+    )
     if donate:
         return jax.jit(f, donate_argnums=(0, 3))
     return jax.jit(f)
@@ -1612,6 +1634,7 @@ def make_multi_step_fn(cfg: KernelConfig, steps: int, donate: bool = True):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("router")
 def _shard_route(
     s: RaftTensors,
     out: StepOutput,
@@ -1751,12 +1774,15 @@ def make_sharded_multi_step_fn(
     )
     lane = PartitionSpec(axis)
     step_lane = PartitionSpec(None, axis)  # (K, G, ...) stacked outputs
-    sm = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(lane,) * 6,
-        out_specs=(lane, step_lane, step_lane, lane, lane),
-        check_vma=False,
+    sm = _named(
+        jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(lane,) * 6,
+            out_specs=(lane, step_lane, step_lane, lane, lane),
+            check_vma=False,
+        ),
+        "sharded_multi_step_batch",
     )
     in_sh = NamedSharding(mesh, lane)
     out_sh = NamedSharding(mesh, step_lane)

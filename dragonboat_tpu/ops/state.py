@@ -266,7 +266,9 @@ class StepOutput(NamedTuple):
     ready_ctx2: jax.Array  # i32[G,R] upper ctx halves
     ready_index: jax.Array  # i32[G,R]
     ready_count: jax.Array  # i32[G]
-    dropped_propose: jax.Array  # i32[G] proposals dropped (no leader etc.)
+    # (a dropped proposal shows as prop_base 0 on its slot, below)
+    dropped_readindex: jax.Array  # i32[G] ReadIndex contexts a leader lane
+    #   dropped: nothing committed in its term yet, or its ri queue full
     dropped_cc: jax.Array  # bool[G] config-change replaced (pending invariant)
     fwd_leader: jax.Array  # i32[G] slot+1 to forward host proposals to
     noop_appended: jax.Array  # i32[G] index of new-leader noop entry (0=none)

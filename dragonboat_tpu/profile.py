@@ -12,13 +12,12 @@ attribution itself a first-class, always-exported plane:
     every stage duration is ALSO observed into an
     ``engine_phase_seconds{engine=...,phase=...}`` histogram
     (events.Histogram, Prometheus exposition via
-    ``NodeHost.write_health_metrics``), and at FULL sampling (ratio 1,
-    the bench/debug opt-in) recorded as a ``phase_span`` event in the
-    FlightRecorder so ``tools.timeline --spans`` renders them
-    interleaved with causal-trace stages — sparse production sampling
-    fills histograms only, never crowding the forensic ring. Unsampled
-    iterations stay allocation- and event-free (the profiler's
-    start/end no-op there).
+    ``NodeHost.write_health_metrics``). At FULL sampling (ratio 1, the
+    bench/debug opt-in) the profiler also stores each span as a
+    ``phase_span`` event in the FlightRecorder's span store, so
+    ``tools.timeline --spans`` renders them interleaved with
+    causal-trace stages. Unsampled iterations stay allocation- and
+    event-free (the profiler's begin/start/end no-op there).
 
   * ``SyncAudit`` — the runtime twin of the static ``device-sync`` rule
     family (analysis/rules_device.py). The blessed seam
@@ -66,15 +65,28 @@ import time
 from typing import Dict, Optional, Tuple
 
 from .events import Histogram, write_histogram_series, _labels
-from .trace import _RING_MAGIC, MmapRing, flight_recorder, read_mmap_ring
+from .trace import _RING_MAGIC, MmapRing, read_mmap_ring
 
-# canonical step-phase vocabulary. The vector engine's step loop
-# (VectorEngine._run_once + _decode) times every stage of a kernel step;
-# bench.py zero-fills phase_breakdown over VECTOR_PHASES so the JSON
-# schema is stable even for configs where a phase never ran.
+# canonical step-phase vocabulary. The vector engine's loop thread is in
+# exactly one of the top-level phases at every instant of a sampled
+# iteration (trace.Profiler.begin); bench.py zero-fills phase_breakdown
+# over VECTOR_PHASES so the JSON schema is stable even for configs where
+# a phase never ran.
+VECTOR_SUBSPANS = (
+    "deliver",      # bulk send/deliver seam (_dispatch_sends), inside the
+                    # send/apply/reads phases
+    "put",          # inside dispatch: device_put of (inbox, ticks[, routes])
+    "launch",       # inside dispatch: the jitted call returning its futures
+    "device_wait",  # inside fetch: until the step's output is ready
+    "copy",         # inside fetch: the device_get after that
+)
 VECTOR_PHASES = (
+    "wait",       # blocked in _ready.wait, a fairness yield, and idle
+                  # iterations that launched nothing
+    "prepare",    # _run_once before _pack: reconciles, clock suspect,
+                  # snapshot status, routes, ticks, request GC, work set
     "pack",       # host-event staging -> inbox planes (one scatter/plane)
-    "dispatch",   # device_put of (inbox, ticks) + jitted step dispatch
+    "dispatch",   # tick plane, device_put + jitted step dispatch
     "fetch",      # _fetch_output: THE consolidated device->host sync
     "place",      # decode phase 0: payloads at device-assigned indexes
     "send_rep",   # decode phase 1: Replicate sends (leave BEFORE fsync)
@@ -83,11 +95,9 @@ VECTOR_PHASES = (
     "apply",      # decode phase 4: committed entries -> RSM task queues
     "reads",      # decode phase 5: confirmed ReadIndex completions
     "maintain",   # decode phase 6: catchup/snapshot/compaction maintenance
-    "deliver",    # bulk send/deliver seam (_dispatch_sends, sub-span of
-                  # the send/apply/reads phases it runs inside)
-)
+) + VECTOR_SUBSPANS
 
-# the scalar ExecEngine worker loop's stages (trace.STAGES order), timed
+# the scalar ExecEngine worker loop's stages, timed
 # by the same Profiler machinery so scalar/vector attribution reads on
 # one scale in the exposition and the bench JSON
 EXEC_PHASES = ("step", "fast_apply", "send", "save", "apply", "exec")
@@ -96,35 +106,21 @@ _PREFIX = "dragonboat_tpu"
 
 
 class PhasePlane:
-    """Process-global phase-span sink: (engine, phase) -> Histogram plus
-    a FlightRecorder ``phase_span`` breadcrumb per sampled span.
+    """Process-global phase-span sink: (engine, phase) -> Histogram.
 
-    Fed exclusively from trace.Profiler's SAMPLED branch (attach via
+    Fed from trace.Profiler's sampled spans (attach via
     ``Profiler.attach_phase_plane``); the ``sampling`` argument mirrors
     the caller's gate so the off path stays event-free and the lint's
-    telemetry rule can see the guard."""
+    telemetry rule can see the guard. The span EVENTS go from the
+    profiler straight to the flight recorder's span store."""
 
     def __init__(self) -> None:
         self._mu = threading.Lock()
         self._hists: Dict[Tuple[str, str], Histogram] = {}
-        # master switch for flight-recorder spans (timeline --spans);
-        # disable for tests that assert exact recorder contents
-        self.record_spans = True
 
     def on_phase(
-        self,
-        engine: str,
-        phase: str,
-        dt: float,
-        sampling: bool,
-        spans: bool = True,
+        self, engine: str, phase: str, dt: float, sampling: bool
     ) -> None:
-        """`sampling` mirrors the calling profiler's 1-in-N gate (off
-        path: nothing happens); `spans` is the producer's full-sampling
-        gate (trace.Profiler sets it only at ratio 1, the bench/debug
-        mode) — sparse production sampling fills histograms but must not
-        crowd the forensic ring's bounded history with phase_span
-        breadcrumbs."""
         if sampling:
             key = (engine, phase)
             with self._mu:
@@ -132,11 +128,6 @@ class PhasePlane:
                 if h is None:
                     h = self._hists[key] = Histogram()
             h.observe(dt)
-            if spans and self.record_spans:
-                flight_recorder().record(
-                    "phase_span", engine=engine, phase=phase,
-                    dur=round(dt, 9),
-                )
 
     def histogram(self, engine: str, phase: str) -> Optional[Histogram]:
         with self._mu:
@@ -146,24 +137,6 @@ class PhasePlane:
         with self._mu:
             hists = list(self._hists.values())
         return sum(h.count for h in hists)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        """(engine, phase) -> {count, sum_s, p50_s, p99_s} for tooling."""
-        with self._mu:
-            items = list(self._hists.items())
-        out: Dict[str, Dict[str, float]] = {}
-        for (engine, phase), h in items:
-            out[f"{engine}/{phase}"] = {
-                "count": float(h.count),
-                "sum_s": round(h.sum, 6),
-                "p50_s": round(h.quantile(0.5), 6),
-                "p99_s": round(h.quantile(0.99), 6),
-            }
-        return out
-
-    def reset(self) -> None:
-        with self._mu:
-            self._hists.clear()
 
     def write(self, w, prefix: str = _PREFIX) -> None:
         """Prometheus exposition: one ``engine_phase_seconds`` histogram
@@ -1003,6 +976,7 @@ __all__ = [
     "PhasePlane",
     "SyncAudit",
     "VECTOR_PHASES",
+    "VECTOR_SUBSPANS",
     "compile_watch",
     "diff_compiles",
     "diff_sync",

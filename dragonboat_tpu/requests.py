@@ -13,6 +13,7 @@ import itertools
 import os
 import struct
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -215,7 +216,7 @@ class BatchRequestState:
     the waiting client)."""
 
     __slots__ = ("batch_id", "n", "completed", "dropped", "deadline",
-                 "_event", "_mu")
+                 "completed_at", "_event", "_mu")
 
     def __init__(self, batch_id: int, n: int, deadline: int) -> None:
         self.batch_id = batch_id
@@ -223,15 +224,23 @@ class BatchRequestState:
         self.completed = 0
         self.dropped = 0
         self.deadline = deadline
+        # time.monotonic() at which the last proposal of the batch was
+        # accounted, read on the thread that accounted it; 0.0 until then
+        self.completed_at = 0.0
         self._event = threading.Event()
         self._mu = threading.Lock()
+
+    def _finish_locked(self) -> None:
+        if not self._event.is_set():
+            self.completed_at = time.monotonic()
+            self._event.set()
 
     def add_done(self, completed: int = 0, dropped: int = 0) -> None:
         with self._mu:
             self.completed += completed
             self.dropped += dropped
             if self.completed + self.dropped >= self.n:
-                self._event.set()
+                self._finish_locked()
 
     def expire(self) -> None:
         """Timeout: account every outstanding proposal as dropped."""
@@ -239,7 +248,7 @@ class BatchRequestState:
             rest = self.n - self.completed - self.dropped
             if rest > 0:
                 self.dropped += rest
-            self._event.set()
+            self._finish_locked()
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self._event.wait(timeout)
@@ -275,11 +284,12 @@ class RequestState:
         self._event = threading.Event()
         self._result: Optional[RequestResult] = None
         self._cb = None
-        # sampled-latency timestamp (see trace.LatencySampler): None on
-        # the unsampled hot path; Node.read stamps a monotonic float on
-        # 1-in-N reads so completion can observe readindex latency
-        # (proposals carry their trace on the Entry instead — the same
-        # object travels propose -> arena -> commit -> apply)
+        # sampled-latency carrier (see trace.LatencySampler): None on the
+        # unsampled hot path; Node.read attaches a trace.LatencyTrace to
+        # 1-in-N reads so the engine can stamp their path and completion
+        # can observe readindex latency (proposals carry their trace on
+        # the Entry instead — the same object travels propose -> arena ->
+        # commit -> apply)
         self.lat = None
 
     def notify(self, result: RequestResult) -> None:

@@ -62,7 +62,7 @@ class Sample:
         self._sum += v
         if len(self._vals) < self._cap:
             self._vals.append(v)
-        else:
+        elif self._cap:  # cap 0: count and sum alone, no percentiles
             j = self._rng.randrange(self._seen)
             if j < self._cap:
                 self._vals[j] = v
@@ -89,46 +89,100 @@ class Sample:
         )
 
 
-STAGES = ("step", "fast_apply", "send", "save", "apply", "exec")
-
-
 class Profiler:
-    """Per-worker stage profiler (cf. trace.go:98-162 profiler; stages match
-    the reference's propose/step/save/cs/exec breakdown plus our apply).
-    Stage names are open-ended: the vector engine records its own pipeline
-    (pack/dev/place/send/save/apply/notify), the scalar engine the classic
-    set — samples are created on first use."""
+    """Sampled stage profiler of one engine loop (cf. trace.go:98-162).
+    Stage names are open-ended: samples are created on first use.
+
+    Two ways to time a stage on the owning loop thread, both free on
+    unsampled iterations:
+
+      * start()/end(stage) — a pair around one stage (the scalar engine's
+        workers); what runs between an end() and the next start() is
+        not timed;
+      * begin(stage) — opens `stage` and closes the span that was
+        running, so consecutive spans are contiguous: from the first
+        begin() of a sampled iteration to the next new_iteration() every
+        instant lies in exactly one of them (the vector engine's loop).
+        Each also records the thread's CPU seconds under `<stage>.cpu`.
+
+    add() records a sub-span the caller timed inside one of those.
+    observe() and fold() take no gate and may be called from any thread:
+    the apply workers' spans and the request path, whose sampling is the
+    request's own. Dotted names (`save.cpu`, `rsm.handle`, `req.w.queue`,
+    `n.spans_dropped`) live in `samples` only and are no stages of the
+    loop: summary() and report() leave them out."""
 
     def __init__(self, sample_ratio: int = 16) -> None:
         self.ratio = max(1, sample_ratio)
         self._iter = 0
         self.sampling = False
-        self.samples: Dict[str, Sample] = {s: Sample(s) for s in STAGES}
+        self.samples: Dict[str, Sample] = {}
         self.batched_groups = Sample("batched_groups")
         self._t0: Optional[float] = None
-        # optional phase-span sink (profile.PhasePlane): sampled stage
-        # durations fan out to the engine_phase_seconds histograms and
-        # the flight recorder; unsampled iterations never reach it
+        # optional histogram sink (profile.PhasePlane): sampled stage
+        # durations fan out to engine_phase_seconds; unsampled iterations
+        # never reach it
         self._plane = None
         self._engine_kind = ""
+        self._sub_kind = ""
         self._span_gate = False
+        # begin(): the running span's name, and its start on the wall
+        # clock and on this thread's CPU clock
+        self._stage: Optional[str] = None
+        self._mark = 0.0
+        self._cpu_mark = 0.0
+        # span events of an iteration's head stages wait until the
+        # iteration begins a stage outside the head; an iteration that
+        # never does launched nothing, and its head joins the idle
+        # stretch that the next one's first head span (`wait`) carries
+        self._head: Tuple[str, ...] = ()
+        self._held: List[Tuple[str, float, float]] = []
+        self._idle_from: Optional[float] = None
+        self._shed = 0  # spans the full store shed on this profiler's appends
+        self._turn = False  # the next begin() is the first of an iteration
 
-    def attach_phase_plane(self, plane, engine_kind: str) -> None:
+    def attach_phase_plane(
+        self, plane, engine_kind: str, idle_head: Tuple[str, ...] = ()
+    ) -> None:
         """Tee sampled stage durations into a profile.PhasePlane under
-        the given engine kind ("vector"/"exec"). Histograms fill at ANY
-        sampling ratio; flight-recorder span events only at FULL
-        sampling (ratio 1 — the bench/debug opt-in) so the sparse
-        production default can never flood the forensic ring's bounded
-        history with phase_span breadcrumbs."""
+        the given engine kind ("vector"/"exec"), sub-spans under
+        `<kind>.sub`. Histograms fill at ANY sampling ratio; the events
+        of the kind's own spans, which are disjoint, reach the
+        flight recorder's span store only at FULL sampling (ratio 1, the
+        bench/debug opt-in). `idle_head` names the stages every
+        iteration of a begin() loop starts with before it knows whether
+        it has work: an idle loop polling every 2 ms then leaves one
+        growing span instead of 1 500 events a second."""
         self._plane = plane
         self._engine_kind = engine_kind
+        self._sub_kind = engine_kind + ".sub"
         self._span_gate = self.ratio == 1
+        self._head = tuple(idle_head)
 
     def new_iteration(self, n_groups: int = 0) -> None:
+        was = self.sampling
         self._iter += 1
         self.sampling = self._iter % self.ratio == 0
+        if was and self._stage is not None:
+            if self.sampling:
+                # the first begin() ends the last span of the iteration
+                # before at the instant it opens its own: no gap
+                self._turn = True
+            else:
+                self.close()
+                self._end_iteration()
         if self.sampling and n_groups:
             self.batched_groups.record(float(n_groups))
+
+    def _end_iteration(self) -> None:
+        """A sampled begin() iteration has closed its last span."""
+        if self._span_gate:
+            if self._held:  # nothing but head stages: an idle iteration
+                if self._idle_from is None:
+                    self._idle_from = self._held[0][1]
+                self._held.clear()
+            self.fold("n.spans_dropped", self._shed)
+            self._shed = 0
 
     def start(self) -> None:
         if self.sampling:
@@ -136,37 +190,109 @@ class Profiler:
 
     def end(self, stage: str) -> None:
         if self.sampling and self._t0 is not None:
-            dt = time.monotonic() - self._t0
-            s = self.samples.get(stage)
-            if s is None:
-                s = self.samples[stage] = Sample(stage)
-            s.record(dt)
-            if self._plane is not None:
-                self._plane.on_phase(
-                    self._engine_kind, stage, dt, self.sampling,
-                    spans=self._span_gate,
-                )
+            now = time.monotonic()
+            self.observe(stage, now - self._t0)
+            if self._span_gate:
+                self._span(self._engine_kind, stage, self._t0, now)
             self._t0 = None
 
-    def add(self, stage: str, dt: float) -> None:
-        """Record a sub-span the CALLER measured (no start/end pairing —
-        for spans nested inside another stage, e.g. the bulk deliver
-        seam inside the send phases). Sampled iterations only; callers
-        gate their own time.monotonic() pair on `self.sampling` so the
-        off path stays clock-read-free."""
+    def begin(self, stage: str) -> None:
         if self.sampling:
-            s = self.samples.get(stage)
-            if s is None:
-                s = self.samples[stage] = Sample(stage)
-            s.record(dt)
-            if self._plane is not None:
-                self._plane.on_phase(
-                    self._engine_kind, stage, dt, self.sampling,
-                    spans=self._span_gate,
-                )
+            now = time.monotonic()
+            cpu = time.thread_time()
+            if self._stage is not None:
+                self._close(now, cpu)
+            if self._turn:
+                self._turn = False
+                self._end_iteration()
+            self._stage, self._mark, self._cpu_mark = stage, now, cpu
+            if self._span_gate:
+                if self._held and stage not in self._head:
+                    for i, (name, t0, t1) in enumerate(self._held):
+                        if i == 0 and self._idle_from is not None:
+                            t0, self._idle_from = self._idle_from, None
+                        self._span(self._engine_kind, name, t0, t1)
+                    self._held.clear()
+                # the running span shows in dumps with the end it has so
+                # far; what is held does not, its stretch may yet merge
+                opens = flight_recorder().open_spans
+                if self._held:
+                    opens.pop(id(self), None)
+                else:
+                    opens[id(self)] = (
+                        self._engine_kind, stage, self._idle_from or now
+                    )
+
+    def close(self) -> None:
+        """End the span begin() left running (no-op without one)."""
+        if self._stage is not None:
+            self._close(time.monotonic(), time.thread_time())
+            flight_recorder().open_spans.pop(id(self), None)
+
+    def _close(self, now: float, cpu: float) -> None:
+        stage, t0 = self._stage, self._mark
+        self._stage = None
+        self.observe(stage, now - t0, cpu - self._cpu_mark)
+        if self._span_gate:
+            if stage in self._head:
+                self._held.append((stage, t0, now))
+            else:
+                self._span(self._engine_kind, stage, t0, now)
+
+    def _span(self, engine: str, stage: str, t0: float, t1: float) -> None:
+        if flight_recorder().span(engine, stage, t0, t1):
+            self._shed += 1
+
+    def add(self, stage: str, dt: float) -> None:
+        """Record a sub-span the CALLER measured on the loop thread
+        (nested inside another stage, e.g. the bulk deliver seam inside
+        the send phases, or the device_put inside dispatch): samples and
+        a histogram under `<kind>.sub`, no span event. Sampled
+        iterations only; callers gate their own time.monotonic() pair on
+        `self.sampling` so the off path stays clock-read-free."""
+        if self.sampling:
+            self.observe(stage, dt, engine=self._sub_kind)
+
+    def observe(
+        self,
+        stage: str,
+        dt: float,
+        cpu: Optional[float] = None,
+        engine: Optional[str] = None,
+    ) -> None:
+        """One span of `dt` wall seconds (and `cpu` seconds of the
+        calling thread) into the samples and the histogram of `engine`
+        (the profiler's own kind if not given). No gate and no event:
+        the caller has decided that this span is sampled."""
+        s = self.samples.get(stage)
+        if s is None:
+            s = self.samples.setdefault(stage, Sample(stage))
+        s.record(dt)
+        if cpu is not None:
+            self.fold(stage + ".cpu", cpu)
+        if self._plane is not None:
+            self._plane.on_phase(engine or self._engine_kind, stage, dt, True)
+
+    def fold(self, name: str, value: float) -> None:
+        """Add `value` to the sample `name`, which keeps a count and a
+        sum and no percentiles: a counter's increments, a `.cpu`
+        companion, one request's share of a stretch. Callable from any
+        thread, like observe(). Two threads recording into one name at
+        once may lose an increment of its count or sum (a few in a
+        million at the rates here, tolerated: telemetry); a reservoir
+        cannot tear, because Sample.record only appends or overwrites a
+        slot below the length the list already has, and the first use of
+        a name creates its Sample once (dict.setdefault is atomic)."""
+        s = self.samples.get(name)
+        if s is None:
+            s = self.samples.setdefault(name, Sample(name, cap=0))
+        s.record(value)
 
     def report(self) -> str:
-        lines = [s.report() for s in self.samples.values() if len(s)]
+        lines = [
+            s.report() for name, s in self.samples.items()
+            if len(s) and "." not in name
+        ]
         if len(self.batched_groups):
             lines.append(
                 f"batched_groups: mean={self.batched_groups.mean():.1f} "
@@ -175,11 +301,11 @@ class Profiler:
         return "\n".join(lines)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
-        """Machine-readable stage costs (mean/p99 in seconds + sample n);
-        bench.py folds the top stages into its JSON line."""
+        """Machine-readable stage costs (mean/p99 in seconds + sample n)
+        of the loop's own stages; bench.py folds them into its JSON."""
         out: Dict[str, Dict[str, float]] = {}
-        for name, s in self.samples.items():
-            if len(s):
+        for name, s in list(self.samples.items()):
+            if len(s) and "." not in name:
                 out[name] = {
                     "n": float(len(s)),
                     "mean_s": s.mean(),
@@ -187,11 +313,6 @@ class Profiler:
                     "total_s": s.mean() * len(s) * self.ratio,
                 }
         return out
-
-    def top_stages(self, k: int = 3) -> List[str]:
-        """Stage names by estimated total cost, descending."""
-        sm = self.summary()
-        return sorted(sm, key=lambda n: -sm[n]["total_s"])[:k]
 
 
 # ---------------------------------------------------------------------------
@@ -230,27 +351,64 @@ class LatencySampler:
 
 
 class LatencyTrace:
-    """Per-sampled-request timestamps, carried on the RequestState AND the
-    proposed Entry (the same object travels propose -> arena -> commit ->
-    apply on the proposing node, so the engine can stamp t_commit without
-    a registry lookup). `owner` pins observation to the proposing node —
-    co-hosted replicas apply the identical Entry objects and must not
-    double-count; `done` makes observation exactly-once-ish.
+    """Per-sampled-request timestamps, carried on the proposed Entry (the
+    same object travels propose -> arena -> commit -> apply on the
+    proposing node, so the engine stamps it without a registry lookup)
+    or, for a read, on its RequestState. `owner` pins observation to the
+    proposing node — co-hosted replicas apply the identical Entry objects
+    and must not double-count; `done` makes observation exactly-once-ish.
+
+    Each stamp is an instant (time.monotonic) and, but for `t_apply0`,
+    the engine's launch ordinal at that instant (VectorEngine.launch_no;
+    0 on an engine that has none): `t0` enqueue, `t_pack` taken out of the node's queue into
+    a launch, `t_commit` quorum commit seen by the host (a read: its
+    context confirmed), `t_apply0` an apply worker took up the ready
+    nodes, the one holding it among them (writes only), `t_done` applied
+    and the waiter notified.
 
     `trace_id` is the cross-node causal key: minted at propose time
     (mint_trace_id), copied onto the proposed Entry (and from there onto
     wire Messages), and stamped into every flight-recorder event the
     request touches — so merged multi-node dumps reconstruct one
-    proposal's propose -> replicate -> quorum -> apply chain."""
+    proposal's propose -> replicate -> quorum -> apply chain. Reads
+    carry none."""
 
-    __slots__ = ("owner", "t0", "t_commit", "done", "trace_id")
+    __slots__ = (
+        "owner", "trace_id", "done",
+        "t0", "t_pack", "t_commit", "t_apply0", "t_done",
+        "n0", "n_pack", "n_commit", "n_done",
+    )
 
-    def __init__(self, owner, t0: float, trace_id: int = 0) -> None:
+    def __init__(self, owner, t0: float, trace_id: int = 0, n0: int = 0) -> None:
         self.owner = owner
-        self.t0 = t0
-        self.t_commit = 0.0
-        self.done = False
         self.trace_id = trace_id
+        self.done = False
+        self.t0 = t0
+        self.t_pack = self.t_commit = self.t_apply0 = self.t_done = 0.0
+        self.n0 = n0
+        self.n_pack = self.n_commit = self.n_done = 0
+
+    def fold(self, prof: Profiler, kind: str) -> None:
+        """At t_done, once: this request's share of each stretch of its
+        path into the engine's profiler, under `req.<kind>.*` (kind "w":
+        queue, replicate, apply_wait, apply; kind "r": queue, confirm,
+        complete), with the launches from pack to commit and a count of
+        one; the stretches add up to t_done - t0. A request that an
+        engine stamped
+        only in part (the scalar engine packs nothing) folds nothing."""
+        if not self.t_pack or not self.t_commit:
+            return
+        pre = "req." + kind + "."
+        prof.fold(pre + "queue", self.t_pack - self.t0)
+        if kind == "w":
+            prof.fold(pre + "replicate", self.t_commit - self.t_pack)
+            prof.fold(pre + "apply_wait", self.t_apply0 - self.t_commit)
+            prof.fold(pre + "apply", self.t_done - self.t_apply0)
+        else:
+            prof.fold(pre + "confirm", self.t_commit - self.t_pack)
+            prof.fold(pre + "complete", self.t_done - self.t_commit)
+        prof.fold(pre + "launches", self.n_commit - self.n_pack + 1)
+        prof.fold(pre + "n", 1)
 
 
 # ---------------------------------------------------------------------------
@@ -410,6 +568,12 @@ def read_mmap_ring(path: str) -> Tuple[dict, List[dict]]:
     return meta, [d for _, d in slots]
 
 
+# the span store holds at least 60 s of a loop that launches 20 times a
+# second and leaves some 25 spans a launch (12 phases, the seam's four
+# sub-spans, a deliver per send phase)
+SPAN_CAPACITY = 32768
+
+
 class FlightRecorder:
     """Bounded ring of structured events with monotonic timestamps.
 
@@ -418,15 +582,32 @@ class FlightRecorder:
     The ring bounds memory: a runaway event source overwrites the oldest
     breadcrumbs instead of growing without limit.
 
+    The profilers' `phase_span` events have a bounded store of their own
+    (span()), so that no other event can evict them; a span that the
+    full store sheds before any dump has returned it is counted in
+    `spans_dropped` (one that a poller has had is retired, not lost).
+    `open_spans` holds the span each
+    begin()-style profiler has running, which dumps show with the end it
+    has so far: a poller sees the loop's present, and a hang dump names
+    the phase the loop is stuck in.
+
     Every event carries a `cluster` field (0 = host-level: breakers,
     send queues, fairness) so dumps filter server-side by Raft group.
     attach_mmap() tees every record into a crash-persistent MmapRing so a
     SIGKILL'd process still leaves a readable timeline (read_mmap_ring)."""
 
-    __slots__ = ("_buf", "_ring", "mono_offset")
+    __slots__ = ("_buf", "_ring", "mono_offset", "_spans", "_span_total",
+                 "_span_seen", "spans_dropped", "open_spans")
 
-    def __init__(self, capacity: int = 8192) -> None:
+    def __init__(
+        self, capacity: int = 8192, span_capacity: int = SPAN_CAPACITY
+    ) -> None:
         self._buf: deque = deque(maxlen=capacity)
+        self._spans: deque = deque(maxlen=span_capacity)
+        self._span_total = 0  # spans ever stored
+        self._span_seen = 0  # of those, how many a dump has returned
+        self.spans_dropped = 0
+        self.open_spans: Dict[int, Tuple[str, str, float]] = {}
         self._ring: Optional[MmapRing] = None
         # wall-minus-monotonic at init: dumps carry it so the timeline CLI
         # can merge rings/dumps from different processes (each process's
@@ -438,20 +619,49 @@ class FlightRecorder:
             fields["cluster"] = 0  # host-level event
         t = time.monotonic()
         self._buf.append((t, event, fields))
+        if self._ring is not None:
+            d = {"t": round(t, 6), "event": event}
+            d.update(fields)
+            self._tee(d)
+
+    def span(self, engine: str, phase: str, t0: float, t1: float) -> bool:
+        """Store one finished profiler span as a `phase_span` event: `t`
+        is the instant it ended and `t0` the instant it began, both as
+        the profiler read them, `dur` their difference. True when the
+        store was full and shed, to make room, a span no dump had seen."""
+        d = {
+            "t": t1, "event": "phase_span", "cluster": 0, "engine": engine,
+            "phase": phase, "dur": t1 - t0, "t0": t0,
+        }
+        spans = self._spans
+        # the oldest span stored is number _span_total - maxlen
+        shed = (
+            len(spans) == spans.maxlen
+            and self._span_total - spans.maxlen >= self._span_seen
+        )
+        if shed:
+            self.spans_dropped += 1
+        self._span_total += 1
+        spans.append(d)
+        if self._ring is not None:
+            self._tee(d)
+        return shed
+
+    def _tee(self, d: dict) -> None:
         ring = self._ring
         if ring is not None:
             try:
-                d = {"t": round(t, 6), "event": event}
-                d.update(fields)
                 ring.write(json.dumps(d, default=str, sort_keys=True).encode())
             except Exception:
                 pass  # persistence must never break the producer
 
     def __len__(self) -> int:
-        return len(self._buf)
+        return len(self._buf) + len(self._spans)
 
     def reset(self) -> None:
         self._buf.clear()
+        self._spans.clear()
+        self._span_total = self._span_seen = self.spans_dropped = 0
 
     # ------------------------------------------------- persistent backing
     def attach_mmap(
@@ -495,12 +705,12 @@ class FlightRecorder:
             ring.flush()
 
     # ------------------------------------------------------------- dumps
-    def _snapshot(self) -> list:
-        """Point-in-time copy of the deque that is safe against concurrent
-        record(): under free threading list(deque) can raise RuntimeError
+    @staticmethod
+    def _snapshot(buf: deque) -> list:
+        """Point-in-time copy of a deque that is safe against concurrent
+        appends: under free threading list(deque) can raise RuntimeError
         ("deque mutated during iteration") — retry until a clean pass
         (appends are tiny, so a clean pass comes within a few tries)."""
-        buf = self._buf
         while True:
             try:
                 return list(buf)
@@ -515,19 +725,37 @@ class FlightRecorder:
     ) -> List[dict]:
         """Events oldest-first as plain dicts (t = monotonic seconds).
         Server-side filters: cluster_id matches the event's `cluster`
-        field, trace_id the `trace` field, event the event name."""
+        field, trace_id the `trace` field, event the event name. Spans
+        are host-level (cluster 0) and carry no trace id; one that still
+        runs is marked `open` and ends at this instant."""
         out = []
-        for t, ev, fields in self._snapshot():
-            if event is not None and ev != event:
-                continue
-            if cluster_id is not None and fields.get("cluster") != cluster_id:
-                continue
-            if trace_id is not None and fields.get("trace") != trace_id:
-                continue
-            d = {"t": round(t, 6), "event": ev}
-            if fields:
-                d.update(fields)
-            out.append(d)
+        if event != "phase_span":
+            for t, ev, fields in self._snapshot(self._buf):
+                if event is not None and ev != event:
+                    continue
+                if cluster_id is not None and fields.get("cluster") != cluster_id:
+                    continue
+                if trace_id is not None and fields.get("trace") != trace_id:
+                    continue
+                d = {"t": round(t, 6), "event": ev}
+                if fields:
+                    d.update(fields)
+                out.append(d)
+        if (event is None or event == "phase_span") and trace_id is None \
+                and not cluster_id:
+            n = len(out)
+            seen = self._span_total  # read first: the copy holds these
+            out.extend(dict(d) for d in self._snapshot(self._spans))
+            self._span_seen = seen
+            now = time.monotonic()
+            for engine, phase, t0 in list(self.open_spans.values()):
+                out.append({
+                    "t": now, "event": "phase_span", "cluster": 0,
+                    "engine": engine, "phase": phase, "dur": now - t0,
+                    "t0": t0, "open": True,
+                })
+            if n:
+                out.sort(key=lambda d: d["t"])
         return out
 
     def to_jsonl(self, meta=None, **filters) -> str:
@@ -561,7 +789,7 @@ def flight_recorder() -> FlightRecorder:
 __all__ = [
     "Sample",
     "Profiler",
-    "STAGES",
+    "SPAN_CAPACITY",
     "LatencySampler",
     "LatencyTrace",
     "FlightRecorder",

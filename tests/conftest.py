@@ -75,7 +75,8 @@ _HANG_DUMP_S = 600
 # mmap ring persists every recorded event the moment it happens (mmap
 # pages live in the kernel page cache, so they survive ANY process death);
 # after a killed run, `python -m dragonboat_tpu.tools.timeline
-# .pytest_flight/live.ring` replays the tail, and the per-test
+# .pytest_flight/live.ring` (`live-gw<n>.ring` per pytest-xdist worker)
+# replays the tail, and the per-test
 # `_test_start` markers show which test was running when the axe fell. ----
 import atexit  # noqa: E402
 import signal  # noqa: E402
@@ -92,8 +93,13 @@ def _attach_session_ring():
     try:
         from dragonboat_tpu.trace import flight_recorder
 
+        # one ring per process: attach_mmap rotates a ring that is there
+        # to .prev, so xdist workers handed one path would rename each
+        # other's rings away and read back somebody else's
+        worker = os.environ.get("PYTEST_XDIST_WORKER")
         path = os.environ.get("FLIGHT_RING_PATH") or os.path.join(
-            _flight_dump_dir(), "live.ring"
+            _flight_dump_dir(),
+            f"live-{worker}.ring" if worker else "live.ring",
         )
         rec = flight_recorder()
         rec.attach_mmap(path)

@@ -386,10 +386,13 @@ def _mk_host(nid, reg, tmp, scope):
                 max_peers=4,
                 log_window=64,
                 share_scope=scope,
-                profile_sample_ratio=1,  # sample (and trace) EVERY request
+                profile_sample_ratio=1,  # sample EVERY step
             ),
         )
     )
+    # and trace EVERY request: the engine's own request sampler stops at
+    # 1 in vector.REQUEST_SAMPLE_FLOOR
+    nh.engine.request_sampler.ratio = 1
     nh.start_cluster(
         {h: f"ca{h}:1" for h in HOSTS},
         False,

@@ -334,7 +334,7 @@ def _random_state_and_output(rng):
                 "apply_from": (KCFG.groups,), "apply_to": (KCFG.groups,),
                 "commit_index": (KCFG.groups,),
                 "hard_changed": (KCFG.groups,),
-                "dropped_propose": (KCFG.groups,),
+                "dropped_readindex": (KCFG.groups,),
                 "dropped_cc": (KCFG.groups,),
                 "fwd_leader": (KCFG.groups,),
                 "noop_appended": (KCFG.groups,),

@@ -335,10 +335,13 @@ def single_host(tmp_path):
                 max_groups=8,
                 max_peers=4,
                 log_window=64,
-                profile_sample_ratio=1,  # sample EVERY request
+                profile_sample_ratio=1,  # sample EVERY step
             ),
         )
     )
+    # and EVERY request: the engine's own request sampler stops at 1 in
+    # vector.REQUEST_SAMPLE_FLOOR
+    nh.engine.request_sampler.ratio = 1
     try:
         nh.start_cluster(
             {1: "obs1:1"},

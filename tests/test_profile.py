@@ -15,6 +15,7 @@ Four subjects:
 """
 from __future__ import annotations
 
+import contextlib
 import gzip
 import io
 import json
@@ -42,54 +43,123 @@ from dragonboat_tpu.trace import Profiler, flight_recorder
 # ---------------------------------------------------------------------------
 
 
-def test_unsampled_iterations_stay_event_free():
+def _drive(prof):
+    """One iteration's worth of every way a profiler is fed on the loop
+    thread: a begin() chain, a start/end pair, a sub-span."""
+    prof.new_iteration(1)
+    prof.begin("wait")
+    prof.begin("pack")
+    prof.begin("dispatch")
+    prof.start()
+    prof.end("step")
+    prof.add("deliver", 0.001)
+
+
+def _unsampled_profiler():
+    """(b), the profiler alone: iterations 1..3 of ratio 4 are never
+    sampled and leave nothing anywhere; iteration 4 fills samples and
+    histograms but, at SPARSE sampling, no span (spans would crowd the
+    store at the always-on production default)."""
     plane = PhasePlane()
     prof = Profiler(sample_ratio=4)
-    prof.attach_phase_plane(plane, "vector")
+    prof.attach_phase_plane(plane, "vector", idle_head=("wait", "pack"))
     rec = flight_recorder()
     rec.reset()
-    for _ in range(3):  # iterations 1..3 of ratio 4: never sampled
-        prof.new_iteration(1)
+    for _ in range(3):
+        _drive(prof)
         assert not prof.sampling
-        prof.start()
-        prof.end("pack")
-        prof.add("deliver", 0.001)
     assert plane.total_observations() == 0, "histogram observed off-path"
+    assert prof.samples == {}, "sample created off-path"
     assert len(rec) == 0, "recorder event on the unsampled path"
-    # iteration 4 IS sampled: histograms fill — but at SPARSE sampling
-    # no flight-recorder spans are emitted (they would crowd the ring's
-    # bounded forensic history at the always-on production default)
-    prof.new_iteration(1)
+    assert rec.open_spans == {}, "running span published off-path"
+    _drive(prof)
     assert prof.sampling
-    prof.start()
-    prof.end("pack")
-    prof.add("deliver", 0.001)
+    prof.new_iteration()  # unsampled again: closes the running span
     assert plane.histogram("vector", "pack").count == 1
-    assert plane.histogram("vector", "deliver").count == 1
+    assert plane.histogram("vector", "dispatch").count == 1
+    assert plane.histogram("vector", "step").count == 1
+    assert plane.histogram("vector.sub", "deliver").count == 1
+    assert len(prof.samples["pack.cpu"]) == 1, "no CPU companion"
+    assert "deliver.cpu" not in prof.samples and "step.cpu" not in prof.samples
     assert len(rec) == 0, "phase_span recorded at sparse sampling"
+    assert rec.open_spans == {}
+
+
+def _unsampled_engine(tmp_path, monkeypatch):
+    """(b), a live engine whose ratio never comes up: writes, a batch
+    and reads go through, and no span, no sample, no request trace and
+    no block_until_ready came of them."""
+    import jax
+
+    from dragonboat_tpu.engine import node as node_mod
+
+    made, blocked = [], []
+    real_trace = node_mod.LatencyTrace
+    monkeypatch.setattr(
+        node_mod, "LatencyTrace",
+        lambda *a, **k: made.append(1) or real_trace(*a, **k),
+    )
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: blocked.append(1) or real_block(x),
+    )
+    with _single_host(tmp_path, profile_sample_ratio=1 << 30) as nh:
+        flight_recorder().reset()
+        sess = nh.get_noop_session(1)
+        for i in range(4):
+            nh.sync_propose(sess, f"k{i}=v".encode(), timeout_s=10.0)
+        h = nh.propose_batch_async(sess, [b"a=1", b"b=2"], 5.0)
+        assert h.wait(10.0) and h.completed == 2
+        rs = nh.read_index(1, 5.0)
+        assert rs.wait(10.0).completed
+        assert rs.lat is None
+        prof = nh.engine.core.profiler
+        assert not prof.sampling
+        assert prof.samples == {}, sorted(prof.samples)
+        assert nh.engine.step_stats()["launches"] > 0
+    rec = flight_recorder()
+    assert rec.dump(event="phase_span") == []
+    assert not [e for e in rec.dump() if "trace" in e], "chain event"
+    assert not made, "an unsampled request allocated a LatencyTrace"
+    assert not blocked, "block_until_ready on an unsampled iteration"
+
+
+@pytest.mark.parametrize("case", ["profiler", "engine"])
+def test_unsampled_iterations_stay_event_free(case, tmp_path, monkeypatch):
+    if case == "profiler":
+        _unsampled_profiler()
+    else:
+        _unsampled_engine(tmp_path, monkeypatch)
 
 
 def test_full_sampling_emits_recorder_spans():
     """Spans reach the flight recorder only at ratio 1 (the bench/debug
-    opt-in, EngineConfig.profile_sample_ratio=1)."""
+    opt-in, EngineConfig.profile_sample_ratio=1): the loop's own, each
+    with the instant it ended as `t` and the instant it began as `t0`;
+    a sub-span leaves a histogram under `<kind>.sub` and no event."""
     plane = PhasePlane()
     prof = Profiler(sample_ratio=1)
     prof.attach_phase_plane(plane, "vector")
     rec = flight_recorder()
     rec.reset()
+    t0 = time.monotonic()
     prof.new_iteration(1)
     prof.start()
     prof.end("pack")
     prof.add("deliver", 0.001)
-    events = [e for e in rec.dump() if e["event"] == "phase_span"]
-    assert {e["phase"] for e in events} == {"pack", "deliver"}
-    assert all(e["engine"] == "vector" for e in events)
+    t1 = time.monotonic()
+    (e,) = rec.dump(event="phase_span")
+    assert (e["engine"], e["phase"]) == ("vector", "pack")
+    assert t0 <= e["t0"] <= e["t"] <= t1 and e["dur"] == e["t"] - e["t0"]
+    assert plane.histogram("vector.sub", "deliver").count == 1
 
 
 def test_phase_vocabulary_covers_both_engines():
     # the canonical keys bench zero-fills; decode phases 0-6 all named
-    for p in ("pack", "dispatch", "fetch", "place", "send_rep", "save",
-              "send_resp", "apply", "reads", "maintain", "deliver"):
+    for p in ("wait", "prepare", "pack", "dispatch", "fetch", "place",
+              "send_rep", "save", "send_resp", "apply", "reads", "maintain",
+              "deliver", "put", "launch", "device_wait", "copy"):
         assert p in VECTOR_PHASES
     for p in ("step", "fast_apply", "send", "save", "apply", "exec"):
         assert p in EXEC_PHASES
@@ -99,7 +169,6 @@ def test_plane_exposition_is_conformant():
     from tests.test_observability import _parse_exposition
 
     plane = PhasePlane()
-    plane.record_spans = False
     plane.on_phase("vector", "pack", 0.002, True)
     plane.on_phase("vector", "save", 0.004, True)
     plane.on_phase("exec", "step", 0.001, True)
@@ -189,8 +258,8 @@ def test_compile_watch_attributes_retraces_per_function():
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture()
-def vec_host(tmp_path):
+@contextlib.contextmanager
+def _single_host(tmp_path, **engine):
     from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
     from dragonboat_tpu.nodehost import NodeHost
     from dragonboat_tpu.transport.loopback import _Registry, loopback_factory
@@ -206,11 +275,8 @@ def vec_host(tmp_path):
             raft_rpc_factory=lambda l: loopback_factory(l, reg),
             enable_metrics=True,
             engine=EngineConfig(
-                kind="vector",
-                max_groups=8,
-                max_peers=4,
-                log_window=64,
-                profile_sample_ratio=1,  # sample EVERY step
+                kind="vector", max_groups=8, max_peers=4, log_window=64,
+                **engine,
             ),
         )
     )
@@ -232,6 +298,12 @@ def vec_host(tmp_path):
         yield nh
     finally:
         nh.stop()
+
+
+@pytest.fixture()
+def vec_host(tmp_path):
+    with _single_host(tmp_path, profile_sample_ratio=1) as nh:  # EVERY step
+        yield nh
 
 
 @pytest.mark.perf
