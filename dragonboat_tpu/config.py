@@ -170,14 +170,20 @@ class EngineConfig:
     # kernel routes cross-shard lane traffic device-to-device between
     # inner steps and stays bit-identical to the unsharded reference.
     steps_per_sync: int = 1
-    # Pipeline the engine loop: dispatch kernel step t, then decode step
-    # t-1's output while the device computes. Removes the device wait from
-    # the loop's critical path on accelerators, where the wait is real
-    # idle time (gain on the chip: not measured); on the cpu backend the
-    # "wait" is the host computing the kernel, so there is nothing to
-    # reclaim and the extra step of latency only hurts. None = auto: on
-    # for accelerators, off for cpu. Costs one extra step of pack
-    # staleness, which the window throttle accounts for.
+    # Hide the kernel behind host work (K=1): dispatch kernel step t, run
+    # step t-1's maintenance (window compaction, snapshot triggers,
+    # catch-up of parked peers) while the device computes, and fetch and
+    # decode step t at the top of the next iteration, before the next
+    # pack. Everything of a step that sends a message or acknowledges a
+    # request is decoded before the next launch, so a Raft hop takes one
+    # launch with the option on as with it off; only maintenance, which
+    # no request waits for, is deferred, and _pack sees the device
+    # window's first index one maintenance late (it throttles on it, so
+    # late only means a little less room). On the cpu backend the "wait"
+    # is the host computing the kernel, so there is nothing to hide.
+    # None = auto: on for accelerators, off for cpu. False on the chip
+    # shows the kernel on every step (PERF.md, PR 25). Ignored at
+    # steps_per_sync > 1.
     overlap_decode: "Optional[bool]" = None
     # Stage-profiler sampling for the vector engine hot loop: 0 = sparse
     # default (1 in 32 iterations — steady-state cost is two clock reads

@@ -1107,8 +1107,8 @@ class VectorEngine:
         # sampled stage durations also land in the process-global phase
         # plane (engine_phase_seconds{engine="vector",phase=...}) and, at
         # ratio 1, in the flight recorder's span store; unsampled steps
-        # never reach either. Every iteration starts with wait, prepare
-        # and pack whether or not it will launch.
+        # never reach either. An iteration that neither decodes a step
+        # nor launches one has wait, prepare and pack and nothing else.
         self.profiler.attach_phase_plane(
             phase_plane(), "vector", idle_head=("wait", "prepare", "pack")
         )
@@ -1313,8 +1313,7 @@ class VectorEngine:
                 planes[f"resid.{name}"] = int(arr.nbytes)
         staging = sum(
             int(plane.nbytes)
-            for buf, ticks, _inbox in self._bufsets
-            for plane in list(buf.values()) + [ticks]
+            for plane in list(self._buf.values()) + [self._ticks]
         )
         self.census.set_planes(
             planes,
@@ -1350,36 +1349,27 @@ class VectorEngine:
             self._threads.append(t)
 
     def _alloc_buffers(self) -> None:
-        # numpy staging buffers for the inbox. TWO sets: with overlapped
-        # decode, step t's buffers must stay untouched while the device may
-        # still be reading them, so pack alternates between the sets.
+        # numpy staging buffers for the inbox. ONE set in every loop: a
+        # step's output is fetched before the next _pack rewrites them, so
+        # the device has consumed them by then.
         G, K = self.kcfg.groups, self.kcfg.inbox_depth
         E = self.kcfg.max_entries_per_msg
-
-        def mk():
-            return {
-                "mtype": np.full((G, K), MSG.NONE, np.int32),
-                "from_slot": np.zeros((G, K), np.int32),
-                "term": np.zeros((G, K), np.int32),
-                "log_index": np.zeros((G, K), np.int32),
-                "log_term": np.zeros((G, K), np.int32),
-                "commit": np.zeros((G, K), np.int32),
-                "reject": np.zeros((G, K), bool),
-                "hint": np.zeros((G, K), np.int32),
-                "hint_high": np.zeros((G, K), np.int32),
-                "n_entries": np.zeros((G, K), np.int32),
-                "entry_terms": np.zeros((G, K, E), np.int32),
-                "entry_cc": np.zeros((G, K, E), bool),
-            }
-
-        self._bufsets = []
-        for _ in range(2 if self._overlap else 1):
-            buf = mk()
-            ticks = np.zeros((G,), np.int32)
-            inbox = Inbox(**{f: buf[f] for f in Inbox._fields})
-            self._bufsets.append((buf, ticks, inbox))
-        self._buf_idx = 0
-        self._buf, self._ticks, self._host_inbox = self._bufsets[0]
+        self._buf = {
+            "mtype": np.full((G, K), MSG.NONE, np.int32),
+            "from_slot": np.zeros((G, K), np.int32),
+            "term": np.zeros((G, K), np.int32),
+            "log_index": np.zeros((G, K), np.int32),
+            "log_term": np.zeros((G, K), np.int32),
+            "commit": np.zeros((G, K), np.int32),
+            "reject": np.zeros((G, K), bool),
+            "hint": np.zeros((G, K), np.int32),
+            "hint_high": np.zeros((G, K), np.int32),
+            "n_entries": np.zeros((G, K), np.int32),
+            "entry_terms": np.zeros((G, K, E), np.int32),
+            "entry_cc": np.zeros((G, K, E), bool),
+        }
+        self._ticks = np.zeros((G,), np.int32)
+        self._host_inbox = Inbox(**{f: self._buf[f] for f in Inbox._fields})
         # columnar row staging for _pack: rows accumulate as python column
         # lists and land in the numpy planes as ONE fancy-indexed scatter
         # per plane (_flush_staged_rows) — list appends are ~4x cheaper
@@ -1390,7 +1380,6 @@ class VectorEngine:
             "hint": [], "hint_high": [], "n_entries": [], "ents": [],
         }
         if self._sharding is not None:
-            # shapes identical across the sets: one sharding pytree serves
             self._inbox_shardings = (
                 jax.tree_util.tree_map(self._sharding, self._host_inbox),
                 self._sharding(self._ticks),
@@ -1723,15 +1712,30 @@ class VectorEngine:
         self._ready.set()
 
     def _run_once(self) -> None:
-        # reconciles, snapshot finalization and rebase rewrite per-group
-        # mirrors (_m_base/_m_last/_lane_by_g); an undecoded in-flight step
-        # would later clobber them with stale device output, so these rare
-        # paths drain the pipeline first
         prof = self.profiler
         prof.begin("prepare")
+        # overlapped K=1: the step the last iteration launched is fetched
+        # and decoded FIRST, before the dirty set is swapped, so what it
+        # hands to co-hosted lanes (set_node_ready marks them dirty) and
+        # the mirrors it refreshes are in this iteration's pack: one
+        # launch a Raft hop, as in the unoverlapped loop. Only its
+        # maintain is owed until this iteration's launch is out, and
+        # hides the kernel: it acknowledges nothing, and all it sends is
+        # the host-side catch-up of a peer that fell out of the device
+        # window. It is owed inside this call only: every return below
+        # pays it. (An exception in between loses it; every trigger in
+        # _maintain is a level, so the next step's maintain makes it up.)
+        owed = self._decode_pending()
+        if owed is not None:
+            prof.begin("prepare")
+        # reconciles, snapshot finalization and rebase rewrite per-group
+        # mirrors (_m_base/_m_last/_lane_by_g) that _maintain reads beside
+        # the step's output, so these rare paths take a step whose
+        # maintain has run, and nothing in flight
         if self._reconq or self._snap_status or self._rebase_due:
-            if self._pending is not None:
-                self._flush_pending()  # times its own fetch and decode
+            if owed is not None:
+                self._decode_maintain(owed)
+                owed = None
                 prof.begin("prepare")
             if self._rebase_due:
                 self._rebase_due = False
@@ -1767,13 +1771,6 @@ class VectorEngine:
                     if lane is not None and lane.active:
                         work.add(lane)
         work |= self._catchups
-        # swap to the idle buffer set BEFORE packing: the other set may
-        # still be read by the in-flight step
-        if self._overlap:
-            self._buf_idx = 1 - self._buf_idx
-            self._buf, self._ticks, self._host_inbox = self._bufsets[
-                self._buf_idx
-            ]
         prof.begin("pack")
         had, packs = self._pack(work)
         if not had:
@@ -1797,9 +1794,8 @@ class VectorEngine:
                 # must be consumed even with no fresh host work
                 skip = False
             if skip:
-                # nothing new dispatched: the pipeline must not sit on an
-                # undecoded step indefinitely
-                self._flush_pending()
+                if owed is not None:
+                    self._decode_maintain(owed)  # no launch to hide behind
                 return
         prof.begin("dispatch")
         if ticks:
@@ -1876,16 +1872,21 @@ class VectorEngine:
         if sampling:
             self._add_seam("put", "launch", t0, t1)
         if self._overlap:
-            # pipeline: decode step t-1 while the device computes step t
-            # (jax dispatch is async — `out` is a future). Ordering
-            # invariants live inside each step's decode, so pipelining
-            # steps preserves them; pack staleness is accounted for by the
-            # per-lane packed_pending window tracking. Swap FIRST so a
-            # decode exception cannot also lose the just-dispatched step.
-            pending, self._pending = self._pending, (work, packs, out)
-            self._flush_one(pending)
+            # pipeline: the device computes step t (jax dispatch is async,
+            # `out` is a future) under step t-1's maintain, the loop's
+            # wait and the next prepare; the next iteration fetches and
+            # decodes it before it packs. The eager compaction in
+            # _maintain lands on the state step t returns, and
+            # _m_devfirst with it: _pack sees both one maintain behind.
+            # Park the launched step FIRST so a maintain that raises
+            # cannot lose it.
+            self._pending = (work, packs, out)
+            if owed is not None:
+                self._decode_maintain(owed)
         else:
-            self._decode(work, packs, self._fetch_output(out))
+            o = self._fetch_output(out)
+            self._decode(work, packs, o)
+            self._decode_maintain(o)
 
     def _add_seam(self, first: str, second: str, t0: float, t1: float) -> None:
         """Two consecutive sub-spans of the host<->device seam, begun at
@@ -1937,15 +1938,23 @@ class VectorEngine:
         note_seam_sync()  # runtime sync audit: one transfer per K steps
         return o._asdict(), pl._asdict(), np.array(rc, np.int32)
 
-    def _flush_pending(self) -> None:
+    def _decode_pending(self) -> Optional[dict]:
+        """Fetch the in-flight step, if there is one, and decode all of it
+        but its maintain; returns its output, to which _decode_maintain is
+        still owed. The step leaves _pending first: a decode that raises
+        is not decoded twice."""
         pending, self._pending = self._pending, None
-        self._flush_one(pending)
-
-    def _flush_one(self, pending) -> None:
         if pending is None:
-            return
+            return None
         work, packs, out = pending
-        self._decode(work, packs, self._fetch_output(out))
+        o = self._fetch_output(out)
+        self._decode(work, packs, o)
+        return o
+
+    def _flush_pending(self) -> None:
+        o = self._decode_pending()
+        if o is not None:
+            self._decode_maintain(o)
 
     def _run_gc(self, gc_cids) -> None:
         """Request-timeout pass over lanes with outstanding requests only
@@ -2496,10 +2505,12 @@ class VectorEngine:
     # --------------------------------------------------------------- decode
     def _decode(self, worked: Set[_Lane], packs, o: dict) -> None:
         """One engine step's host fan-out (the K=1 path): the decode
-        phases run in the reference ordering over a single StepOutput.
-        The phase bodies live in the _decode_* subfunctions so the
-        multi-step super-step (_decode_super) can orchestrate the same
-        code with its masked, per-inner-step inputs."""
+        phases that send, save and acknowledge run in the reference
+        ordering over a single StepOutput; the caller owes the step its
+        _decode_maintain. The phase bodies live in the _decode_*
+        subfunctions so the multi-step super-step (_decode_super) can
+        orchestrate the same code with its masked, per-inner-step
+        inputs."""
         self.last_output = o  # numpy snapshot for diagnostics/tools
         note_engine_steps(1)
         prof = self.profiler
@@ -2524,8 +2535,11 @@ class VectorEngine:
         # ---- phase 5: confirmed reads ------------------------------------
         prof.begin("reads")
         self._decode_reads(o)
-        # ---- phase 6: maintenance ----------------------------------------
-        prof.begin("maintain")
+
+    def _decode_maintain(self, o: dict) -> None:
+        """Phase 6 of a K=1 step, maintenance: no request waits for it,
+        so the overlapped loop runs it behind the next launch."""
+        self.profiler.begin("maintain")
         self._maintain(o)
 
     def _decode_super(self, worked: Set[_Lane], packs, o: dict, pl: dict) -> None:
@@ -3843,16 +3857,14 @@ class VectorEngine:
         self._state = s._replace(active=s.active.at[g].set(False))
         lane.active = False
         # zero the freed lane's host planes so nothing leaks into the next
-        # tenant of g: the inbox staging rows of BOTH buffer sets (the
-        # overlap pipeline alternates sets; the next occupant must never
-        # see a stale row where _pack left data the kernel has already
-        # consumed), the pending-tick row, and every protocol mirror
-        # (lane_stats/decode gate on _m_active, but stale bases would
-        # corrupt the first reads after a mis-gated access)
-        for buf, ticks, _inbox in self._bufsets:
-            for name, plane in buf.items():
-                plane[g] = MSG.NONE if name == "mtype" else 0
-            ticks[g] = 0
+        # tenant of g: the inbox staging rows (the next occupant must
+        # never see a stale row where _pack left data the kernel has
+        # already consumed), the pending-tick row, and every protocol
+        # mirror (lane_stats/decode gate on _m_active, but stale bases
+        # would corrupt the first reads after a mis-gated access)
+        for name, plane in self._buf.items():
+            plane[g] = MSG.NONE if name == "mtype" else 0
+        self._ticks[g] = 0
         self._m_base[g] = 0
         self._m_devfirst[g] = 1
         self._m_term[g] = 0

@@ -4,8 +4,10 @@ launches, the span carrier and the ReadIndex drop counter (ISSUE 23).
 A small co-hosted deployment (3 NodeHosts on one shared engine core, two
 groups of three replicas) runs at profile_sample_ratio 1 in the three
 modes the benchmark's cells use: one protocol step a launch with the
-decode after its own launch, the same with the decode overlapped one
-launch late (the accelerator default), and eight protocol steps a launch.
+whole decode after its own launch, the same with only a step's maintain
+left behind the next launch (the accelerator default: the kernel runs
+under it, and every hop still takes one launch), and eight protocol steps
+a launch. ISSUE 25 added the cases that hold the overlapped loop to that.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ from dragonboat_tpu.profile import (
     phase_plane,
 )
 from dragonboat_tpu.trace import FlightRecorder, flight_recorder
+from dragonboat_tpu.types import MessageType as MT
 
 GROUPS = (1, 2)
 MODES = {
@@ -28,7 +31,9 @@ MODES = {
     # commit). With three co-hosted replicas and one step a launch a
     # commit needs the leader's append, the followers' append and ack and
     # the leader's commit; at eight steps a launch the kernel routes all
-    # of that between lanes inside one launch.
+    # of that between lanes inside one launch. The overlapped loop takes
+    # no more launches than the plain one (test_overlap_costs_no_launch):
+    # it decodes a step before the next pack and defers only maintain.
     "k1": (1, False, 2),
     "k1-overlap": (1, True, 2),
     "k8": (8, None, 1),
@@ -352,3 +357,260 @@ def test_readindex_dropped_counts_the_kernels_plane(tmp_path, overlap):
         assert s.mean() * len(s) - mark_prof == pytest.approx(sum(seen))
     finally:
         c.stop()
+
+
+# ------------------------------------------- (f) one launch a hop (ISSUE 25)
+def _request_sums(core) -> dict:
+    """How many writes and reads were sampled so far, and their launches."""
+    out = {}
+    for kind in ("w", "r"):
+        n = core.profiler.samples.get(f"req.{kind}.n")
+        la = core.profiler.samples.get(f"req.{kind}.launches")
+        out[kind] = (
+            len(n) if n is not None else 0,
+            la.mean() * len(la) if la is not None else 0.0,
+        )
+    return out
+
+
+def test_overlap_costs_no_launch(tmp_path):
+    """A commit and a ReadIndex take as many launches in the overlapped
+    loop as in the plain one: what a step's decode hands to co-hosted
+    lanes rides the very next launch, because the decode comes before the
+    next pack and only maintain stays behind the launch. (Before ISSUE 25
+    the overlapped loop took twice as many.)"""
+    means = {}
+    for mode in ("k1", "k1-overlap"):
+        k, overlap, _least = MODES[mode]
+        c = Cluster(tmp_path / mode, f"hops-{mode}", steps_per_sync=k,
+                    overlap_decode=overlap)
+        try:
+            assert c.core._overlap == overlap
+            # a write first: the kernel drops a ReadIndex that comes
+            # before its leader's first commit of the term
+            c.traffic(1)
+            base = _request_sums(c.core)
+            for i in range(50):  # writes and reads on the leaders' hosts
+                writes, reads = [], []
+                for g, lead in c.leaders.items():
+                    nh = c.hosts[lead]
+                    for j in range(2):
+                        writes.append(nh.propose_batch_async(
+                            nh.get_noop_session(g), [f"k{i}.{j}=v".encode()],
+                            5.0))
+                        reads.append(nh.read_index(g, 5.0))
+                for h in writes:
+                    assert h.wait(10.0) and h.completed == 1
+                for rs in reads:
+                    assert rs.wait(10.0).completed
+            local = _request_sums(c.core)
+            for i in range(25):  # reads a follower's host forwards
+                for rs in [c.hosts[1 + lead % 3].read_index(g, 5.0)
+                           for g, lead in c.leaders.items()]:
+                    assert rs.wait(10.0).completed
+            both = _request_sums(c.core)
+        finally:
+            c.stop()
+        nw, lw = (a - b for a, b in zip(local["w"], base["w"]))
+        nr, lr = (a - b for a, b in zip(local["r"], base["r"]))
+        nf, lf = (a - b for a, b in zip(both["r"], base["r"]))
+        assert nw >= 200 and nr >= 200 and nf - nr >= 50, (base, local, both)
+        means[mode] = (lw / nw, lr / nr, (lf - lr) / (nf - nr))
+    plain, overlapped = means["k1"], means["k1-overlap"]
+    # the leader's append, the followers' append and ack, the leader's
+    # commit: 3 launches, and as many for a ReadIndex's heartbeat round
+    assert abs(overlapped[0] - plain[0]) <= 0.25, means
+    assert abs(overlapped[1] - plain[1]) <= 0.25, means
+    assert 3.0 <= overlapped[0] <= 3.25 and 3.0 <= overlapped[1] <= 3.25
+    # a forwarded read adds the way to the leader and back: 5, or 4 where
+    # one pack holds both lanes and takes the follower's first (the order
+    # of a set), so the two loops may differ by a part of one launch
+    assert 4.0 <= overlapped[2] <= 5.0 and 4.0 <= plain[2] <= 5.0, means
+
+
+# what a replica may send only after the save wave of the step that built it
+RESPONSES = {MT.REPLICATE_RESP, MT.REQUEST_VOTE_RESP, MT.HEARTBEAT_RESP,
+             MT.REQUEST_PREVOTE_RESP, MT.READ_INDEX_RESP}
+
+
+def _spy_decode(core, log) -> None:
+    """Log, from the loop thread, the decode's calls in the order they
+    run. The step's output dict itself is logged: identity names a step."""
+    place, saves, sends = (
+        core._decode_place, core._commit_saves, core._dispatch_sends)
+    reads, maintain, rebase = (
+        core._decode_reads, core._maintain, core._do_rebase)
+
+    def spy_place(o, packs):
+        log.append(("place", o))
+        place(o, packs)
+
+    def spy_saves(updates, lane_saves):
+        saves(updates, lane_saves)
+        log.append(("saved", len(updates)))
+
+    def spy_sends(batch):
+        log.append(("send", {m.type for _lane, m in batch}))
+        sends(batch)
+
+    def spy_reads(o, skip_routed=None):
+        reads(o, skip_routed)
+        log.append(("reads", o))
+
+    def spy_maintain(o):
+        log.append(("maintain", o))
+        maintain(o)
+
+    def spy_rebase():
+        log.append(("rebase", None))
+        rebase()
+
+    core._decode_place, core._commit_saves = spy_place, spy_saves
+    core._dispatch_sends, core._decode_reads = spy_sends, spy_reads
+    core._maintain, core._do_rebase = spy_maintain, spy_rebase
+
+
+@pytest.mark.parametrize("mode", ["k1", "k1-overlap"])
+def test_decode_order_holds_in_both_loops(tmp_path, mode):
+    """Whichever K=1 loop runs: no response built from a step's output
+    leaves before that step's save wave has returned, a step's maintain
+    comes after its reads and before the next step's place, and a rebase
+    never falls between a step's place and its maintain."""
+    k, overlap, _least = MODES[mode]
+    c = Cluster(tmp_path, f"order-{mode}", steps_per_sync=k,
+                overlap_decode=overlap)
+    try:
+        core = c.core
+        log = []
+        _spy_decode(core, log)
+        c.traffic(3)
+        # an election under the spies: vote requests and their responses
+        g, old = GROUPS[0], c.leaders[GROUPS[0]]
+        target = 1 + old % 3
+        c.hosts[old].request_leader_transfer(g, target)
+        deadline = time.monotonic() + 30
+        while c._leader(g) != target and time.monotonic() < deadline:
+            time.sleep(0.02)
+        c.leaders[g] = c._leader(g)
+        assert c.leaders[g] == target
+        c.traffic(2)
+        core._rebase_due = True  # what _maintain sets past 2**30 entries
+        c.traffic(2)
+        core.drain()
+        events = list(log)
+    finally:
+        c.stop()
+    first = next(i for i, (what, _) in enumerate(events) if what == "place")
+    step = saved = read = maintained = None
+    steps = seen_resp = 0
+    for what, arg in events[first:]:
+        if what == "place":
+            assert step is None or maintained, "a step lost its maintain"
+            step, saved, read, maintained = arg, False, False, False
+            steps += 1
+        elif what == "saved":
+            saved = True
+        elif what == "send":
+            if arg & RESPONSES:
+                seen_resp += 1
+                assert saved, f"{arg} left before the step's save"
+        elif what == "reads":
+            assert arg is step
+            read = True
+        elif what == "maintain":
+            assert arg is step and read and not maintained
+            maintained = True
+        elif what == "rebase":
+            assert maintained, "rebase between a step's decode and maintain"
+    kinds = [what for what, _ in events]
+    assert steps >= 20 and seen_resp >= 10 and "rebase" in kinds
+    sent = set().union(*(a for w, a in events if w == "send"))
+    assert {MT.REPLICATE_RESP, MT.REQUEST_VOTE_RESP,
+            MT.HEARTBEAT_RESP} <= sent
+
+
+@pytest.mark.parametrize("flush", [False, True], ids=["crash", "stop"])
+def test_stop_with_a_step_in_flight(tmp_path, flush):
+    """The overlapped loop parks a launched step undecoded. A crash stop
+    lets it die: nothing of it is saved or sent. A plain stop decodes it,
+    save wave and maintain included."""
+    c = Cluster(tmp_path, f"inflight-{flush}", overlap_decode=True)
+    try:
+        core = c.core
+        launched, release = threading.Event(), threading.Event()
+        step_fn = core._step_fn
+
+        def held(state, inbox, ticks):
+            res = step_fn(state, inbox, ticks)
+            if not launched.is_set():
+                launched.set()
+                release.wait(30)
+            return res
+
+        core._step_fn = held  # ticks keep the loop launching
+        assert launched.wait(30)
+        log = []
+        _spy_decode(core, log)
+        stopper = threading.Thread(
+            target=core.stop, kwargs={"flush": flush}, daemon=True)
+        stopper.start()
+        deadline = time.monotonic() + 10
+        while not core._stopped.is_set() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert core._stopped.is_set()
+        release.set()
+        stopper.join(timeout=40)
+        assert not stopper.is_alive()
+        assert core._pending is None
+        kinds = [what for what, _ in log]
+        # the step before it was decoded before the launch: its maintain
+        # alone may come after
+        owed = kinds[:1] == ["maintain"]
+        if flush:
+            tail = kinds[owed:]
+            assert tail[0] == "place" and tail[-1] == "maintain", kinds
+            assert tail.count("saved") == 1 and tail.count("place") == 1
+            assert log[-1][1] is log[owed][1]  # maintain of the same step
+        else:
+            assert kinds[owed:] == [], kinds
+    finally:
+        c.stop()
+
+
+def test_decode_with_nothing_to_pack_still_maintains(cluster):
+    """With the ticks held back, one read is all the work there is: the
+    iteration that decodes its last launch has nothing to pack. It still
+    runs that step's maintain, and the spans stay contiguous."""
+    core = cluster.core
+    rec = flight_recorder()
+    g, lead = GROUPS[0], cluster.leaders[GROUPS[0]]
+    core.global_tick = lambda host=0: None  # instance attribute: no ticks
+    try:
+        time.sleep(0.1)  # what was in flight drains; the loop goes idle
+        rec.reset()
+        first = core.launch_no
+        assert cluster.hosts[lead].read_index(g, 5.0).wait(10.0).completed
+        time.sleep(0.05)
+        launches = core.launch_no - first
+        spans = [e for e in rec.dump(event="phase_span")
+                 if not e.get("open") and e["engine"] == "vector"]
+    finally:
+        del core.global_tick
+    names = [e["phase"] for e in spans]
+    assert launches >= 1
+    assert names.count("dispatch") == launches
+    assert names.count("fetch") == launches
+    assert names.count("maintain") == launches
+    assert core._pending is None
+    last_fetch = len(names) - 1 - names[::-1].index("fetch")
+    tail = names[last_fetch:]
+    assert "maintain" in tail
+    if cluster.mode == "k1-overlap":
+        # decoded at the top of an iteration that launched nothing
+        assert "dispatch" not in tail, tail
+        assert tail.index("pack") < tail.index("maintain"), tail
+    for a, b in zip(spans, spans[1:]):
+        assert b["t0"] >= a["t"], (a, b)
+    wall = spans[-1]["t"] - spans[0]["t0"]
+    covered = sum(e["dur"] for e in spans)
+    assert abs(wall - covered) <= 0.02 * wall, (wall, covered, names)

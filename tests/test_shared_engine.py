@@ -138,8 +138,9 @@ def test_shared_release_keeps_core_alive(hosts):
 
 def test_overlapped_decode_pipeline(tmp_path):
     """Forced overlap_decode (the accelerator default): dispatch step t,
-    decode t-1 while the device computes. Commits and reads must flow
-    unchanged through the pipelined loop."""
+    maintain t-1 while the device computes, decode t before the next
+    pack. Commits and reads must flow unchanged through the pipelined
+    loop."""
     reg = _Registry()
     hs = {}
     for nid, addr in MEMBERS.items():
