@@ -68,6 +68,7 @@ carries real node ids and real (un-rebased) indexes.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -995,6 +996,43 @@ def build_save_updates(o: dict, base, lane_by_g):
             )
             lane_saves.append((lane, ents, state))
     return updates, lane_saves
+
+
+def _is_ack(m: Message) -> bool:
+    t = m.type
+    return t == MT.HEARTBEAT_RESP or (t == MT.REPLICATE_RESP and not m.reject)
+
+
+def _coalesce_acks(backlog: deque) -> None:
+    """Order a leader's waiting wire messages for an inbox that cannot
+    take them all. Every acknowledgement is folded into the newest of
+    its sender and term, at the oldest's place in its kind's queue (so
+    the sender whose answer has waited longest is served first, and none
+    starves): an accepted ReplicateResp only ever raises match and next
+    to its index, and a HeartbeatResp confirms its ReadIndex context and
+    with it every earlier one (kernel: readindex_pop), so the newest of
+    each says all the older ones said. The two kinds then take turns, a
+    ReplicateResp first: one follower's answer of each kind is a quorum
+    at three replicas, so a step that has two slots for them advances
+    both the commit index and the reads. Rejections and every other
+    type go first, as they came."""
+    newest = {}
+    for m in backlog:
+        if _is_ack(m):
+            newest[m.type, m.from_, m.term] = m
+    kept = []
+    acks = {MT.REPLICATE_RESP: [], MT.HEARTBEAT_RESP: []}
+    for m in backlog:
+        if _is_ack(m):
+            m = newest.pop((m.type, m.from_, m.term), None)
+            if m is not None:
+                acks[m.type].append(m)
+        else:
+            kept.append(m)
+    for pair in itertools.zip_longest(*acks.values()):
+        kept.extend(m for m in pair if m is not None)
+    backlog.clear()
+    backlog.extend(kept)
 
 
 class VectorEngine:
@@ -2018,6 +2056,10 @@ class VectorEngine:
             col.clear()
         had = bool(self._catchups)
         packs: Dict[_Lane, Dict[int, tuple]] = {}
+        # what this launch carries, lane by lane, for the sampled
+        # iterations' n.* counters (plain local ints; folded at the end)
+        counting = self.profiler.sampling
+        n_lanes = n_ents = n_hot = n_cut = n_reads = n_ctxs = 0
         # per-lane mirror reads gathered ONCE as columns (per-element
         # int(arr[g]) reads were a measured hot spot at fleet widths)
         work = list(lanes)
@@ -2089,14 +2131,31 @@ class VectorEngine:
                 )
                 had = True
                 k += 1
-            # 1. wire/protocol messages first
-            while lane.msg_backlog and k < K:
+            # 1. wire/protocol messages first. A leader whose followers'
+            # acknowledgements would fill the inbox keeps one slot for a
+            # row of its own proposals (if its window has room) and one
+            # for its ReadIndex context: at K = 4 the two Replicate and
+            # two heartbeat responses of a step otherwise starve both for
+            # as long as anything is in flight, and a saturated lane
+            # commits a window's worth every eight launches (PERF.md,
+            # PR 26). What waits among the wire messages is made
+            # cumulative first, so it never piles up.
+            is_leader = g_role == ROLE.LEADER
+            wire_end = K
+            if is_leader and len(lane.msg_backlog) + k > K - 2:
+                own = bool(lane.staged_reads) + bool(
+                    lane.staged_props
+                    and W - 1 - (g_last - g_devfirst + 1) > lane.packed_pending
+                )
+                if own and len(lane.msg_backlog) + k > K - own:
+                    _coalesce_acks(lane.msg_backlog)
+                    wire_end = max(K - own, k + 1)
+            while lane.msg_backlog and k < wire_end:
                 m = lane.msg_backlog.popleft()
                 k_used = self._pack_wire(lane, m, k, b)
                 if k_used:
                     had = True
                     k += 1
-            is_leader = g_role == ROLE.LEADER
             leader_nid = lane.rev.get(g_leader - 1)
             # 2. one config change per step (lone message; host invariant)
             if k < K and lane.staged_ccs and not lane.cc_inflight:
@@ -2128,13 +2187,18 @@ class VectorEngine:
             # the kernel never has to drop for lack of room (minus 1 slot
             # of slack for a concurrent new-leader noop append); what
             # doesn't fit stays staged and re-packs after compaction
+            k_wire = k
             if lane.staged_props:
                 if is_leader:
                     free = (
                         W - 1 - (g_last - g_devfirst + 1)
                         - lane.packed_pending
                     )
-                    while lane.staged_props and k < K and free > 0:
+                    lane_ents = 0
+                    # one context confirms every read staged: it is never
+                    # the proposals that take its slot
+                    k_end = K - 1 if lane.staged_reads else K
+                    while lane.staged_props and k < k_end and free > 0:
                         ents = []
                         cap = min(E, free)
                         while lane.staged_props and len(ents) < cap:
@@ -2146,6 +2210,7 @@ class VectorEngine:
                                 e.lat.n_pack = self.launch_no + 1
                             ents.append(e)
                         free -= len(ents)
+                        lane_ents += len(ents)
                         lane.packed_pending += len(ents)
                         self._stage_row(
                             g, k, MSG.PROPOSE, from_slot=lane.self_slot(),
@@ -2154,6 +2219,12 @@ class VectorEngine:
                         lane.pack_info[k] = ("prop", ents)
                         had = True
                         k += 1
+                    if counting:
+                        n_ents += lane_ents
+                        if lane_ents > n_hot:
+                            n_hot = lane_ents
+                        if lane.staged_props:  # cut by `free` or by K
+                            n_cut += 1
                 elif leader_nid is not None and leader_nid != node.node_id():
                     ents = list(lane.staged_props)
                     lane.staged_props.clear()
@@ -2180,6 +2251,9 @@ class VectorEngine:
                             enc = _enc_ctx(lane.self_slot(), ctx.low)
                             lane.ri_pending[enc] = ctx
                             self._stamp_reads_packed(lane, enc, states)
+                            if counting:
+                                n_reads += len(states)
+                                n_ctxs += 1
                             self._stage_row(
                                 g, k, MSG.READ_INDEX,
                                 from_slot=lane.self_slot(), hint=enc[0],
@@ -2195,6 +2269,9 @@ class VectorEngine:
                         enc = _enc_ctx(lane.self_slot(), ctx.low)
                         lane.ri_pending[enc] = ctx
                         self._stamp_reads_packed(lane, enc, states)
+                        if counting:
+                            n_reads += len(states)
+                            n_ctxs += 1
                         node._send_message(
                             Message(
                                 type=MT.READ_INDEX,
@@ -2205,6 +2282,8 @@ class VectorEngine:
                                 hint_high=enc[1],
                             )
                         )
+            if counting and k > k_wire:  # its clients' rows, not its peers'
+                n_lanes += 1
             # 5. leadership transfer
             target = node.pending_leader_transfer.get()
             if target is not None and k < K:
@@ -2237,6 +2316,16 @@ class VectorEngine:
                 + len(lane.staged_ccs)
             )
         self._p_staged_backlog = backlog
+        if counting and had:  # a launch follows: one record a launch
+            fold = self.profiler.fold
+            fold("n.packs", 1)
+            fold("n.lanes_packed", n_lanes)
+            fold("n.entries_packed", n_ents)
+            fold("n.hot_lane_entries", n_hot)
+            fold("n.lanes_window_cut", n_cut)
+            fold("n.staged_left", backlog)
+            fold("n.reads_bound", n_reads)
+            fold("n.read_contexts", n_ctxs)
         self._flush_staged_rows()
         return had, packs
 
@@ -2925,6 +3014,10 @@ class VectorEngine:
         step's updates through ONE call, so conflict-truncation rewrites
         apply sequentially inside a single barrier)."""
         if updates:
+            if self.profiler.sampling:
+                self.profiler.fold("n.save_bytes", sum(
+                    len(e.cmd) for u in updates for e in u.entries_to_save
+                ))
             self._save_updates(updates, lane_saves)
         for lane, ents, state in lane_saves:
             if ents:
