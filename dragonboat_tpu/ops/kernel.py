@@ -75,6 +75,32 @@ def _term_at(s: RaftTensors, idx):
     return jnp.where(in_ring, ring, jnp.where(marker, s.marker_term, 0))
 
 
+def _rotate_rows(x, shift):
+    """x[..., (j + shift) % n] for every column j of the last axis (length n):
+    each row rotated left by its own traced amount, shift[...] of any sign.
+    A contiguous run of ring slots modulo W is such a rotation, and a
+    take_along_axis with a full index plane lowers on the TPU to a general
+    gather at ~10 ns an element; this is ceil(log2 n) static rolls, each
+    selected on one bit of shift, exact for any n."""
+    n = x.shape[-1]
+    shift = shift % n
+    for k in range((n - 1).bit_length()):
+        bit = ((shift >> k) & 1).astype(bool)[..., None]
+        x = jnp.where(bit, jnp.roll(x, -(1 << k), axis=-1), x)
+    return x
+
+
+def _ring_run(ring, start, n: int):
+    """ring[..., (start + e) % W] for e in 0..n-1: n consecutive slots."""
+    return _rotate_rows(ring, start)[..., :n]
+
+
+def _run_to_ring(run, start, W: int):
+    """[G,W] plane whose slot (start + e) % W holds run[:, e], e in 0..E-1
+    (E <= W); the other slots are padding the caller masks out."""
+    return _rotate_rows(jnp.pad(run, ((0, 0), (0, W - run.shape[1]))), -start)
+
+
 def _self_mask(s: RaftTensors):
     """bool[G,P]: True at each group's own slot."""
     P = s.member.shape[1]
@@ -488,7 +514,7 @@ def _handle_message(s: RaftTensors, m, out, cfg: KernelConfig):
             e_idx = prev[:, None] + 1 + jnp.arange(E, dtype=i32)[None, :]
             e_valid = jnp.arange(E, dtype=i32)[None, :] < nent[:, None]
             have = e_idx <= s.last_index[:, None]
-            exist_term = jnp.take_along_axis(s.log_term, e_idx % W, axis=1)
+            exist_term = _ring_run(s.log_term, prev + 1, E)
             conflict = e_valid & (~have | (exist_term != m["entry_terms"]))
             first_conf = jnp.min(
                 jnp.where(conflict, e_idx, jnp.iinfo(jnp.int32).max), axis=1
@@ -498,17 +524,16 @@ def _handle_message(s: RaftTensors, m, out, cfg: KernelConfig):
             # ring-slot write WITHOUT a per-entry loop: slot w receives absolute
             # index i(w) = lo + ((w - lo) mod W) — the unique index in the
             # written span congruent to w (nent <= E <= W guarantees at most
-            # one) — so the whole scatter is one (G,W) gather+select and the
-            # kernel cost is independent of E (the old form unrolled E one-hot
-            # scatters, which capped how many entries a message could carry)
+            # one) — and that index's entry is e = (w - (prev + 1)) mod W, so
+            # the whole scatter is the message's run rotated onto the ring and
+            # one (G,W) select, at a cost independent of E
             w_idx = jnp.arange(W, dtype=i32)[None, :]
             lo = jnp.where(do_append, first_conf, 1)
             hi = prev + nent
             i_w = lo[:, None] + jnp.mod(w_idx - lo[:, None], W)
             written = do_append[:, None] & (i_w <= hi[:, None])
-            e_pos = jnp.clip(i_w - (prev[:, None] + 1), 0, E - 1)
-            terms_w = jnp.take_along_axis(m["entry_terms"], e_pos, axis=1)
-            cc_w = jnp.take_along_axis(m["entry_cc"], e_pos, axis=1)
+            terms_w = _run_to_ring(m["entry_terms"], prev + 1, W)
+            cc_w = _run_to_ring(m["entry_cc"], prev + 1, W)
             log_term = jnp.where(written, terms_w, s.log_term)
             log_cc = jnp.where(written, cc_w, s.log_is_cc)
             new_last = jnp.where(do_append, prev + nent, s.last_index)
@@ -763,8 +788,7 @@ def _handle_message(s: RaftTensors, m, out, cfg: KernelConfig):
             a_hi = s.last_index + nent
             i_w = a_lo[:, None] + jnp.mod(w_idx - a_lo[:, None], W)
             written = can_append[:, None] & (i_w <= a_hi[:, None])
-            e_pos = jnp.clip(i_w - a_lo[:, None], 0, E - 1)
-            cc_w = jnp.take_along_axis(eff_cc, e_pos, axis=1)
+            cc_w = _run_to_ring(eff_cc, a_lo, W)
             log_term = jnp.where(written, s.term[:, None], s.log_term)
             log_cc = jnp.where(written, cc_w, s.log_is_cc)
             new_last = jnp.where(can_append, s.last_index + nent, s.last_index)
@@ -1354,7 +1378,6 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
     K = cfg.inbox_depth
     E = cfg.max_entries_per_msg
     R = cfg.readindex_depth
-    W = s.log_term.shape[1]
     flags = out.send_flags
     self_col = s.self_slot[:, None]
     self_gp = jnp.broadcast_to(self_col, (G, P))
@@ -1405,10 +1428,9 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
     # Replicate entry metadata comes straight from the sender's ring (the
     # host path reads the same (term, is_cc) pairs off the arena entries)
     e_off = jnp.arange(E, dtype=i32)[None, None, :]
-    e_idx = (out.send_prev_index + 1)[:, :, None] + e_off
     e_live = (e_off < out.send_n_entries[:, :, None]) & rep_want[:, :, None]
-    ring_t = jnp.take_along_axis(s.log_term[:, None, :], e_idx % W, axis=2)
-    ring_cc = jnp.take_along_axis(s.log_is_cc[:, None, :], e_idx % W, axis=2)
+    ring_t = _ring_run(s.log_term[:, None, :], out.send_prev_index + 1, E)
+    ring_cc = _ring_run(s.log_is_cc[:, None, :], out.send_prev_index + 1, E)
     rep_terms = jnp.where(e_live, ring_t, 0)
     rep_cc = e_live & ring_cc
 

@@ -1,9 +1,11 @@
 """Tests for the vectorized Raft kernel: protocol behavior on the loopback
 simulation cluster, plus invariant checks across randomized runs."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dragonboat_tpu.ops import KernelConfig, ROLE
+from dragonboat_tpu.ops.kernel import _ring_run, _rotate_rows, _run_to_ring
 from dragonboat_tpu.ops.loopback import LoopbackCluster
 
 
@@ -285,3 +287,119 @@ def test_kernel_randomized_chaos_invariants():
     c.run(20)
     for g in range(2):
         assert len(set(c.field("committed", g))) == 1
+
+
+# ---------------------------------------------------------------- ring runs
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.bool_], ids=["int32", "bool"])
+@pytest.mark.parametrize("W,E", [(256, 64), (96, 32), (64, 64), (12, 5), (7, 1)])
+def test_ring_run_helpers_match_the_gather_forms(W, E, dtype):
+    """A contiguous run of ring slots modulo W read (`_ring_run`) and written
+    (`_run_to_ring`) as a row rotation gives what take_along_axis gave on the
+    index formulas the kernel used before: everywhere on the read side, and
+    wherever `written` holds on the write side."""
+    rng = np.random.default_rng(W * 1000 + E)
+    # hand-made lanes first: a run that ends on the ring's last slot, one that
+    # wraps it, n = 0, n = E, prev = 0, prev far beyond W; then random ones
+    prev = np.array([W - E - 1, W - 2, W - 1, 0, 5 * W + 3, 2 * W - 1], np.int32)
+    nent = np.array([E, E, 0, E, E // 2, 1], np.int32)
+    skip = np.array([0, 0, 0, 0, E // 4, 0], np.int32)  # first_conf - (prev + 1)
+    n_rand = 40
+    prev = np.concatenate([prev, rng.integers(0, 9 * W, n_rand)]).astype(np.int32)
+    nent = np.concatenate([nent, rng.integers(0, E + 1, n_rand)]).astype(np.int32)
+    skip = np.concatenate(
+        [skip, rng.integers(0, E + 1, n_rand) % np.maximum(nent[6:], 1)]
+    ).astype(np.int32)
+    G = len(prev)
+
+    def plane(shape):
+        if dtype == np.bool_:
+            return rng.integers(0, 2, shape).astype(bool)
+        return rng.integers(1, 1 << 30, shape).astype(np.int32)
+
+    ring, ents = plane((G, W)), plane((G, E))
+    e = np.arange(E, dtype=np.int32)[None, :]
+    w = np.arange(W, dtype=np.int32)[None, :]
+
+    # read side: inbox/follower_append's exist_term
+    e_idx = prev[:, None] + 1 + e
+    want = np.take_along_axis(ring, e_idx % W, axis=1)
+    got = np.asarray(_ring_run(jnp.asarray(ring), jnp.asarray(prev) + 1, E))
+    assert got.dtype == ring.dtype and np.array_equal(got, want)
+
+    # read side over peers: replicate_fanout's ring_t / ring_cc at K > 1
+    prev_gp = np.stack([prev, prev[::-1], prev + 1], axis=1)
+    e_idx = (prev_gp + 1)[:, :, None] + e[None]
+    want = np.take_along_axis(ring[:, None, :], e_idx % W, axis=2)
+    got = _ring_run(jnp.asarray(ring)[:, None, :], jnp.asarray(prev_gp) + 1, E)
+    assert np.array_equal(np.asarray(got), want)
+
+    # write side: follower_append (lo = first_conf) and propose_append (skip 0)
+    for lo in (prev + 1 + skip, prev + 1):
+        hi = prev + nent
+        i_w = lo[:, None] + np.mod(w - lo[:, None], W)
+        written = (nent > 0)[:, None] & (i_w <= hi[:, None])
+        e_pos = np.clip(i_w - (prev[:, None] + 1), 0, E - 1)
+        want = np.where(written, np.take_along_axis(ents, e_pos, axis=1), ring)
+        run = _run_to_ring(jnp.asarray(ents), jnp.asarray(prev) + 1, W)
+        got = np.where(written, np.asarray(run), ring)
+        assert run.shape == (G, W) and np.array_equal(got, want)
+        assert written.sum() == np.maximum(hi - lo + 1, 0)[nent > 0].sum()
+
+    # the rotation itself, any sign and size of shift
+    shift = np.concatenate([prev, -prev - 1])
+    both = np.concatenate([ring, ring])
+    want = np.take_along_axis(both, (w + shift[:, None]) % W, axis=1)
+    got = _rotate_rows(jnp.asarray(both), jnp.asarray(shift))
+    assert np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("W,E", [(12, 5), (16, 8)])
+def test_kernel_append_that_wraps_the_ring(W, E):
+    """Appends whose run of slots crosses the ring's end, on the leader
+    (propose_append) and on the followers (follower_append), leave the rings
+    a plain NumPy replay of the log leaves."""
+    cfg = KernelConfig(
+        groups=1, peers=3, log_window=W, inbox_depth=8,
+        max_entries_per_msg=E, readindex_depth=2,
+    )
+    c = LoopbackCluster(n_replicas=3, n_groups=1, cfg=cfg)
+    c.run(30)
+    lead = c.leader_of(0)
+    assert lead is not None
+    term = c.field("term", 0)[lead]
+    ref_t, ref_cc = np.zeros(W, np.int32), np.zeros(W, bool)
+    ref_t[1 % W] = term  # the leader's no-op at index 1
+    last = 1
+
+    def compact():
+        # what the engine's maintain does once entries are applied
+        for h, st in enumerate(c.states):
+            done = st.committed
+            c.states[h] = st._replace(
+                first_index=done + 1, marker_term=jnp.full_like(done, term)
+            )
+
+    def propose(ns, cc_at=None):
+        nonlocal last
+        for j, n in enumerate(ns):
+            c.propose(lead, 0, n=n, cc_first=(j == cc_at))
+            for i in range(last + 1, last + n + 1):
+                ref_t[i % W], ref_cc[i % W] = term, (j == cc_at)
+            last += n
+        c.run(3)
+        assert c.field("committed", 0) == [last] * 3
+        compact()
+
+    while last < W - 2:
+        propose([min(E, W - 2 - last)])
+    # one launch: a proposal that crosses the ring's end on the leader, a
+    # config change alone in its message, one more; the followers get all of
+    # them in one Replicate whose run crosses the end too
+    assert last == W - 2 and c.leader_of(0) == lead
+    propose([2, 1, 1], cc_at=1)
+    assert last == W + 2 and ref_cc[W % W + 1] and ref_cc.sum() == 1
+    for st in c.states:
+        assert np.array_equal(np.asarray(st.log_term)[0], ref_t)
+        assert np.array_equal(np.asarray(st.log_is_cc)[0], ref_cc)
