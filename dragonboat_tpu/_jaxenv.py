@@ -1,4 +1,4 @@
-"""JAX process set-up shared by tests, bench, the chip smoke and the driver
+"""JAX process set-up shared by tests, the benchmark and the driver
 hooks: the cpu pin that gives tests a virtual multi-device mesh, and the
 persistent compilation cache.
 

@@ -144,10 +144,6 @@ class EngineConfig:
     inbox_depth: int = 8
     # Max outstanding ReadIndex system contexts per group on device.
     readindex_depth: int = 4
-    # Max proposal batches appended per group per step.
-    proposal_lanes: int = 1
-    # How many protocol micro-steps (inbox drain rounds) per kernel launch.
-    micro_steps: int = 1
     # Max entries carried by one inbox row / Replicate message. The kernel's
     # ring-slot scatter is O(G*W) regardless of this value, so raising it
     # widens per-step ingestion at the cost of inbox transfer size only.
@@ -188,7 +184,8 @@ class EngineConfig:
     # Stage-profiler sampling for the vector engine hot loop: 0 = sparse
     # default (1 in 32 iterations — steady-state cost is two clock reads
     # per stage only on sampled iterations), 1 = record every step (full
-    # stage timings; benches and debugging), N>1 = sample 1/N.
+    # stage timings; the benchmark's traced run and debugging), N>1 =
+    # sample 1/N.
     profile_sample_ratio: int = 0
     # Per-step cap on coalesced tick backlogs after an engine loop stall
     # (cold compile, CPU contention between co-scheduled loops). Backlog

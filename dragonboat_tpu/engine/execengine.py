@@ -17,7 +17,6 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Set, Tuple
 
-from ..logger import get_logger
 from ..profile import HOT_LANE_COUNTERS, DeviceCensus, phase_plane
 from ..settings import hard, soft
 from ..trace import LatencySampler, Profiler
@@ -26,7 +25,6 @@ from ..rsm.manager import From as OffloadFrom
 from .fairness import FairnessWatchdog
 from .node import Node
 
-_plog = get_logger("execengine")
 
 # Scalar twin of the kernel's counter plane. Mirrors ops.state.CTR_NAMES
 # verbatim (pinned by a test) — duplicated here so the scalar engine stays
@@ -46,7 +44,7 @@ _COUNTER_ATTRS = (
 class _NullProfiler:
     """Zero-cost stand-in when profiling is disabled."""
 
-    def new_iteration(self, n_groups: int = 0) -> None:
+    def new_iteration(self) -> None:
         pass
 
     def start(self) -> None:
@@ -253,7 +251,7 @@ class ExecEngine:
     def exec_nodes(self, nodes: List[Node], worker: int = 0) -> None:
         """THE hot loop (cf. execNodes execengine.go:474-560)."""
         prof = self.profilers[worker] if self.profilers else _NULL_PROFILER
-        prof.new_iteration(len(nodes))
+        prof.new_iteration()
         prof.start()
         updates: List[Tuple[Node, Update]] = []
         for node in nodes:
@@ -452,20 +450,19 @@ class ExecEngine:
     def device_census(self) -> dict:
         """Shape-compatible HBM census: the scalar engine holds no device
         memory, so every byte/fill key is present and zero — consumers
-        (bench JSON, gauges, tools.top) need not branch per engine."""
+        (gauges, tools.top) need not branch per engine."""
         return DeviceCensus.empty()
 
     def lane_stats(self) -> Dict[int, dict]:
         """Per-group introspection, shape-compatible with
         VectorEngine.lane_stats(): cluster_id -> {node_id, leader_id,
         term, commit_gap, ticks_since_leader_change}. Feeds the same
-        engine_lane_* gauges (NodeHost._export_health_gauges) and the
-        bench JSON lane fold, so dashboards read identically whichever
-        engine a host runs. Derived from each group's protocol core under
-        its step lock — the scalar engine hosts few groups and the export
-        cadence is ~1/s, so the per-group lock round-trip is noise here
-        (the vector engine's zero-sync numpy mirrors exist because it
-        hosts thousands)."""
+        engine_lane_* gauges (NodeHost._export_health_gauges), so
+        dashboards read identically whichever engine a host runs. Derived
+        from each group's protocol core under its step lock — the scalar
+        engine hosts few groups and the export cadence is ~1/s, so the
+        per-group lock round-trip is noise here (the vector engine's
+        zero-sync numpy mirrors exist because it hosts thousands)."""
         out: Dict[int, dict] = {}
         with self._nodes_mu:
             nodes = list(self._nodes.values())
@@ -538,11 +535,6 @@ class ExecEngine:
         self.snapshot_ready.wake_all()
         for t in self._threads:
             t.join(timeout=2)
-        # dump sampled stage latencies (cf. execengine.go:197-211)
-        for i, prof in enumerate(self.profilers):
-            report = prof.report()
-            if report:
-                _plog.infof("step worker %d stage latencies:\n%s", i, report)
 
 
 __all__ = ["ExecEngine", "WorkReady"]

@@ -1091,9 +1091,8 @@ class VectorEngine:
                 # round UP to a device multiple so every shard holds the
                 # same block. NOT silent: the shortfall is stamped in
                 # step_stats (padded_groups/mesh_devices -> engine_step_*
-                # gauges + bench JSON) and the ghost lanes are never
-                # handed out by the allocator, so lane_stats never
-                # reports them
+                # gauges) and the ghost lanes are never handed out by
+                # the allocator, so lane_stats never reports them
                 self.kcfg = self.kcfg._replace(
                     groups=((self.kcfg.groups + n - 1) // n) * n
                 )
@@ -1138,8 +1137,8 @@ class VectorEngine:
         # stage profiler for the hot loop (cf. reference execengine.go
         # :197-211 + trace.go:98-162). Sparse sampling by default (1/32):
         # per-step full sampling is pure hot-loop overhead in production;
-        # benches and debugging opt into every-step recording through
-        # EngineConfig.profile_sample_ratio=1.
+        # the benchmark's traced run and debugging opt into every-step
+        # recording through EngineConfig.profile_sample_ratio=1.
         ratio = (getattr(ecfg, "profile_sample_ratio", 0) or 0) if ecfg else 0
         self.profiler = Profiler(sample_ratio=ratio if ratio > 0 else 32)
         # sampled stage durations also land in the process-global phase
@@ -1185,13 +1184,13 @@ class VectorEngine:
             "msgs_routed_device": 0,
             # sharded mesh: ghost lanes added by the device-multiple
             # round-up (never allocated) and the mesh width — static
-            # stamps, not counters, so bench JSON and gauges can tell a
-            # padded sharded run from an exact one
+            # stamps, not counters, so the gauges can tell a padded
+            # sharded run from an exact one
             "padded_groups": self._padded_groups,
             "mesh_devices": self._mesh_devices,
             # exceptions the loop caught from _run_once and survived: a
             # kernel the compiler refuses would otherwise show up only as
-            # proposal time-outs (chip_smoke.py asserts this stays zero)
+            # proposal time-outs (benchmark/lib/check.py holds it at zero)
             "loop_exceptions": 0,
             # ReadIndex contexts the kernel dropped for want of a slot
             # (StepOutput.dropped_readindex, summed as fetched). Counted,
@@ -4301,9 +4300,6 @@ class VectorEngine:
                     self._m_snap_pending[lane.g] = False
 
     # --------------------------------------------------------------- control
-    def profile_summary(self) -> dict:
-        return self.profiler.summary()
-
     def fairness_stats(self) -> dict:
         """Tick-fairness watchdog snapshot: inter-iteration latency vs the
         tick period, the starvation gauge, burst clamps, enforced yields."""
@@ -4388,7 +4384,7 @@ class VectorEngine:
         the lane's accepted log runs ahead of its quorum commit — a
         persistently large gap flags a lane that cannot reach quorum).
         Exported ~1/s by NodeHost._export_health_gauges as cluster_id-
-        labelled engine_lane_* gauges and folded into bench.py's JSON."""
+        labelled engine_lane_* gauges."""
         out: Dict[tuple, dict] = {}
         with self._lanes_mu:
             lanes = list(self._lanes.values())

@@ -61,8 +61,7 @@ class Histogram:
             self.count += 1
 
     def merge(self, other: "Histogram") -> None:
-        """Fold another histogram with identical bounds into this one
-        (bench aggregates per-host histograms into one distribution)."""
+        """Fold another histogram with identical bounds into this one."""
         if other.bounds != self.bounds:
             raise ValueError("histogram bounds mismatch")
         with other._mu:
@@ -195,14 +194,9 @@ class MetricsRegistry:
         with self._mu:
             return self._hists.get(name, {}).get(key)
 
-    def histograms(self, name: str) -> List[Histogram]:
-        """Every label key's histogram for `name` (bench merges them)."""
-        with self._mu:
-            return list(self._hists.get(name, {}).values())
-
     def histogram_items(self, name: str) -> List[Tuple[_LabelKey, Histogram]]:
-        """(key, histogram) pairs for `name` — key-aware merges (the
-        bench serving fold splits urgent vs bulk by the klass label)."""
+        """(key, histogram) pairs for `name` — key-aware reads (the
+        placement controller takes the bulk class by the klass label)."""
         with self._mu:
             return list(self._hists.get(name, {}).items())
 

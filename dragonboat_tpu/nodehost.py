@@ -904,7 +904,7 @@ class NodeHost(IMessageHandler):
         """Tag/untag a cluster as mid live-migration on this host (both
         the source and the join target get marked): the inbound snapshot
         chunk tracker counts streams for marked clusters as MIGRATION
-        streams, so the bench/longhaul ledgers can tell a migration's
+        streams, so the longhaul ledger can tell a migration's
         install traffic from ordinary catch-up."""
         with self._nodes_mu:
             if active:

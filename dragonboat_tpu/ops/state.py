@@ -102,8 +102,8 @@ class CTR:
     COUNT = 8
 
 
-#: bench/stats key per CTR slot, in slot order (the one canonical naming
-#: shared by engine counter_stats(), bench JSON, gauges and tools.top)
+#: stats key per CTR slot, in slot order (the one canonical naming
+#: shared by engine counter_stats(), gauges and tools.top)
 CTR_NAMES = (
     "elections_started",
     "elections_won",

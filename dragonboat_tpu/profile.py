@@ -13,8 +13,8 @@ attribution itself a first-class, always-exported plane:
     ``engine_phase_seconds{engine=...,phase=...}`` histogram
     (events.Histogram, Prometheus exposition via
     ``NodeHost.write_health_metrics``). At FULL sampling (ratio 1, the
-    bench/debug opt-in) the profiler also stores each span as a
-    ``phase_span`` event in the FlightRecorder's span store, so
+    benchmark's traced run and debugging) the profiler also stores each
+    span as a ``phase_span`` event in the FlightRecorder's span store, so
     ``tools.timeline --spans`` renders them interleaved with
     causal-trace stages. Unsampled iterations stay allocation- and
     event-free (the profiler's begin/start/end no-op there).
@@ -35,8 +35,8 @@ attribution itself a first-class, always-exported plane:
     activation scatters) expose their trace-cache sizes per function, so
     a retrace in steady state is attributable to the function that
     retraced (``engine_compile_events_total`` / per-function cache
-    gauges; ``bench.py`` folds the measurement-window delta into every
-    config's JSON and ``tools.perfdiff --gate`` fails on growth).
+    gauges; ``benchmark/lib/probes.py`` reports the measurement
+    window's delta as ``run.compiles_in_window``).
 
   * ``HistorySampler`` — the diagnosis plane's TIME axis: a background
     thread that, every ``interval_s`` (default 250ms, entirely off the
@@ -52,8 +52,8 @@ attribution itself a first-class, always-exported plane:
     ring like any other forensic artifact.
 
 jax is imported lazily (inside ``install()``) so this module — like the
-analysis package — stays importable in jax-free contexts
-(``tools.perfdiff`` reads bench JSONs without ever touching a backend).
+analysis package — stays importable in jax-free contexts (``tools.top``
+and ``tools.doctor`` read history rings without ever touching a backend).
 """
 from __future__ import annotations
 
@@ -69,9 +69,7 @@ from .trace import _RING_MAGIC, MmapRing, read_mmap_ring
 
 # canonical step-phase vocabulary. The vector engine's loop thread is in
 # exactly one of the top-level phases at every instant of a sampled
-# iteration (trace.Profiler.begin); bench.py zero-fills phase_breakdown
-# over VECTOR_PHASES so the JSON schema is stable even for configs where
-# a phase never ran.
+# iteration (trace.Profiler.begin).
 VECTOR_SUBSPANS = (
     "deliver",      # bulk send/deliver seam (_dispatch_sends), inside the
                     # send/apply/reads phases
@@ -99,7 +97,7 @@ VECTOR_PHASES = (
 
 # the scalar ExecEngine worker loop's stages, timed
 # by the same Profiler machinery so scalar/vector attribution reads on
-# one scale in the exposition and the bench JSON
+# one scale in the exposition
 EXEC_PHASES = ("step", "fast_apply", "send", "save", "apply", "exec")
 
 _PREFIX = "dragonboat_tpu"
@@ -132,11 +130,6 @@ class PhasePlane:
     def histogram(self, engine: str, phase: str) -> Optional[Histogram]:
         with self._mu:
             return self._hists.get((engine, phase))
-
-    def total_observations(self) -> int:
-        with self._mu:
-            hists = list(self._hists.values())
-        return sum(h.count for h in hists)
 
     def write(self, w, prefix: str = _PREFIX) -> None:
         """Prometheus exposition: one ``engine_phase_seconds`` histogram
@@ -254,7 +247,7 @@ class SyncAudit:
 
     def out_of_seam_in_package(self) -> Dict[str, int]:
         """Out-of-seam sites attributed to dragonboat_tpu code only (the
-        tier-1 assertion's subject; test/bench harness sites excluded)."""
+        tier-1 assertion's subject; test and harness sites excluded)."""
         with self._mu:
             return {
                 s: n
@@ -270,8 +263,8 @@ class SyncAudit:
 
 
 def diff_sync(before: dict, after: dict) -> dict:
-    """Per-window delta of two SyncAudit.snapshot() dicts (bench folds
-    the measurement window's delta, not process-lifetime totals)."""
+    """Per-window delta of two SyncAudit.snapshot() dicts (a window's
+    own syncs, not process-lifetime totals)."""
     sites = {
         s: n - before.get("sites", {}).get(s, 0)
         for s, n in after.get("sites", {}).items()
@@ -288,7 +281,7 @@ def diff_sync(before: dict, after: dict) -> dict:
     }
 
 
-# the HBM census schema: ALWAYS-present bench-JSON / gauge keys (the
+# the HBM census schema: ALWAYS-present engine_hbm_* gauge keys (the
 # ROADMAP paged-arena item's baseline). Zero-filled when no device
 # engine ran (bring-up-failed path, scalar-only hosts).
 CENSUS_KEYS = (
@@ -320,8 +313,8 @@ class DeviceCensus:
 
     jax-free like the rest of this module: numpy is imported inside
     ``snapshot()`` only (the callers that pass mirrors already loaded
-    it), so jax-free readers (``tools.perfdiff``) can import the class
-    and its ``empty()`` schema without touching a backend."""
+    it), so the jax-free scalar engine can import the class and its
+    ``empty()`` schema without touching a backend."""
 
     def __init__(self) -> None:
         self._mu = threading.Lock()
@@ -358,8 +351,7 @@ class DeviceCensus:
     @staticmethod
     def empty() -> dict:
         """The zero-filled census schema: what a host with no device
-        engine (or a bring-up-failed bench config) reports, so the JSON
-        keys are ALWAYS present."""
+        engine reports, so the engine_hbm_* gauges are ALWAYS present."""
         out = {
             "hbm_bytes_total": 0,
             "hbm_log_bytes": 0,
@@ -585,8 +577,8 @@ HOT_LANE_COUNTERS = (
 )
 
 # the always-present sampler gauge schema (engine_history_* in the
-# Prometheus exposition, `history` fold in the bench JSON): zero-filled
-# when no sampler is attached so consumers never branch
+# Prometheus exposition): zero-filled when no sampler is attached so
+# consumers never branch
 HISTORY_STATS_KEYS = (
     "samples_total",
     "errors_total",
@@ -892,7 +884,7 @@ class HistorySampler:
     @staticmethod
     def empty_stats() -> dict:
         """Zero-filled stats schema for hosts with no sampler attached —
-        gauges and bench JSON keys stay ALWAYS present."""
+        the engine_history_* gauges stay ALWAYS present."""
         return {
             "samples_total": 0,
             "errors_total": 0,
@@ -912,8 +904,8 @@ def read_history(path: str):
 
 # ---------------------------------------------------------------------------
 # process-global singletons (like trace.flight_recorder: every engine and
-# NodeHost in the process feeds one plane, and the exposition/bench folds
-# read it without plumbing)
+# NodeHost in the process feeds one plane, and the exposition reads it
+# without plumbing)
 # ---------------------------------------------------------------------------
 
 _phase_plane = PhasePlane()

@@ -107,7 +107,7 @@ class MigrationPlan:
 
 def host_target(nh, sm_factory, config_factory) -> MigrationTarget:
     """Bind a MigrationTarget to a live in-process NodeHost (tests,
-    longhaul, bench). `config_factory(cluster_id, node_id)` returns the
+    longhaul). `config_factory(cluster_id, node_id)` returns the
     joiner's Config; witnesses/observers are not migration targets."""
 
     def start(cluster_id: int, node_id: int) -> None:

@@ -282,8 +282,8 @@ class SessionManager:
             return {k: len(v) for k, v in self._pools.items()}
 
     def stats(self) -> dict:
-        """Counter snapshot (always the same keys — bench/longhaul fold
-        these into their JSON schemas)."""
+        """Counter snapshot (always the same keys — longhaul folds
+        these into its JSON schema)."""
         with self._mu:
             out = dict(self._counters)
         out["pooled"] = sum(self.pool_sizes().values())

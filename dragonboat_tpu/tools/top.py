@@ -40,7 +40,7 @@ Three ways in:
                (re-reads the ring each interval, so a live sampler
                turns the console into a real-time view).
 
-The snapshot CLI operates on FILES (bench and longhaul write them as
+The snapshot CLI operates on FILES (longhaul writes them as
 artifacts); `--watch` re-reads the file each interval and derives ingest
 rates from consecutive reads, so a writer refreshing the snapshot turns
 a frozen view into a live console without any IPC plumbing.
@@ -344,7 +344,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument("snapshot", nargs="?", default=None,
                     help="snapshot JSON written by collect_snapshot "
-                         "(bench/longhaul artifact)")
+                         "(a longhaul artifact)")
     ap.add_argument("--history", default=None, metavar="RING",
                     help="render from a telemetry history ring "
                          "(profile.HistorySampler) instead of snapshot "

@@ -42,8 +42,7 @@ class Sample:
     reservoir, so long-run percentiles reflect the WHOLE run. The old
     fill-then-freeze cap silently dropped everything after the first 50k
     values, skewing percentiles toward bring-up. mean() stays exact (sum
-    over all values); __len__ reports values SEEN, keeping the profiler's
-    total_s accounting unchanged."""
+    over all values); __len__ reports values SEEN."""
 
     __slots__ = ("name", "_vals", "_cap", "_seen", "_sum", "_rng")
 
@@ -80,14 +79,6 @@ class Sample:
     def mean(self) -> float:
         return self._sum / self._seen if self._seen else 0.0
 
-    def report(self) -> str:
-        return (
-            f"{self.name}: n={len(self)} mean={self.mean()*1e6:.1f}us "
-            f"p50={self.percentile(0.50)*1e6:.1f}us "
-            f"p99={self.percentile(0.99)*1e6:.1f}us "
-            f"p999={self.percentile(0.999)*1e6:.1f}us"
-        )
-
 
 class Profiler:
     """Sampled stage profiler of one engine loop (cf. trace.go:98-162).
@@ -110,14 +101,13 @@ class Profiler:
     the apply workers' spans and the request path, whose sampling is the
     request's own. Dotted names (`save.cpu`, `rsm.handle`, `req.w.queue`,
     `n.spans_dropped`) live in `samples` only and are no stages of the
-    loop: summary() and report() leave them out."""
+    loop."""
 
     def __init__(self, sample_ratio: int = 16) -> None:
         self.ratio = max(1, sample_ratio)
         self._iter = 0
         self.sampling = False
         self.samples: Dict[str, Sample] = {}
-        self.batched_groups = Sample("batched_groups")
         self._t0: Optional[float] = None
         # optional histogram sink (profile.PhasePlane): sampled stage
         # durations fan out to engine_phase_seconds; unsampled iterations
@@ -149,17 +139,17 @@ class Profiler:
         `<kind>.sub`. Histograms fill at ANY sampling ratio; the events
         of the kind's own spans, which are disjoint, reach the
         flight recorder's span store only at FULL sampling (ratio 1, the
-        bench/debug opt-in). `idle_head` names the stages every
-        iteration of a begin() loop starts with before it knows whether
-        it has work: an idle loop polling every 2 ms then leaves one
-        growing span instead of 1 500 events a second."""
+        benchmark's traced run and debugging). `idle_head` names the
+        stages every iteration of a begin() loop starts with before it
+        knows whether it has work: an idle loop polling every 2 ms then
+        leaves one growing span instead of 1 500 events a second."""
         self._plane = plane
         self._engine_kind = engine_kind
         self._sub_kind = engine_kind + ".sub"
         self._span_gate = self.ratio == 1
         self._head = tuple(idle_head)
 
-    def new_iteration(self, n_groups: int = 0) -> None:
+    def new_iteration(self) -> None:
         was = self.sampling
         self._iter += 1
         self.sampling = self._iter % self.ratio == 0
@@ -171,8 +161,6 @@ class Profiler:
             else:
                 self.close()
                 self._end_iteration()
-        if self.sampling and n_groups:
-            self.batched_groups.record(float(n_groups))
 
     def _end_iteration(self) -> None:
         """A sampled begin() iteration has closed its last span."""
@@ -287,32 +275,6 @@ class Profiler:
         if s is None:
             s = self.samples.setdefault(name, Sample(name, cap=0))
         s.record(value)
-
-    def report(self) -> str:
-        lines = [
-            s.report() for name, s in self.samples.items()
-            if len(s) and "." not in name
-        ]
-        if len(self.batched_groups):
-            lines.append(
-                f"batched_groups: mean={self.batched_groups.mean():.1f} "
-                f"p99={self.batched_groups.percentile(0.99):.0f}"
-            )
-        return "\n".join(lines)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        """Machine-readable stage costs (mean/p99 in seconds + sample n)
-        of the loop's own stages; bench.py folds them into its JSON."""
-        out: Dict[str, Dict[str, float]] = {}
-        for name, s in list(self.samples.items()):
-            if len(s) and "." not in name:
-                out[name] = {
-                    "n": float(len(s)),
-                    "mean_s": s.mean(),
-                    "p99_s": s.percentile(0.99),
-                    "total_s": s.mean() * len(s) * self.ratio,
-                }
-        return out
 
 
 # ---------------------------------------------------------------------------

@@ -70,7 +70,7 @@ class Chunks:
         # install streams that began while their cluster was marked
         # mid live-migration on this host (NodeHost.mark_migrating, set
         # by serving/placement.py on both ends of a member swap): the
-        # counter that lets the bench/longhaul ledgers tell migration
+        # counter that lets the longhaul ledger tell migration
         # install traffic from ordinary crash-rejoin catch-up
         self._migration_streams = 0
 

@@ -35,11 +35,9 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "perf: the perf-attribution gate — the tools.perfdiff regression "
-        "gate over the checked-in fixtures (sub-second, jax-free) plus "
-        "the runtime device-sync/retrace audit assertions over a live "
-        "vector-engine scenario; run it alone with `-m perf` alongside "
-        "the `-m lint` gate",
+        "perf: the perf-attribution gate — the runtime device-sync/retrace "
+        "audit assertions over a live vector-engine scenario; run it "
+        "alone with `-m perf` alongside the `-m lint` gate",
     )
     config.addinivalue_line(
         "markers",
@@ -68,6 +66,55 @@ import faulthandler  # noqa: E402
 import pytest  # noqa: E402
 
 _HANG_DUMP_S = 600
+
+# ---- engine-kind test ids. "vector-overlap" is the vector engine in the
+# K=1 loop order every one-chip cell of the benchmark runs: decode of step
+# t-1 at the top of the iteration, maintain owed behind the launch, a
+# step in flight across iterations. `overlap_decode` is auto-on only off
+# the CPU, so a plain "vector" case here runs the other order. ----
+VECTOR_KINDS = ("vector", "vector-overlap")
+ENGINE_KINDS = ("scalar",) + VECTOR_KINDS
+
+
+def pytest_collection_modifyitems(items):
+    """The cells' rehearsals go last: each is a subprocess that keeps two
+    to three cores and the disk busy (every fsync honoured), and beside
+    the suite's load-sensitive tests it cost them whole runs. Last, one
+    worker runs them while the others finish and then go idle."""
+    items.sort(key=lambda item: item.path.name == "test_cells_rehearse.py")
+
+
+def engine_kw(kind_id: str) -> dict:
+    """The EngineConfig keywords an engine-kind test id stands for."""
+    if kind_id == "vector-overlap":
+        return {"kind": "vector", "overlap_decode": True}
+    return {"kind": kind_id}
+
+
+def make_native(*targets: str):
+    """`make -C native <targets>`, one at a time: every xdist worker
+    imports every test file, and in a fresh checkout their builds would
+    otherwise race each other in native/build."""
+    import fcntl
+    import subprocess
+
+    native = os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "native"
+    )
+    os.makedirs(os.path.join(native, "build"), exist_ok=True)
+    with open(os.path.join(native, "build", ".lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        return subprocess.run(
+            ["make", "-C", native, *targets],
+            capture_output=True, text=True, timeout=300,
+        )
+
+
+def host_of_kind(nh, kind_id: str):
+    """`nh`, once its engine is up, checked to run the order its id names."""
+    if kind_id == "vector-overlap":
+        assert nh.engine.core._overlap is True
+    return nh
 
 # ---- crash-persistent ring (the timeout-kill half of the forensics
 # story): JSONL failure dumps only happen when pytest survives to report —

@@ -5,8 +5,12 @@ leadership readout. The 50k-group regime from BASELINE.json comes up in
 scale with CI-generous bounds."""
 from __future__ import annotations
 
+import os
 import time
 
+import jax
+
+from dragonboat_tpu import _jaxenv
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.statemachine import IStateMachine, Result
@@ -113,3 +117,22 @@ def test_bulk_start_matches_incremental(tmp_path):
             assert nh.stale_read(c, None) >= 1
     finally:
         nh.stop()
+
+
+def test_compile_cache_placement(monkeypatch):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # placed from outside: JAX honours the variable, code sets nothing
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert _jaxenv.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        # otherwise: the one fixed path inside the checkout
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert _jaxenv.enable_compile_cache() == _jaxenv.COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache"
+        )
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

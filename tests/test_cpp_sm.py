@@ -3,11 +3,12 @@ round-trip across the ABI, and a full cluster run with snapshot-based
 catch-up (mirrors internal/cpp/wrapper_test.go coverage)."""
 import io
 import os
-import subprocess
 import threading
 import time
 
 import pytest
+
+from conftest import make_native
 
 _BUILD = os.path.join(os.path.dirname(__file__), "..", "native", "build")
 _SO = os.path.join(_BUILD, "libkvstore_sm.so")
@@ -22,10 +23,7 @@ def _built() -> bool:
         return True
     if shutil.which("g++") is None:
         return False  # genuinely no toolchain: skip
-    proc = subprocess.run(
-        ["make", "-C", os.path.join(os.path.dirname(__file__), "..", "native")],
-        capture_output=True, text=True,
-    )
+    proc = make_native()
     if proc.returncode != 0:
         raise RuntimeError(f"native build failed:\n{proc.stderr}")
     return os.path.exists(_SO)

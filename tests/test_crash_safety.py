@@ -3,6 +3,7 @@
 (cf. internal/rsm/offload.go:48-133)."""
 import pytest
 
+from conftest import VECTOR_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import ErrDirLocked, NodeHost
 from dragonboat_tpu.rsm.manager import From, OffloadedStatus
@@ -145,16 +146,17 @@ class SlowSnapSM(IStateMachine):
         self.d = json.loads(r.read().decode())
 
 
-def _mk_snap_host(nid, reg, tmp, members):
+def _mk_snap_host(nid, reg, tmp, members, engine_kind):
     cfg = NodeHostConfig(
         deployment_id=88, rtt_millisecond=5, raft_address=f"s{nid}:1",
         nodehost_dir=f"{tmp}/h{nid}",
         raft_rpc_factory=lambda l, reg=reg: loopback_factory(l, reg),
         engine=EngineConfig(
-            kind="vector", max_groups=32, max_peers=4, log_window=64
+            **engine_kw(engine_kind), max_groups=32, max_peers=4,
+            log_window=64,
         ),
     )
-    nh = NodeHost(cfg)
+    nh = host_of_kind(NodeHost(cfg), engine_kind)
     nh.start_cluster(
         members, False, lambda c, n: SlowSnapSM(c, n),
         Config(cluster_id=1, node_id=nid, election_rtt=20, heartbeat_rtt=4),
@@ -162,13 +164,15 @@ def _mk_snap_host(nid, reg, tmp, members):
     return nh
 
 
-def test_crash_mid_save_snapshot_then_restart_rejoins(tmp_path):
+@pytest.mark.parametrize("engine_kind", VECTOR_KINDS)
+def test_crash_mid_save_snapshot_then_restart_rejoins(tmp_path, engine_kind):
     SlowSnapSM.gate.clear()
     SlowSnapSM.saving.clear()
     reg = _Registry()
     members = {n: f"s{n}:1" for n in (1, 2, 3)}
     hosts = {
-        n: _mk_snap_host(n, reg, str(tmp_path), members) for n in (1, 2, 3)
+        n: _mk_snap_host(n, reg, str(tmp_path), members, engine_kind)
+        for n in (1, 2, 3)
     }
     rec = HistoryRecorder()
 

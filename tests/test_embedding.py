@@ -8,6 +8,8 @@ import subprocess
 
 import pytest
 
+from conftest import make_native
+
 _NATIVE = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "native"))
 _DEMO = os.path.join(_NATIVE, "build", "embed_demo")
 _OO_DEMO = os.path.join(_NATIVE, "build", "oo_demo")
@@ -23,10 +25,7 @@ def _built() -> bool:
     # toolchain present: a build FAILURE must fail loudly, not skip —
     # except a missing libpython dev install, which is a missing optional
     # dependency like an absent compiler
-    proc = subprocess.run(
-        ["make", "-C", _NATIVE, "all", "embed"],
-        capture_output=True, text=True, timeout=300,
-    )
+    proc = make_native("all", "embed")
     if proc.returncode != 0:
         if "Python.h" in proc.stderr:
             return False

@@ -79,18 +79,15 @@ def test_sample_percentiles():
     assert s.percentile(0.5) == 51.0
     assert s.percentile(0.99) == 100.0
     assert 50.0 <= s.mean() <= 51.0
-    assert "p99" in s.report()
 
 
 def test_profiler_samples_at_ratio():
     p = Profiler(sample_ratio=4)
     for _ in range(16):
-        p.new_iteration(8)
+        p.new_iteration()
         p.start()
         p.end("step")
     assert len(p.samples["step"]) == 4
-    assert len(p.batched_groups) == 4
-    assert "step:" in p.report()
 
 
 def test_logger_factory_swap_retroactive():

@@ -130,34 +130,3 @@ def test_trace_lint_catches_regressions():
         "trace",
     )
     assert len(got) == 3, got
-
-
-def test_bench_json_carries_commit_latency_keys():
-    """BENCH JSON schema smoke test: the per-config latency report always
-    carries commit_latency_p50_s / commit_latency_p99_s (0.0 when no
-    samples landed), and real observations produce real percentiles."""
-    import bench
-    from dragonboat_tpu.events import MetricsRegistry
-
-    class FakeNH:
-        def __init__(self):
-            self.metrics = MetricsRegistry()
-
-    nh = FakeNH()
-    for v in (0.001, 0.002, 0.004, 0.008):
-        nh.metrics.observe("proposal_commit_latency_seconds", (1, 1), v)
-        nh.metrics.observe("proposal_apply_latency_seconds", (1, 1), 2 * v)
-    r = bench._latency_report({1: nh})
-    assert set(r) >= {
-        "commit_latency_p50_s",
-        "commit_latency_p99_s",
-        "commit_latency_samples",
-        "apply_latency_p99_s",
-        "fsync_latency_p99_s",
-    }
-    assert r["commit_latency_samples"] == 4
-    assert 0 < r["commit_latency_p50_s"] <= r["commit_latency_p99_s"]
-    # schema stability: keys exist even with zero hosts / zero samples
-    r0 = bench._latency_report({})
-    assert r0["commit_latency_p50_s"] == 0.0
-    assert r0["commit_latency_p99_s"] == 0.0

@@ -30,6 +30,7 @@ import time
 import numpy as np
 import pytest
 
+from conftest import ENGINE_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, ConfigError, EngineConfig, NodeHostConfig
 from dragonboat_tpu.core.logentry import InMemLogDB
 from dragonboat_tpu.core.raft import Raft
@@ -514,11 +515,13 @@ def _mk_host(nid, reg, workdir, engine_kind, cp=None, rtt_ms=5):
             nodehost_dir=os.path.join(workdir, f"nh{nid}"),
             raft_rpc_factory=lambda a: loopback_factory(a, reg),
             engine=EngineConfig(
-                kind=engine_kind, max_groups=8, max_peers=4, log_window=64,
-                share_scope="lease-test" if engine_kind == "vector" else None,
+                **engine_kw(engine_kind), max_groups=8, max_peers=4,
+                log_window=64,
+                share_scope="lease-test" if engine_kind != "scalar" else None,
             ),
         )
     )
+    host_of_kind(nh, engine_kind)
     if cp is not None:
         nh.set_tick_clock(cp.clock_fn(str(nid)))
     return nh
@@ -553,7 +556,7 @@ def _leader_host(hosts):
     return None
 
 
-@pytest.mark.parametrize("engine_kind", ["scalar", "vector"])
+@pytest.mark.parametrize("engine_kind", ENGINE_KINDS)
 def test_lease_probe_api_and_fallback(tmp_path, engine_kind):
     """`NodeHost.lease_read` (the explicit lease-only probe): serves off
     a live leader lease, raises the typed ErrLeaseExpired (an

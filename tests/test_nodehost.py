@@ -9,6 +9,7 @@ import time
 
 import pytest
 
+from conftest import ENGINE_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.requests import ErrRejected, ErrTimeout
@@ -16,7 +17,7 @@ from dragonboat_tpu.statemachine import IStateMachine, Result
 from dragonboat_tpu.transport.loopback import _Registry, loopback_factory
 
 
-@pytest.fixture(params=["scalar", "vector"])
+@pytest.fixture(params=ENGINE_KINDS)
 def engine_kind(request):
     return request.param
 
@@ -63,10 +64,11 @@ def mk_nodehost(addr, registry, rtt_ms=5, nodehost_dir="", engine_kind="scalar")
         # one canonical shape for every vector-engine test so the whole
         # suite shares a single compiled kernel (make_step_fn lru cache)
         engine=EngineConfig(
-            kind=engine_kind, max_groups=32, max_peers=4, log_window=64
+            **engine_kw(engine_kind), max_groups=32, max_peers=4,
+            log_window=64,
         ),
     )
-    return NodeHost(cfg)
+    return host_of_kind(NodeHost(cfg), engine_kind)
 
 
 def group_config(cluster_id, node_id, **kw):

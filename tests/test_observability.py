@@ -408,9 +408,8 @@ def test_e2e_latency_histograms_and_step_stats(single_host):
 
 def test_scalar_engine_lane_stats_parity(tmp_path):
     """ROADMAP PR-4 headroom item: ExecEngine.lane_stats() returns the
-    same per-lane shape as VectorEngine.lane_stats(), so engine_lane_*
-    gauges and the bench JSON lane fold cover the scalar engine too."""
-    import bench
+    same per-lane shape as VectorEngine.lane_stats(), so the
+    engine_lane_* gauges cover the scalar engine too."""
     from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
     from dragonboat_tpu.nodehost import NodeHost
     from dragonboat_tpu.transport.loopback import _Registry, loopback_factory
@@ -472,11 +471,6 @@ def test_scalar_engine_lane_stats_parity(tmp_path):
         nh._export_health_gauges()
         assert nh.metrics.gauge_value("engine_lane_leader_id", (1, 1)) == 1.0
         assert nh.metrics.gauge_value("engine_lane_term", (1, 1)) >= 1.0
-        # and the bench JSON lane fold works under the scalar engine
-        fold = bench._lane_report({1: nh})
-        assert fold["lanes_total"] == 1
-        assert fold["lanes_with_leader"] == 1
-        assert fold["lane_commit_gap_max"] >= 0
     finally:
         nh.stop()
 
@@ -561,9 +555,8 @@ def test_scalar_engine_counter_and_census_parity(tmp_path):
     """ISSUE 18: ExecEngine exposes the same counter_stats /
     lane_counters / device_census shapes as the vector engine (names =
     ops.state.CTR_NAMES; census always-present and all-zero — the
-    scalar engine holds no device memory), so gauges, bench JSON and
-    tools.top need not branch per engine."""
-    import bench
+    scalar engine holds no device memory), so gauges and tools.top need
+    not branch per engine."""
     from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
     from dragonboat_tpu.engine.execengine import _COUNTER_ATTRS
     from dragonboat_tpu.nodehost import NodeHost
@@ -622,10 +615,6 @@ def test_scalar_engine_counter_and_census_parity(tmp_path):
         assert nh.metrics.gauge_value(
             "engine_hbm_bytes_total", (0, 0)
         ) == 0.0
-        # and the bench census fold covers the scalar engine too
-        fold = bench._census_report({1: nh})
-        assert fold["hbm_bytes_total"] == 0
-        assert fold["counters"]["commit_advances"] >= 4
     finally:
         nh.stop()
 

@@ -33,6 +33,7 @@ from dragonboat_tpu.serving import (
     SessionManager,
     host_target,
 )
+from dragonboat_tpu.serving.retry import call_with_retries
 from dragonboat_tpu.statemachine import IStateMachine, Result
 from dragonboat_tpu.transport.loopback import _Registry, loopback_factory
 
@@ -459,8 +460,12 @@ def test_live_migration_under_hot_tenant_load():
             new_hn = host_of_node(hosts, new_lid)
             mgr2 = SessionManager(hosts[new_hn].serving_front())
             mgr2.adopt(7, CLUSTER, sess)
-            t2 = hosts[new_hn].serving_front().propose_session(
-                7, CLUSTER, sess, b"dedup=1", 30.0
+            # the hot tenant may have the host shedding: the shed is
+            # typed, and the client half (retry.py) rides it out
+            t2 = call_with_retries(
+                lambda remaining_s: hosts[new_hn].serving_front()
+                .propose_session(7, CLUSTER, sess, b"dedup=1", remaining_s),
+                30.0,
             )
             r2 = t2.wait()
             assert r2.completed

@@ -46,7 +46,7 @@ from dragonboat_tpu.trace import Profiler, flight_recorder
 def _drive(prof):
     """One iteration's worth of every way a profiler is fed on the loop
     thread: a begin() chain, a start/end pair, a sub-span."""
-    prof.new_iteration(1)
+    prof.new_iteration()
     prof.begin("wait")
     prof.begin("pack")
     prof.begin("dispatch")
@@ -68,7 +68,9 @@ def _unsampled_profiler():
     for _ in range(3):
         _drive(prof)
         assert not prof.sampling
-    assert plane.total_observations() == 0, "histogram observed off-path"
+    exposition = io.StringIO()
+    plane.write(exposition)
+    assert exposition.getvalue() == "", "histogram observed off-path"
     assert prof.samples == {}, "sample created off-path"
     assert len(rec) == 0, "recorder event on the unsampled path"
     assert rec.open_spans == {}, "running span published off-path"
@@ -134,9 +136,10 @@ def test_unsampled_iterations_stay_event_free(case, tmp_path, monkeypatch):
 
 
 def test_full_sampling_emits_recorder_spans():
-    """Spans reach the flight recorder only at ratio 1 (the bench/debug
-    opt-in, EngineConfig.profile_sample_ratio=1): the loop's own, each
-    with the instant it ended as `t` and the instant it began as `t0`;
+    """Spans reach the flight recorder only at ratio 1 (the traced run's
+    and debugging's opt-in, EngineConfig.profile_sample_ratio=1): the
+    loop's own, each with the instant it ended as `t` and the instant it
+    began as `t0`;
     a sub-span leaves a histogram under `<kind>.sub` and no event."""
     plane = PhasePlane()
     prof = Profiler(sample_ratio=1)
@@ -144,7 +147,7 @@ def test_full_sampling_emits_recorder_spans():
     rec = flight_recorder()
     rec.reset()
     t0 = time.monotonic()
-    prof.new_iteration(1)
+    prof.new_iteration()
     prof.start()
     prof.end("pack")
     prof.add("deliver", 0.001)
@@ -156,7 +159,7 @@ def test_full_sampling_emits_recorder_spans():
 
 
 def test_phase_vocabulary_covers_both_engines():
-    # the canonical keys bench zero-fills; decode phases 0-6 all named
+    # the canonical span names; decode phases 0-6 all named
     for p in ("wait", "prepare", "pack", "dispatch", "fetch", "place",
               "send_rep", "save", "send_resp", "apply", "reads", "maintain",
               "deliver", "put", "launch", "device_wait", "copy"):
@@ -515,69 +518,6 @@ def test_history_sampler_adds_zero_syncs_and_zero_retraces(vec_host, tmp_path):
     assert last["counters"]["elections_won"] >= 1
     assert last["census"]["hbm_bytes_total"] > 0
     assert last.get("errors", []) == []
-
-
-@pytest.mark.perf
-def test_bench_attribution_fold_schema():
-    """Acceptance: every bench config JSON always contains
-    phase_breakdown (ALL canonical phase keys, zero when the phase never
-    ran), device_syncs and compile_events — even on the zero-host /
-    bring-up-failed path."""
-    import bench
-
-    r = bench._attribution_report({}, None, None)
-    assert set(r["phase_breakdown"]) == set(VECTOR_PHASES)
-    assert all(v == 0.0 for v in r["phase_breakdown"].values())
-    assert r["device_syncs"] == {"in_seam": 0, "out_of_seam": 0, "sites": {}}
-    assert r["compile_events"]["total"] == 0
-    assert r["compile_events"]["per_function"] == {}
-
-
-@pytest.mark.perf
-def test_bench_census_fold_schema():
-    """Acceptance (ISSUE 18): every bench config JSON always carries the
-    HBM census keys and the counter totals — zero-filled on the
-    zero-host / bring-up-failed path, so perfdiff and the paged-arena
-    baseline read a stable schema from any artifact."""
-    import bench
-    from dragonboat_tpu.ops.state import CTR_NAMES
-    from dragonboat_tpu.profile import CENSUS_KEYS
-
-    r = bench._census_report({})
-    assert set(r) == set(CENSUS_KEYS) | {"counters"}
-    assert r["hbm_bytes_total"] == 0
-    assert r["hbm_log_bytes"] == 0
-    assert r["log_fill_p50"] == 0.0
-    assert r["log_fill_p99"] == 0.0
-    assert r["hbm_waste_ratio"] == 0.0
-    assert set(r["counters"]) == set(CTR_NAMES)
-    assert all(v == 0 for v in r["counters"].values())
-
-
-@pytest.mark.perf
-def test_bench_history_fold_schema(tmp_path):
-    """Acceptance (ISSUE 19): every bench config JSON always carries the
-    history_* sampler keys — zero-filled when the sampler never started
-    (bring-up-failed path) so perfdiff's informational history section
-    reads a stable schema; a live sampler reports its real counts."""
-    import bench
-    from dragonboat_tpu.profile import HISTORY_STATS_KEYS
-
-    r = bench._history_report(None)
-    assert set(r) == {f"history_{k}" for k in HISTORY_STATS_KEYS}
-    assert r["history_samples_total"] == 0
-    assert r["history_errors_total"] == 0
-    assert r["history_sample_cost_seconds_total"] == 0.0
-    assert r["history_interval_seconds"] == 0.0
-    sampler = bench._start_history(str(tmp_path), {})
-    assert sampler is not None
-    try:
-        sampler.sample_once()
-    finally:
-        sampler.stop(final_sample=False)
-    live = bench._history_report(sampler)
-    assert set(live) == set(r)
-    assert live["history_interval_seconds"] > 0.0
 
 
 @pytest.mark.perf

@@ -5,6 +5,7 @@ import time
 
 import pytest
 
+from conftest import ENGINE_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.requests import ErrInvalidSession
@@ -33,16 +34,16 @@ class CounterSM(IStateMachine):
         pass
 
 
-@pytest.mark.parametrize("engine", ["scalar", "vector"])
+@pytest.mark.parametrize("engine", ENGINE_KINDS)
 def test_propose_batch_commits_in_order(tmp_path, engine):
     reg = _Registry()
-    nh = NodeHost(NodeHostConfig(
+    nh = host_of_kind(NodeHost(NodeHostConfig(
         deployment_id=88, rtt_millisecond=5, raft_address="pb1:1",
         nodehost_dir=str(tmp_path / "nh"),
         raft_rpc_factory=lambda l: loopback_factory(l, reg),
-        engine=EngineConfig(kind=engine, max_groups=4, max_peers=4,
+        engine=EngineConfig(**engine_kw(engine), max_groups=4, max_peers=4,
                             log_window=64),
-    ))
+    )), engine)
     try:
         nh.start_cluster({1: "pb1:1"}, False, lambda c, n: CounterSM(),
                          Config(cluster_id=1, node_id=1, election_rtt=20,
@@ -112,18 +113,18 @@ def test_propose_batch_overflow_drops_tail(tmp_path):
         nh.stop()
 
 
-@pytest.mark.parametrize("engine", ["scalar", "vector"])
+@pytest.mark.parametrize("engine", ENGINE_KINDS)
 def test_propose_batch_async_handle(tmp_path, engine):
     """propose_batch_async: ONE BatchRequestState for the whole batch,
     completion counted in runs (batch keys route by (batch_id, seq))."""
     reg = _Registry()
-    nh = NodeHost(NodeHostConfig(
+    nh = host_of_kind(NodeHost(NodeHostConfig(
         deployment_id=89, rtt_millisecond=5, raft_address="pba1:1",
         nodehost_dir=str(tmp_path / "nh"),
         raft_rpc_factory=lambda l: loopback_factory(l, reg),
-        engine=EngineConfig(kind=engine, max_groups=4, max_peers=4,
+        engine=EngineConfig(**engine_kw(engine), max_groups=4, max_peers=4,
                             log_window=64),
-    ))
+    )), engine)
     try:
         nh.start_cluster({1: "pba1:1"}, False, lambda c, n: CounterSM(),
                          Config(cluster_id=1, node_id=1, election_rtt=20,

@@ -20,6 +20,9 @@ import time
 
 import pytest
 
+from conftest import (
+    ENGINE_KINDS, VECTOR_KINDS, engine_kw, host_of_kind,
+)
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.faults import FaultPlane, FaultSpec
 from dragonboat_tpu.lincheck import HistoryRecorder, check_kv_history
@@ -71,10 +74,11 @@ def _mk_host(nid, reg, tmp, engine_kind, snapshot_entries=0,
         raft_address=f"c{nid}:1",
         raft_rpc_factory=lambda listen, reg=reg: loopback_factory(listen, reg),
         engine=EngineConfig(
-            kind=engine_kind, max_groups=32, max_peers=4, log_window=64
+            **engine_kw(engine_kind), max_groups=32, max_peers=4,
+            log_window=64,
         ),
     )
-    nh = NodeHost(cfg)
+    nh = host_of_kind(NodeHost(cfg), engine_kind)
     nh.start_cluster(
         {h: f"c{h}:1" for h in HOSTS},
         False,
@@ -146,7 +150,7 @@ def _wait_converged(hosts, deadline_s=45.0):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("engine_kind", ["scalar", "vector"])
+@pytest.mark.parametrize("engine_kind", ENGINE_KINDS)
 def test_stop_restart_cluster_rejoins_live_group(tmp_path, engine_kind):
     """Graceful detach + in-process restart: the restarted node replays
     its WAL, catches up from the leader and converges."""
@@ -174,7 +178,7 @@ def test_stop_restart_cluster_rejoins_live_group(tmp_path, engine_kind):
             nh.stop()
 
 
-@pytest.mark.parametrize("engine_kind", ["vector"])
+@pytest.mark.parametrize("engine_kind", VECTOR_KINDS)
 def test_crash_restart_with_snapshot_install(tmp_path, engine_kind):
     """Crash a follower, commit enough for the leader to snapshot and
     compact past the crashed node's log, restart: the rejoiner MUST take
@@ -226,7 +230,7 @@ def test_crash_restart_with_snapshot_install(tmp_path, engine_kind):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("engine_kind", ["scalar", "vector"])
+@pytest.mark.parametrize("engine_kind", ENGINE_KINDS)
 def test_crash_restart_cycles_every_node(tmp_path, engine_kind):
     """Drummer-style: N crash/restart cycles of EACH node under live
     client traffic — lincheck green, replicas converged after every
@@ -344,7 +348,8 @@ def test_tear_wal_tails_sweeps_shards(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_vector_lane_reuse_50_restarts_no_growth(tmp_path):
+@pytest.mark.parametrize("engine_kind", VECTOR_KINDS)
+def test_vector_lane_reuse_50_restarts_no_growth(tmp_path, engine_kind):
     """ISSUE 7 satellite: start/stop/restart a cluster 50x on one vector
     engine — the free list returns to its initial size every time, the
     lane registry stays empty after stops, and the node still serves."""
@@ -356,10 +361,11 @@ def test_vector_lane_reuse_50_restarts_no_growth(tmp_path):
         raft_address="c1:1",
         raft_rpc_factory=lambda listen: loopback_factory(listen, reg),
         engine=EngineConfig(
-            kind="vector", max_groups=32, max_peers=4, log_window=64
+            **engine_kw(engine_kind), max_groups=32, max_peers=4,
+            log_window=64,
         ),
     )
-    nh = NodeHost(cfg)
+    nh = host_of_kind(NodeHost(cfg), engine_kind)
     core = nh.engine.core
     try:
         nh.start_cluster(

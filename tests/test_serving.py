@@ -27,6 +27,7 @@ import time
 
 import pytest
 
+from conftest import ENGINE_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.client import Session
 from dragonboat_tpu.events import MetricsRegistry
@@ -684,17 +685,19 @@ class KVSM(IStateMachine):
 
 
 def mk_host(addr, registry, engine_kind="scalar", rtt_ms=5):
-    return NodeHost(
+    nh = NodeHost(
         NodeHostConfig(
             deployment_id=1,
             rtt_millisecond=rtt_ms,
             raft_address=addr,
             raft_rpc_factory=lambda listen: loopback_factory(listen, registry),
             engine=EngineConfig(
-                kind=engine_kind, max_groups=32, max_peers=4, log_window=64
+                **engine_kw(engine_kind), max_groups=32, max_peers=4,
+                log_window=64,
             ),
         )
     )
+    return host_of_kind(nh, engine_kind)
 
 
 def group_config(cluster_id, node_id, **kw):
@@ -716,7 +719,7 @@ def wait_for(pred, timeout=30.0):
     return False
 
 
-@pytest.fixture(params=["scalar", "vector"])
+@pytest.fixture(params=ENGINE_KINDS)
 def engine_kind(request):
     return request.param
 
@@ -918,44 +921,6 @@ def test_storm_schedule_is_seed_deterministic_without_a_host():
             assert 1.5 <= mult <= 2.5
         assert set(weights) == {1, 2, 3}
     assert sum(w for _, _, w, _ in a) >= 2.0
-
-
-# ---------------------------------------------------------------------------
-# bench JSON fold schema
-# ---------------------------------------------------------------------------
-
-
-def test_bench_serving_report_schema_stable():
-    import bench
-
-    keys = {
-        "serving_admitted_total",
-        "serving_shed_total",
-        "serving_wakes_total",
-        "serving_urgent_p99_s",
-        "serving_bulk_p50_s",
-        "serving_bulk_p99_s",
-        # ISSUE 14: per-tenant latency + the session/migration ledger
-        # joined the ALWAYS-present fold (zero/empty when no front,
-        # placement plane or migration stream existed)
-        "serving_tenant_latency",
-        "migrations_started",
-        "migrations_completed",
-        "migrations_aborted",
-        "migration_streams",
-    }
-    assert keys == set(bench._serving_report({}))  # zero hosts
-    host, front = _mk_front()
-    try:
-        with pytest.raises(ErrTimeout):
-            front.sync_propose(1, 100, b"k=v", 0.05)
-        host._serving = front
-        r = bench._serving_report({1: host})
-        assert r["serving_admitted_total"] == 1
-        assert r["migrations_started"] == 0
-        assert r["serving_tenant_latency"] == {}
-    finally:
-        front.stop()
 
 
 # ---------------------------------------------------------------------------

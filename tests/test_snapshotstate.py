@@ -6,6 +6,7 @@ import time
 
 import pytest
 
+from conftest import ENGINE_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.engine.snapshotstate import SnapshotState, TaskSlot
 
 
@@ -85,7 +86,7 @@ def _counter_sm():
     return SM
 
 
-@pytest.mark.parametrize("engine", ["scalar", "vector"])
+@pytest.mark.parametrize("engine", ENGINE_KINDS)
 def test_duplicate_snapshot_request_ignored(tmp_path, engine):
     """A second user snapshot request with nothing newly applied is
     rejected instead of writing an identical image (cf. node.go:1085-1091
@@ -97,13 +98,13 @@ def test_duplicate_snapshot_request_ignored(tmp_path, engine):
 
     SM = _counter_sm()
     reg = _Registry()
-    nh = NodeHost(NodeHostConfig(
+    nh = host_of_kind(NodeHost(NodeHostConfig(
         deployment_id=91, rtt_millisecond=5, raft_address="ssf1:1",
         nodehost_dir=str(tmp_path / "nh1"),
         raft_rpc_factory=lambda l: loopback_factory(l, reg),
-        engine=EngineConfig(kind=engine, max_groups=4, max_peers=4,
+        engine=EngineConfig(**engine_kw(engine), max_groups=4, max_peers=4,
                             log_window=64),
-    ))
+    )), engine)
     try:
         nh.start_cluster({1: "ssf1:1"}, False, lambda c, n: SM(),
                          Config(cluster_id=1, node_id=1, election_rtt=20,

@@ -246,7 +246,7 @@ def test_raft_top_renders_checked_in_snapshot_via_cli():
     assert snap["lanes"][0]["heat"] > snap["lanes"][-1]["heat"]
     assert snap["census"]["hbm_waste_ratio"] == 0.69
     # a non-snapshot file refuses cleanly
-    p = cli(os.path.join(repo, "tests", "data", "perfdiff_base.json"))
+    p = cli(os.path.join(repo, "tests", "data", "timeline_node1.jsonl"))
     assert p.returncode == 2
     assert "error" in p.stderr
 

@@ -269,3 +269,15 @@ def test_sharded_multistep_engine_padding_and_device_routing(tmp_path):
     finally:
         for nh in hosts.values():
             nh.stop()
+
+
+def test_shard_over_mesh_on_one_device_raises(monkeypatch):
+    from dragonboat_tpu.engine import vector
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(vector.jax, "devices", lambda: one)
+    cfg = NodeHostConfig(
+        engine=EngineConfig(kind="vector", max_groups=8, shard_over_mesh=True)
+    )
+    with pytest.raises(ValueError, match="more than one visible jax device"):
+        vector.VectorEngine(None, nh_config=cfg)

@@ -18,6 +18,7 @@ import zlib
 
 import pytest
 
+from conftest import VECTOR_KINDS, engine_kw, host_of_kind
 from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.ops.state import ROLE
@@ -49,18 +50,20 @@ class KV(IStateMachine):
         self.d = json.loads(r.read().decode())
 
 
-def _mk_host(nid, reg, engine_kind="vector"):
-    return NodeHost(
+def _mk_host(nid, reg, engine_kind):
+    nh = NodeHost(
         NodeHostConfig(
             deployment_id=9,
             rtt_millisecond=5,
             raft_address=f"wl{nid}:1",
             raft_rpc_factory=lambda l, reg=reg: loopback_factory(l, reg),
             engine=EngineConfig(
-                kind=engine_kind, max_groups=32, max_peers=4, log_window=64
+                **engine_kw(engine_kind), max_groups=32, max_peers=4,
+                log_window=64,
             ),
         )
     )
+    return host_of_kind(nh, engine_kind)
 
 
 def _cfg(nid, **kw):
@@ -91,12 +94,12 @@ def _propose_n(nh, n, tag, timeout_s=5.0):
         nh.sync_propose(s, f"k{i % 4}={tag}{i}".encode(), timeout_s=timeout_s)
 
 
-@pytest.fixture
-def two_plus_witness():
+@pytest.fixture(params=VECTOR_KINDS)
+def two_plus_witness(request):
     """Hosts 1,2 full members; host 3 joins as a WITNESS through the
     membership-change API (request_add_witness + join start)."""
     reg = _Registry()
-    hosts = {nid: _mk_host(nid, reg) for nid in (1, 2, 3)}
+    hosts = {nid: _mk_host(nid, reg, request.param) for nid in (1, 2, 3)}
     members = {1: "wl1:1", 2: "wl2:1"}
     for nid in (1, 2):
         hosts[nid].start_cluster(
@@ -170,12 +173,13 @@ def test_witness_counts_toward_commit_quorum(two_plus_witness):
     assert st is not None and st["payload_bytes"] == 0
 
 
-def test_observer_replicates_without_voting_then_promotes():
+@pytest.mark.parametrize("engine_kind", VECTOR_KINDS)
+def test_observer_replicates_without_voting_then_promotes(engine_kind):
     """An observer lane replicates + applies the full log (SM hash
     converges) but never votes or campaigns; add_node promotes it to a
     full member in place."""
     reg = _Registry()
-    hosts = {nid: _mk_host(nid, reg) for nid in (1, 2, 3)}
+    hosts = {nid: _mk_host(nid, reg, engine_kind) for nid in (1, 2, 3)}
     members = {1: "wl1:1", 2: "wl2:1"}
     try:
         for nid in (1, 2):
@@ -228,12 +232,13 @@ def test_observer_replicates_without_voting_then_promotes():
                 pass
 
 
-def test_witness_removal_and_rejoin():
+@pytest.mark.parametrize("engine_kind", VECTOR_KINDS)
+def test_witness_removal_and_rejoin(engine_kind):
     """The churn half: remove the witness, re-add a FRESH witness id, and
     the group keeps committing throughout (membership change over lane
     variants at vector scale)."""
     reg = _Registry()
-    hosts = {nid: _mk_host(nid, reg) for nid in (1, 2, 3)}
+    hosts = {nid: _mk_host(nid, reg, engine_kind) for nid in (1, 2, 3)}
     members = {1: "wl1:1", 2: "wl2:1"}
     try:
         for nid in (1, 2):
