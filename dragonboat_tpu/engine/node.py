@@ -311,13 +311,17 @@ class Node:
 
     def apply_update_run(self, entries, results=None) -> None:
         """Run-level completion for a contiguous batch of plain applied
-        entries (the RSM manager's fast path): batch-tracked proposals
-        complete per (batch_id, count) instead of per entry. `results`
-        aligns with `entries`; None means no per-request keys exist in the
-        run (the manager skips result realignment for pure batch runs)."""
-        counts: dict = {}
+        entries (the RSM manager's fast path): what apply_update does
+        for each of them (never rejected, never ignored, no read
+        notify), with batch-tracked proposals completed per (batch_id,
+        count). `results` aligns with `entries`; None means no
+        per-request keys exist in the run (the manager skips result
+        realignment for pure batch runs)."""
         if results is None and not self._batches:
-            return  # replica apply with no locally-tracked batches
+            # replica apply with no locally-tracked batches; a sampled
+            # entry is observed on the node that tracks its batch
+            return
+        counts: dict = {}
         sampled = None  # observed last: t_done is after the notify
         if results is None:
             for e in entries:

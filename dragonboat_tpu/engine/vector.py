@@ -4253,13 +4253,21 @@ class VectorEngine:
             sampled = prof.sampling
             if sampled:
                 c0 = time.thread_time()
+                n_ents = n_run_ents = n_runs = 0
             for cid in cids:
                 node = self.get_node(cid)
                 if node is None or node.stopped:
                     continue
-                if not node.sm.loaded(OffloadFrom.COMMIT_WORKER):
+                sm = node.sm
+                if not sm.loaded(OffloadFrom.COMMIT_WORKER):
                     continue  # lost the race with NodeHost close
                 node._apply_t0 = t0
+                if sampled:
+                    # what the manager applies and how (its own plain
+                    # counters: entries, those a run applied, runs)
+                    n_ents -= sm.applied_entries
+                    n_run_ents -= sm.applied_run_entries
+                    n_runs -= sm.applied_runs
                 try:
                     node.handle_task(batch, apply)
                 except Exception:
@@ -4267,14 +4275,21 @@ class VectorEngine:
 
                     traceback.print_exc()
                 finally:
-                    node.sm.offloaded(OffloadFrom.COMMIT_WORKER)
-                if node.sm.task_queue.size() > 0:
+                    sm.offloaded(OffloadFrom.COMMIT_WORKER)
+                if sampled:
+                    n_ents += sm.applied_entries
+                    n_run_ents += sm.applied_run_entries
+                    n_runs += sm.applied_runs
+                if sm.task_queue.size() > 0:
                     self.set_task_ready(cid)
             if sampled:
                 prof.observe(
                     "rsm.handle", time.monotonic() - t0,
                     time.thread_time() - c0, engine="rsm",
                 )
+                prof.fold("n.apply_entries", n_ents)
+                prof.fold("n.apply_run_entries", n_run_ents)
+                prof.fold("n.apply_runs", n_runs)
 
     def _snapshot_worker_main(self, worker: int) -> None:
         while not self._stopped.is_set():

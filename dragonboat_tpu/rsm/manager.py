@@ -9,7 +9,6 @@ replicated apply path, so every replica makes identical decisions.
 """
 from __future__ import annotations
 
-import contextlib
 import threading
 from collections import deque
 from dataclasses import dataclass, field
@@ -76,6 +75,7 @@ from ..types import (
     EntryType,
     Membership,
     Snapshot,
+    NOOP_CLIENT_ID,
     SERIES_ID_FOR_REGISTER,
     SERIES_ID_FOR_UNREGISTER,
 )
@@ -248,6 +248,25 @@ class StateMachineManager:
         self.task_queue = TaskQueue()
         self._batched_last_applied = 0
         self._sync_req_index = 0
+        # what this replica's apply path did, for the worker that drives
+        # it to fold into the profiler: entries applied, those of them a
+        # run applied, and runs (one worker handles a node, so plain ints)
+        self.applied_entries = 0
+        self.applied_run_entries = 0
+        self.applied_runs = 0
+        # Critical section for `sm.update + applied-index advance`, so a
+        # snapshot can never capture an index older than the data it
+        # saves (replay from it would apply the gap twice, a whole run
+        # wide). For a non-concurrent SM it is the wrapper mutex, which
+        # save_snapshot holds across its index label + data write. A
+        # concurrent/on-disk SM's snapshot is point-in-time from
+        # prepare_snapshot, but its label is read beside it in
+        # _get_ss_meta: those two take a lock of their own, outside
+        # `_mu`, so no user update ever runs under `_mu`.
+        if managed.concurrent_snapshot() or managed.on_disk():
+            self._apply_section = threading.Lock()
+        else:
+            self._apply_section = managed.exclusive()
 
     # ------------------------------------------------------------ properties
     def last_applied_index(self) -> int:
@@ -415,7 +434,7 @@ class StateMachineManager:
         self._snapshotter.stream(self._make_save_fn(meta), meta, sink)
 
     def _get_ss_meta(self, req: SSRequest) -> SSMeta:
-        with self._mu:
+        with self._apply_section, self._mu:
             if self._members.is_empty():
                 raise RuntimeError("taking snapshot with empty membership")
             ctx = self._sm.prepare_snapshot() if self._sm.concurrent_snapshot() else None
@@ -441,17 +460,6 @@ class StateMachineManager:
         self._sm.sync()
 
     # --------------------------------------------------------------- applying
-    def _apply_section(self):
-        """Critical section for `sm.update + applied-index advance`: a
-        non-concurrent SM returns the wrapper mutex (the same lock
-        save_snapshot holds across its index label + data write), so a
-        snapshot can never capture an index older than the data it saves.
-        Concurrent/on-disk SMs take point-in-time snapshots through
-        prepare_snapshot and need no cross-section — they get a no-op."""
-        if self._sm.concurrent_snapshot() or self._sm.on_disk():
-            return contextlib.nullcontext()
-        return self._sm.exclusive()
-
     def handle(self, batch: List[Task], apply: List[SMEntry]) -> Optional[Task]:
         """Drain the task queue, applying entry batches; returns the first
         snapshot task encountered (the engine routes it to a snapshot
@@ -483,24 +491,27 @@ class StateMachineManager:
         use_batch = self._sm.concurrent_snapshot() or self._sm.on_disk()
         apply.clear()
         # fast path for EVERY SM type: maximal runs of plain no-op-session
-        # updates apply under ONE lock round-trip with ONE run-level
-        # completion notify (per-entry locks + notifications were the
-        # apply-side hot spot at high proposal rates). Log order is
-        # preserved by flushing the other buffer whenever the entry stream
-        # switches between the run and the session/config slow path.
+        # application entries apply under ONE lock round-trip with ONE
+        # run-level completion notify (per-entry locks + notifications
+        # were the apply-side hot spot at high proposal rates). Session-
+        # managed entries, config changes and empty new-leader entries go
+        # one by one. Log order is preserved by flushing the other buffer
+        # whenever the entry stream switches between the two.
         run: List[Entry] = []
+        slow = 0
+        floor = self._index  # the last index applied or taken up here
         for t in batch:
             for e in t.entries:
-                if e.index <= self._index:
+                if e.index <= floor:
                     # already applied: a snapshot recovery can leapfrog
                     # entry tasks that were queued before it (the reference
                     # tolerates the same overlap, statemachine.go onUpdate)
                     continue
+                floor = e.index
                 if (
-                    not e.is_config_change()
-                    and e.is_update()
-                    and not e.is_empty()
-                    and e.is_noop_session()
+                    e.client_id == NOOP_CLIENT_ID
+                    and e.cmd
+                    and e.type != EntryType.CONFIG_CHANGE
                 ):
                     if apply:
                         self._apply_batch(apply)
@@ -508,6 +519,7 @@ class StateMachineManager:
                     run.append(e)
                     continue
                 self._flush_run(run)
+                slow += 1
                 if use_batch:
                     self._handle_entry_batched(e, apply)
                 else:
@@ -516,6 +528,7 @@ class StateMachineManager:
         if apply:
             self._apply_batch(apply)
             apply.clear()
+        self.applied_entries += slow
         batch.clear()
 
     def _flush_run(self, run: List[Entry]) -> None:
@@ -524,33 +537,35 @@ class StateMachineManager:
             return
         ents = run[:]
         run.clear()
-        skip_until = self._on_disk_init_index if self._sm.on_disk() else 0
-        smes = [SMEntry(index=e.index, cmd=decode_payload(e)) for e in ents]
-        to_run = [se for se in smes if se.index > skip_until]
+        on_disk = self._sm.on_disk()
+        skip_until = self._on_disk_init_index if on_disk else 0
+        smes = [
+            SMEntry(index=e.index, cmd=decode_payload(e))
+            for e in ents if e.index > skip_until
+        ]
         last = ents[-1]
-        with self._apply_section():
-            done = self._sm.update(to_run) if to_run else []
+        with self._apply_section:
+            done = self._sm.update(smes) if smes else smes
             with self._mu:
                 self._set_applied(last.index, last.term)
-                if self._sm.on_disk():
+                if on_disk:
                     self._on_disk_index = max(self._on_disk_index, last.index)
+        self.applied_entries += len(ents)
+        self.applied_run_entries += len(ents)
+        self.applied_runs += 1
         # per-proposal results are only retained for per-request keys;
         # batch-tracked proposals complete by count alone, so the common
-        # bulk path skips the result realignment entirely
+        # bulk path skips the result realignment entirely. By index, not
+        # by position: what the SM skipped (on-disk, already persisted)
+        # or did not hand back answers with the empty Result, as
+        # _do_update answers it.
         if any(e.key and not (e.key & BATCH_KEY_BIT) for e in ents):
             by_index = {se.index: se.result for se in done}
             empty = Result()
             results = [by_index.get(e.index, empty) for e in ents]
         else:
             results = None
-        run_notify = getattr(self._node, "apply_update_run", None)
-        if run_notify is not None:
-            run_notify(ents, results)
-        else:  # minimal INodeProxy implementations (tests, tools)
-            if results is None:
-                results = [Result()] * len(ents)
-            for e, r in zip(ents, results):
-                self._node.apply_update(e, r, False, False, False)
+        self._node.apply_update_run(ents, results)
 
     def _handle_entry_batched(self, e: Entry, apply: List[SMEntry]) -> None:
         """Batched path: plain updates accumulate; anything session- or
@@ -597,8 +612,10 @@ class StateMachineManager:
 
     def _apply_batch(self, apply: List[SMEntry]) -> None:
         # only reachable for concurrent/on-disk SMs (_handle_batch's
-        # use_batch gate), whose snapshots are point-in-time — no
-        # _apply_section needed here
+        # use_batch gate), and from _apply_batch_boundary under `_mu`,
+        # inside which the _apply_section lock cannot be taken: a snapshot
+        # between update and the index advance below can still label a
+        # buffer of session-managed updates with the index before it
         if not apply:
             return
         skip_until = self._on_disk_init_index if self._sm.on_disk() else 0
@@ -676,7 +693,7 @@ class StateMachineManager:
 
     def _do_update(self, e: Entry, notify_read: bool, session: int = 0) -> None:
         skip = self._sm.on_disk() and e.index <= self._on_disk_init_index
-        with self._apply_section():
+        with self._apply_section:
             if skip:
                 results = [SMEntry(index=e.index, cmd=decode_payload(e))]
             else:
