@@ -90,13 +90,18 @@ class WorkReady:
             self._events[p].set()
 
     def wait_and_take(self, worker: int, timeout: float = 0.5) -> Set[int]:
-        ev = self._events[worker]
-        if not ev.wait(timeout):
+        if not self._events[worker].wait(timeout):
             return set()
+        return self.take(worker)
+
+    def take(self, worker: int) -> Set[int]:
+        """What has become ready for `worker` since it last took, without
+        waiting: a worker in the middle of a long batch looks again
+        between two tasks."""
         with self._locks[worker]:
             out = self._sets[worker]
             self._sets[worker] = set()
-            ev.clear()
+            self._events[worker].clear()
         return out
 
     def wake_all(self) -> None:

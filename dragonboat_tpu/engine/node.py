@@ -65,6 +65,14 @@ from .queue import EntryQueue, MessageQueue, ReadIndexQueue
 from .snapshotstate import SnapshotState
 
 
+def _span_start(observe):
+    """(wall, thread CPU) where a span will be recorded, else zeros: an
+    unobserved task reads no clock."""
+    if observe is None:
+        return 0.0, 0.0
+    return time.monotonic(), time.thread_time()
+
+
 class Node:
     def __init__(
         self,
@@ -178,6 +186,13 @@ class Node:
         # (cf. snapshotstate.go:64-214)
         self.ss = SnapshotState()
         self._applied_since_snapshot = 0
+        # plain counts of completed snapshot work (saves committed,
+        # InstallSnapshot restores, deferred log compactions run); the
+        # vector engine's snapshot workers fold their deltas on sampled
+        # wake-ups, as its task workers fold the manager's
+        self.snapshots_saved = 0
+        self.snapshots_installed = 0
+        self.log_compactions = 0
         # launch the protocol core (VectorNode overrides: its protocol state
         # lives in the shared device tensors, not a per-group Peer)
         self.peer = self._launch_core(
@@ -954,19 +969,27 @@ class Node:
         self._applied_since_snapshot = 0
         self.push_take_snapshot_request(SSRequest())
 
-    def run_snapshot_work(self) -> None:
+    def run_snapshot_work(self, observe=None) -> None:
         """Executed on a snapshot worker: drain the FSM's request slots and
         any deferred log compaction (cf. execengine.go:227-335 snapshot
-        worker mains + snapshotstate.go req slots)."""
+        worker mains + snapshotstate.go req slots). `observe(name, t0,
+        c0)`, where the engine passes one, records a task's span from its
+        wall and thread-CPU start."""
         did = False
         task, had = self.ss.save_req.take()
         if had:
             did = True
+            t0, c0 = _span_start(observe)
             self._do_save_snapshot(task.ss_request or SSRequest())
+            if observe is not None:
+                observe("snap.save", t0, c0)
         task, had = self.ss.recover_req.take()
         if had:
             did = True
+            t0, c0 = _span_start(observe)
             self._do_recover_snapshot(task)
+            if observe is not None:
+                observe("snap.recover", t0, c0)
         if did:
             # a snapshot task that raced the occupied slot sits requeued in
             # the task queue; wake the task worker now that the slot drained
@@ -976,9 +999,13 @@ class Node:
             # persistent-log compaction is disk IO: it runs HERE, not under
             # the protocol lock where finalization queued it
             # (cf. snapshotstate.go compactLogTo + node.go:849-867)
+            t0, c0 = _span_start(observe)
             self.logdb.remove_entries_to(
                 self.cluster_id, self._node_id, compact_to
             )
+            self.log_compactions += 1
+            if observe is not None:
+                observe("snap.compact", t0, c0)
 
     def _do_save_snapshot(self, req: SSRequest) -> None:
         """IO half of a save, on the snapshot worker; the result lands in
@@ -994,6 +1021,7 @@ class Node:
             else:
                 ss, env = self.sm.save_snapshot(req)
                 self.snapshotter.commit(ss, req)
+                self.snapshots_saved += 1
         except Exception:
             failed = True
         self.ss.save_completed.put((ss, req, failed, ignored))
@@ -1046,6 +1074,7 @@ class Node:
                             self.sm.last_applied_index()
                         )
                 self.clear_install_aborted()
+                self.snapshots_installed += 1
         finally:
             self.ss.clear_recovering_from_snapshot()
 

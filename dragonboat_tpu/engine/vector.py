@@ -276,6 +276,91 @@ def _make_activate_fn(cfg: KernelConfig, n: int):
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _make_patch_fn(cfg: KernelConfig):
+    """Jitted reconcile of the lanes a config change, a snapshot restore
+    or a stop touched, ALL of them in one fixed-shape call an iteration
+    of the loop: whole-G masked updates, compiled once. The per-peer
+    planes are re-ranked ON DEVICE from `src` (the old slot now standing
+    at each slot, -1 for a new peer), so the loop reads nothing back. A
+    lane reconciled by `.at[g].set` chains cost ten blocking reads and
+    twenty eager dispatches a config change a replica; at a few replica
+    moves a second over a fleet that was the whole loop."""
+    P = cfg.peers
+
+    def apply(s: RaftTensors, v):
+        m, rs, src = v["remap"], v["restore"], v["src"]
+        has = src >= 0
+        idx = jnp.maximum(src, 0)
+        mp, rsp = m[:, None], rs[:, None]
+        cols = jnp.arange(1, P + 1, dtype=jnp.int32)[None, :]
+
+        def perm(x, default):
+            moved = jnp.where(has, jnp.take_along_axis(x, idx, axis=1), default)
+            return jnp.where(mp, moved, x)
+
+        def ref(x):
+            # slot+1 encoded references (leader/vote/transfer)
+            new = jnp.max(
+                jnp.where(has & (src == (x - 1)[:, None]), cols, 0), axis=1
+            )
+            return jnp.where(m, jnp.where(x > 0, new, 0), x).astype(x.dtype)
+
+        def put(x, value, mask=rs):
+            mask = mask if x.ndim == 1 else mask[:, None]
+            return jnp.where(mask, value, x).astype(x.dtype)
+
+        nxt = jnp.maximum(perm(s.next, (s.last_index + 1)[:, None]), 1)
+        return s._replace(
+            active=s.active & ~v["deact"],
+            pending_cc=s.pending_cc & ~v["cc_clear"],
+            member=put(s.member, v["member"], m),
+            voting=put(s.voting, v["voting"], m),
+            observer=put(s.observer, v["observer"], m),
+            witness=put(s.witness, v["witness"], m),
+            self_slot=put(s.self_slot, v["self_slot"], m),
+            role=put(s.role, ROLE.FOLLOWER, v["to_follower"]),
+            leader=ref(s.leader),
+            vote=ref(s.vote),
+            transfer_to=ref(s.transfer_to),
+            match=put(perm(s.match, 0), 0),
+            next=put(jnp.where(mp, nxt, s.next), 1),
+            rstate=put(perm(s.rstate, RSTATE.RETRY), RSTATE.RETRY),
+            ract=perm(s.ract, False),
+            snap_sent=put(perm(s.snap_sent, 0), 0),
+            vresp=perm(s.vresp, False),
+            vgrant=perm(s.vgrant, False),
+            # ack bitmasks are slot-indexed: clear and let heartbeats
+            # re-confirm (membership changes are rare a lane)
+            ri_acks=put(s.ri_acks, 0, m),
+            # a lane rebuilt at a snapshot point (raft.go:439-517 restore)
+            term=put(s.term, jnp.maximum(s.term, v["term"])),
+            first_index=put(s.first_index, 1),
+            marker_term=put(s.marker_term, v["marker_term"]),
+            last_index=put(s.last_index, 0),
+            committed=put(s.committed, 0),
+            processed=put(s.processed, 0),
+            applied=put(s.applied, 0),
+            unsaved_from=put(s.unsaved_from, 1),
+            log_term=put(s.log_term, 0),
+            log_is_cc=put(s.log_is_cc, False),
+            ri_ctx=put(s.ri_ctx, 0),
+            ri_index=put(s.ri_index, 0),
+            ri_count=put(s.ri_count, 0),
+            # an InstallSnapshot is word from the leader (raft.go
+            # handleFollowerInstallSnapshot: leaderIsAvailable resets the
+            # election tick). The kernel never saw that message, so the
+            # timer is reset here: a joiner that carried an expired timer
+            # into its first membership campaigned at once and deposed
+            # the leader that was bringing it up
+            election_tick=put(s.election_tick, 0),
+        )
+
+    return compile_watch().register(
+        "reconcile_lanes", jax.jit(apply, donate_argnums=(0,))
+    )
+
+
 class _SharedClock(LogicalClock):
     """One logical clock shared by every lane of a VectorEngine. The engine
     loop gates the pending-queue gc pass with ONE should_gc() check per
@@ -444,6 +529,7 @@ class VectorNode(Node):
             idx = self.sm.recover_from_snapshot(task)
             if idx > 0:
                 self.clear_install_aborted()
+                self.snapshots_installed += 1
                 ss = self.snapshotter.get_most_recent_snapshot()
                 if ss is not None and not ss.is_empty():
                     with self._mu:
@@ -602,15 +688,17 @@ class _Lane:
         # because the engine's _m_term mirror is rebound from device state
         # every step (the device never saw the snapshot message).
         self.adopted_term = 0
-        # slot -> [next_to_send, goal, match_at_progress, progress_tick]
+        # slot -> [next_to_send, goal, match_at_progress, progress_tick,
+        # progress_launch]
         self.catchup: Dict[int, list] = {}
         # snapshot-status feedback (cf. feedback.go:38-128): slot ->
-        # (sent_tick, snapshot_index); a peer that does not ack the
+        # (sent_tick, snapshot_index, sent_launch, sent_at: the host clock
+        # of a sampled send, else 0.0); a peer that does not ack the
         # snapshot within the retry window gets a synthetic
         # SNAPSHOT_STATUS reject so the kernel un-parks it and the
         # leader retries — a lost InstallSnapshot must not wedge the
         # remote in SNAPSHOT state forever
-        self.snap_inflight: Dict[int, Tuple[int, int]] = {}
+        self.snap_inflight: Dict[int, tuple] = {}
         self.active = False
         self.cc_inflight = False
         # (members, observers, witnesses) snapshot of the last membership
@@ -998,6 +1086,21 @@ def build_save_updates(o: dict, base, lane_by_g):
     return updates, lane_saves
 
 
+# The per-peer recovery timers (catch-up stall, snapshot feedback retry)
+# are counted in ticks, as the reference's (feedback.go:38-128), AND in
+# launches: a peer's acknowledgement cannot be back in fewer launches than
+# its round trip takes (replicate out, follower step, response in, leader
+# step), nor a restore's in fewer than the hand-offs it passes (pack, task
+# worker, snapshot worker, reconcile, acknowledgement, leader step). On a
+# loop whose launch outlasts the tick bound (a loaded fleet: 1-3 s a
+# launch, 2 s of ticks) the ticks alone declared every catching-up peer
+# lost before its first acknowledgement could return, and shipped it a
+# snapshot, and then another. Where launches are short the ticks decide,
+# as before.
+_ACK_LAUNCHES = 4
+_RESTORE_LAUNCHES = 8
+
+
 def _is_ack(m: Message) -> bool:
     t = m.type
     return t == MT.HEARTBEAT_RESP or (t == MT.REPLICATE_RESP and not m.reject)
@@ -1288,6 +1391,7 @@ class VectorEngine:
         self._free = list(range(self._groups_requested - 1, -1, -1))
         self._lanes_mu = threading.RLock()
         self._reconq: deque = deque()  # host->device ops, loop-applied
+        self._patch: Optional[dict] = None  # see _staged_patch
         self._stopped = threading.Event()
         self._ready = threading.Event()
         # crash teardown flag (stop(flush=False)): the loop discards its
@@ -1463,7 +1567,13 @@ class VectorEngine:
         # event) summed here by the decode fold — loop-thread writes,
         # lock-free reads via counter_stats/lane_counters (a torn read
         # costs one stale sample on an export path, never a decision)
+        # lanes whose state machine is being restored from a snapshot:
+        # they are given no ticks (see the tick plane in _run_once)
+        self._m_recovering = np.zeros((G,), bool)
         self._ctr = np.zeros((G, CTR.COUNT), np.uint64)
+        # what the lanes that have left had counted: counter_stats() stays
+        # cumulative when a lane is freed or reused
+        self._ctr_left = np.zeros((CTR.COUNT,), np.uint64)
 
     # ------------------------------------------------------- mirror helpers
     def _committed_real(self, g: int) -> int:
@@ -1850,7 +1960,12 @@ class VectorEngine:
                     hv[h] = c
                 per_lane = hv[self._m_host]
             np.minimum(self._m_tick_cap, per_lane, out=self._ticks)
-            self._ticks *= self._m_active
+            # a lane being restored from a snapshot is not stepped in the
+            # reference (node.go: a recovering node's step is skipped):
+            # its messages are held host-side, so its election timer must
+            # stand too, or a restore that outlasts the timeout makes the
+            # replica campaign against the leader that is bringing it up
+            self._ticks *= self._m_active & ~self._m_recovering
             self._last_tick_burst = ticks
             if ticks > 1 and bool(
                 np.any((per_lane > self._m_tick_cap) & self._m_active)
@@ -2149,12 +2264,41 @@ class VectorEngine:
                 if own and len(lane.msg_backlog) + k > K - own:
                     _coalesce_acks(lane.msg_backlog)
                     wire_end = max(K - own, k + 1)
+            # A follower takes no more Replicate entries in a step than
+            # its device window has room for. The leader's own window
+            # bounds what device replication sends, but not a backlog of
+            # host-log catch-up Replicates released at once (held while a
+            # snapshot restored, or sent one a launch while the launches
+            # were slow): five rows of 64 into a window of 256 wrapped
+            # the ring, and the replica lost entries it had acknowledged.
+            # What does not fit waits, in order, for the next step.
+            room = W - 1 - (g_last - g_devfirst + 1)
+            tail = g_last
+            held = None
             while lane.msg_backlog and k < wire_end:
                 m = lane.msg_backlog.popleft()
+                if m.type == MT.REPLICATE and m.entries and not is_leader:
+                    if held is not None:
+                        held.append(m)  # behind one that waits: in order
+                        continue
+                    # (one that leaves a gap is the kernel's to reject:
+                    # the leader learns where this replica stands from it)
+                    if m.log_index - b <= tail:
+                        grow = (
+                            m.log_index - b + min(len(m.entries), E) - tail
+                        )
+                        if grow > room:
+                            held = [m]
+                            continue
+                        if grow > 0:
+                            room -= grow
+                            tail += grow
                 k_used = self._pack_wire(lane, m, k, b)
                 if k_used:
                     had = True
                     k += 1
+            if held:
+                lane.msg_backlog.extendleft(reversed(held))
             leader_nid = lane.rev.get(g_leader - 1)
             # 2. one config change per step (lone message; host invariant)
             if k < K and lane.staged_ccs and not lane.cc_inflight:
@@ -2574,6 +2718,7 @@ class VectorEngine:
         if lane.recovering:
             return  # a restore is already in flight; the retry re-delivers
         lane.recovering = True
+        self._m_recovering[lane.g] = True
         # multi-step: a recovering lane leaves the on-device routing
         # table — routed traffic would advance kernel state the restore
         # is about to overwrite; the host path holds its messages instead
@@ -3278,7 +3423,7 @@ class VectorEngine:
         goal = self._last_real(g)
         first, last = lane.node.log_reader.get_range()
         if start >= first and start <= last + 1:
-            lane.catchup[p] = [start, goal, m.hint, self.clock.tick]
+            lane.catchup[p] = [start, goal, m.hint, self.clock.tick, self.launch_no]
             self._catchups.add(lane)
         else:
             self._send_snapshot(lane, p)
@@ -3295,11 +3440,27 @@ class VectorEngine:
         b = int(self._m_base[g])
         goal = b + int(o["last_index"][g])
         match = b + int(o["match"][g, p])
+        sent = lane.snap_inflight.get(p)
+        if sent is not None and match < sent[1]:
+            # a snapshot is on its way and its restore not acknowledged
+            # yet. The transport's "delivered" status un-parked the peer
+            # (becomeWait, remote.go:119-127) and the kernel parked it
+            # again at the leader's last index: put the watermark back on
+            # the snapshot that was sent, so its acknowledgement un-parks
+            # the peer, and send nothing. (Deciding by `match` here, which
+            # a restore in progress has not moved, shipped a second whole
+            # image behind every first.) A lost snapshot is the feedback
+            # timer's to retry.
+            s = self._state
+            self._state = s._replace(
+                snap_sent=s.snap_sent.at[g, p].set(max(sent[1] - b, 0))
+            )
+            return
         start = match + 1
         first, last = lane.node.log_reader.get_range()
         if start >= first and start <= last + 1:
-            # [next_to_send, goal, match_at_progress, progress_tick]
-            lane.catchup[p] = [start, goal, match, self.clock.tick]
+            # [next_to_send, goal, match_at_progress, progress_tick, _launch]
+            lane.catchup[p] = [start, goal, match, self.clock.tick, self.launch_no]
             self._catchups.add(lane)
         else:
             # the follower needs entries the host log no longer has
@@ -3321,7 +3482,7 @@ class VectorEngine:
             # still arm the feedback timer: the synthetic reject will
             # un-park the peer so host-log replication retries instead of
             # wedging it in SNAPSHOT state
-            lane.snap_inflight[p] = (self.clock.tick, 0)
+            lane.snap_inflight[p] = (self.clock.tick, 0, self.launch_no, 0.0)
             self._snapfb.add(lane)
             return
         if p in lane.wit_slots:
@@ -3349,7 +3510,13 @@ class VectorEngine:
         self._state = s._replace(
             snap_sent=s.snap_sent.at[g, p].set(dev_idx)
         )
-        lane.snap_inflight[p] = (self.clock.tick, ss.index)
+        sent_at = 0.0
+        if self.profiler.sampling:
+            self.profiler.fold("n.snapshots_sent", 1)
+            sent_at = time.monotonic()
+        lane.snap_inflight[p] = (
+            self.clock.tick, ss.index, self.launch_no, sent_at
+        )
         self._snapfb.add(lane)
 
     def _run_catchups(self, lane: _Lane, o) -> None:
@@ -3362,63 +3529,76 @@ class VectorEngine:
         # as lost (the same silence bound the protocol uses to declare a
         # leader dead) and falls back to the snapshot path
         stall_ticks = max(2 * lane.cfg.election_rtt, 8)
+        now, launch = self.clock.tick, self.launch_no
         done = []
         for p, cu in lane.catchup.items():
-            nxt, goal, last_match, progress_tick = cu
+            nxt, goal, last_match, progress_tick, progress_launch = cu
             match = b + int(o["match"][g, p])
             if match >= goal or int(self._m_role[g]) != ROLE.LEADER:
                 done.append(p)
                 continue
             if match > last_match:
-                cu[2], cu[3] = match, self.clock.tick
-            elif self.clock.tick - progress_tick > stall_ticks:
+                cu[2], cu[3], cu[4] = match, now, launch
+            elif (
+                now - progress_tick > stall_ticks
+                and launch - progress_launch >= _ACK_LAUNCHES
+            ):
                 done.append(p)
                 self._send_snapshot(lane, p)
                 continue
             if match + 1 > nxt:
                 nxt = match + 1
-            first, last = lane.node.log_reader.get_range()
-            if nxt < first:
-                done.append(p)
-                self._send_snapshot(lane, p)
-                continue
-            if nxt > last:
-                continue  # wait for the follower to ack what's in flight
-            hi = min(nxt + self.kcfg.max_entries_per_msg - 1, last, goal)
-            try:
-                ents = lane.node.log_reader.entries(nxt, hi + 1, 1 << 20)
-                prev = nxt - 1
-                prev_term = (
-                    lane.node.log_reader.term(prev) if prev > 0 else 0
-                )
-            except Exception:
-                done.append(p)
-                self._send_snapshot(lane, p)
-                continue
-            if not ents:
-                done.append(p)
-                continue
             to_nid = lane.rev.get(p)
             if to_nid is None:
                 done.append(p)
                 continue
-            if p in lane.wit_slots:
-                # host catchup honors the witness shape too
-                ents = _make_metadata_entries(ents)
-            lane.node._send_message(
-                Message(
-                    type=MT.REPLICATE,
-                    cluster_id=lane.node.cluster_id,
-                    to=to_nid,
-                    from_=lane.node.node_id(),
-                    term=int(self._m_term[g]),
-                    log_index=prev,
-                    log_term=prev_term,
-                    commit=min(self._committed_real(g), ents[-1].index),
-                    entries=ents,
+            # half a window of entries a launch, in Replicates of
+            # max_entries_per_msg: the follower's pack takes what its own
+            # window has room for and keeps the rest in order. One message
+            # a launch left a joiner four batches behind five launches
+            # from its leader, on a loop whose launch is seconds long.
+            budget = max(self.kcfg.log_window // 2, 1)
+            while budget > 0:
+                first, last = lane.node.log_reader.get_range()
+                if nxt < first:
+                    done.append(p)
+                    self._send_snapshot(lane, p)
+                    break
+                if nxt > last or nxt > goal:
+                    break  # wait for the follower to ack what's in flight
+                hi = min(nxt + self.kcfg.max_entries_per_msg - 1, last, goal)
+                try:
+                    ents = lane.node.log_reader.entries(nxt, hi + 1, 1 << 20)
+                    prev = nxt - 1
+                    prev_term = (
+                        lane.node.log_reader.term(prev) if prev > 0 else 0
+                    )
+                except Exception:
+                    done.append(p)
+                    self._send_snapshot(lane, p)
+                    break
+                if not ents:
+                    done.append(p)
+                    break
+                last_sent = ents[-1].index
+                if p in lane.wit_slots:
+                    # host catchup honors the witness shape too
+                    ents = _make_metadata_entries(ents)
+                lane.node._send_message(
+                    Message(
+                        type=MT.REPLICATE,
+                        cluster_id=lane.node.cluster_id,
+                        to=to_nid,
+                        from_=lane.node.node_id(),
+                        term=int(self._m_term[g]),
+                        log_index=prev,
+                        log_term=prev_term,
+                        commit=min(self._committed_real(g), last_sent),
+                        entries=ents,
+                    )
                 )
-            )
-            cu[0] = ents[-1].index + 1
+                budget -= len(ents)
+                nxt = cu[0] = last_sent + 1
         for p in done:
             lane.catchup.pop(p, None)
         if not lane.catchup:
@@ -3438,14 +3618,27 @@ class VectorEngine:
         g = lane.g
         b = int(self._m_base[g])
         retry_ticks = max(4 * lane.cfg.election_rtt, 16)
+        now, launch = self.clock.tick, self.launch_no
         is_leader = int(self._m_role[g]) == ROLE.LEADER
         done = []
-        for p, (sent_tick, ss_index) in lane.snap_inflight.items():
+        for p, sent in lane.snap_inflight.items():
+            sent_tick, ss_index, sent_launch, sent_at = sent
             match = b + int(o["match"][g, p])
             if not is_leader or (ss_index > 0 and match >= ss_index):
                 done.append(p)  # acked (or leadership moved on)
+                if sent_at and is_leader:
+                    # sent -> the restored replica's acknowledgement seen
+                    prof = self.profiler
+                    prof.observe(
+                        "snap.install", time.monotonic() - sent_at,
+                        engine="snapshot",
+                    )
+                    prof.fold("n.snapshots_acked", 1)
                 continue
-            if self.clock.tick - sent_tick > retry_ticks:
+            if (
+                now - sent_tick > retry_ticks
+                and launch - sent_launch >= _RESTORE_LAUNCHES
+            ):
                 done.append(p)
                 from_nid = lane.rev.get(p)
                 if from_nid is not None:
@@ -3483,6 +3676,8 @@ class VectorEngine:
         parked = (o["rstate"] == RSTATE.SNAPSHOT) & (
             (o["role"] == ROLE.LEADER)[:, None]
         )
+        if self.profiler.sampling:
+            self.profiler.fold("n.peer_steps_parked", np.count_nonzero(parked))
         for g, p in zip(*np.nonzero(parked)):
             lane = lane_by_g[g]
             if (
@@ -3614,7 +3809,6 @@ class VectorEngine:
     # ----------------------------------------------------------- reconciles
     def _apply_reconciles(self) -> None:
         batch: List[_Lane] = []
-        cc_clear: List[int] = []
         while True:
             try:
                 op = self._reconq.popleft()
@@ -3624,14 +3818,16 @@ class VectorEngine:
                 batch.append(op[1])
                 continue
             if op[0] == "cc_done":
-                # batched below: one fixed-shape mask op instead of a
-                # per-lane scatter (bootstrap emits one per cluster)
+                # staged below with the other lane patches: one fixed-
+                # shape mask op instead of a per-lane scatter (bootstrap
+                # emits one per cluster)
                 lane = self._lane_of(op[1])
                 if lane is not None and lane.active:
-                    cc_clear.append(lane.g)
+                    self._staged_patch()["cc_clear"][lane.g] = True
                     lane.cc_inflight = False
                 continue
             if batch:
+                self._flush_patch()  # a lane index freed above is reused
                 self._activate_batch(batch)
                 batch = []
             try:
@@ -3648,19 +3844,79 @@ class VectorEngine:
                     lane = self._lane_of(op[1])
                     if lane is not None:
                         lane.recovering = False
+                        self._m_recovering[lane.g] = False
                         self._routes_dirty = True
             except Exception:
                 import traceback
 
                 traceback.print_exc()
+        self._flush_patch()
         if batch:
             self._activate_batch(batch)
-        if cc_clear:
-            mask = np.zeros((self.kcfg.groups,), bool)
-            mask[cc_clear] = True
-            s = self._state
-            self._state = s._replace(
-                pending_cc=s.pending_cc & jnp.asarray(~mask)
+
+    # the planes one _make_patch_fn call takes, staged host-side
+    _PATCH_MASKS = ("remap", "restore", "to_follower", "deact", "cc_clear")
+    _PATCH_PEER_FLAGS = ("member", "voting", "observer", "witness")
+    _PATCH_COLS = ("self_slot", "term", "marker_term")
+
+    def _staged_patch(self) -> dict:
+        """The device patch this iteration's reconciles are staging
+        (created on first use, applied by _flush_patch)."""
+        v = self._patch
+        if v is None:
+            G, P = self.kcfg.groups, self.kcfg.peers
+            v = {name: np.zeros((G,), bool) for name in self._PATCH_MASKS}
+            for name in self._PATCH_PEER_FLAGS:
+                v[name] = np.zeros((G, P), bool)
+            for name in self._PATCH_COLS:
+                v[name] = np.zeros((G,), np.int32)
+            v["src"] = np.full((G, P), -1, np.int32)
+            self._patch = v
+        return v
+
+    def _stage_remap(self, lane: _Lane, perm: Dict[int, int], mem) -> dict:
+        """Stage lane's re-ranked slots (perm: old slot -> new slot, from
+        _Lane.set_slots) and its membership flags; returns the staged
+        patch for the caller's own fields. A lane patched twice in one
+        iteration takes two calls: the second re-ranks the first's result."""
+        g = lane.g
+        if self._patch is not None and self._patch["remap"][g]:
+            self._flush_patch()
+        v = self._staged_patch()
+        P = self.kcfg.peers
+        v["remap"][g] = True
+        src = v["src"][g]
+        for old, new in perm.items():
+            if old < P and new < P:
+                src[new] = old
+        witness = v["witness"][g]
+        for nid, slot in lane.slots.items():
+            if slot >= P:
+                continue
+            v["member"][g, slot] = True
+            if nid in mem.observers:
+                v["observer"][g, slot] = True
+            elif nid in mem.witnesses:
+                witness[slot] = True
+                v["voting"][g, slot] = True
+            else:
+                v["voting"][g, slot] = True
+        lane.wit_slots = frozenset(np.nonzero(witness)[0].tolist())
+        self_slot = lane.self_slot()
+        if self_slot < 0:
+            self_slot = lane.slot_of(lane.node.node_id(), provisional=True)
+        v["self_slot"][g] = max(self_slot, 0)
+        # the leader mirror is a slot+1 reference too; it stays readable
+        # (get_leader_id, leader_snapshot) against the new lane.rev
+        old_leader = int(self._m_leader[g]) - 1
+        self._m_leader[g] = perm.get(old_leader, -1) + 1
+        return v
+
+    def _flush_patch(self) -> None:
+        v, self._patch = self._patch, None
+        if v is not None:
+            self._state = _make_patch_fn(self.kcfg)(
+                self._state, {k: jnp.asarray(a) for k, a in v.items()}
             )
 
     def _lane_of(self, node) -> Optional[_Lane]:
@@ -3891,7 +4147,7 @@ class VectorEngine:
     def _activate_batch(self, lanes: List[_Lane]) -> None:
         """Activate many lanes with ONE jitted scatter call — the engine
         analogue of ops/state.configure_groups_uniform. Batches pad to
-        power-of-4 buckets so the compile caches hit."""
+        power-of-4 buckets from 16 up so the compile caches hit."""
         vals: List[dict] = []
         gs: List[int] = []
         for lane in lanes:
@@ -3909,7 +4165,12 @@ class VectorEngine:
         if not vals:
             return
         n = len(vals)
-        bucket = 1
+        if self.profiler.sampling:
+            self.profiler.fold("n.lanes_joined", n)
+        # no bucket below 16: replicas that join a running core come one
+        # to a few an iteration, and each smaller bucket (1, 4) was one
+        # more compile, the second of them inside a serving window
+        bucket = 16
         while bucket < n:
             bucket *= 4
         bucket = min(bucket, self.kcfg.groups)
@@ -3945,9 +4206,10 @@ class VectorEngine:
                 # racing a graceful stop): freeing g twice would hand the
                 # same lane index to two tenants
                 return
-        s = self._state
-        self._state = s._replace(active=s.active.at[g].set(False))
+        self._staged_patch()["deact"][g] = True
         lane.active = False
+        if self.profiler.sampling:
+            self.profiler.fold("n.lanes_left", 1)
         # zero the freed lane's host planes so nothing leaks into the next
         # tenant of g: the inbox staging rows (the next occupant must
         # never see a stale row where _pack left data the kernel has
@@ -3972,6 +4234,8 @@ class VectorEngine:
         self._m_quiesced[g] = False
         self._m_host[g] = 0
         self._m_leader_change_tick[g] = 0
+        self._m_recovering[g] = False
+        self._ctr_left += self._ctr[g]
         self._ctr[g] = 0
         self._carry.discard(lane)
         self._catchups.discard(lane)
@@ -4013,57 +4277,9 @@ class VectorEngine:
         if sig == lane.mem_sig:
             return  # image unchanged (bootstrap CCs restate membership)
         lane.mem_sig = sig
-        P = self.kcfg.peers
         g = lane.g
         perm = lane.set_slots(member_ids)
-        s = self._state
-        # permute [P]-indexed rows: value at old slot moves to new slot
-        def permute_row(row, default):
-            vals = np.asarray(row)
-            out = np.full_like(vals, default)
-            for old, new in perm.items():
-                if old < P and new < P:
-                    out[new] = vals[old]
-            return out
-
-        member = np.zeros((P,), bool)
-        voting = np.zeros((P,), bool)
-        observer = np.zeros((P,), bool)
-        witness = np.zeros((P,), bool)
-        for nid, slot in lane.slots.items():
-            if slot >= P:
-                continue
-            member[slot] = True
-            if nid in mem.observers:
-                observer[slot] = True
-            elif nid in mem.witnesses:
-                witness[slot] = True
-                voting[slot] = True
-            else:
-                voting[slot] = True
-        lane.wit_slots = frozenset(np.nonzero(witness)[0].tolist())
-        dev_last = int(np.asarray(s.last_index[g]))
-        match = permute_row(s.match[g], 0)
-        nxt = permute_row(s.next[g], dev_last + 1)
-        nxt = np.maximum(nxt, 1)
-        rstate = permute_row(s.rstate[g], RSTATE.RETRY)
-        ract = permute_row(s.ract[g], False)
-        snap_sent = permute_row(s.snap_sent[g], 0)
-        vresp = permute_row(s.vresp[g], False)
-        vgrant = permute_row(s.vgrant[g], False)
-
-        def remap_ref(v):
-            # slot+1 encoded references (leader/vote/transfer)
-            v = int(np.asarray(v))
-            if v <= 0:
-                return 0
-            new = perm.get(v - 1)
-            return new + 1 if new is not None else 0
-
-        self_slot = lane.self_slot()
-        if self_slot < 0:
-            self_slot = lane.slot_of(node.node_id(), provisional=True)
-        new_leader = remap_ref(s.leader[g])
+        patch = self._stage_remap(lane, perm, mem)
         # self-promotion: an observer added as a full member becomes a
         # follower in place, inheriting its replicated log (cf. raft.go
         # addNode / scalar Raft.add_node become_follower path)
@@ -4072,29 +4288,7 @@ class VectorEngine:
             and node.node_id() in mem.addresses
         ):
             self._m_role[g] = ROLE.FOLLOWER
-            s = s._replace(role=s.role.at[g].set(ROLE.FOLLOWER))
-        upd = dict(
-            member=s.member.at[g].set(jnp.asarray(member)),
-            voting=s.voting.at[g].set(jnp.asarray(voting)),
-            observer=s.observer.at[g].set(jnp.asarray(observer)),
-            witness=s.witness.at[g].set(jnp.asarray(witness)),
-            self_slot=s.self_slot.at[g].set(max(self_slot, 0)),
-            leader=s.leader.at[g].set(new_leader),
-            vote=s.vote.at[g].set(remap_ref(s.vote[g])),
-            transfer_to=s.transfer_to.at[g].set(remap_ref(s.transfer_to[g])),
-            match=s.match.at[g].set(jnp.asarray(match)),
-            next=s.next.at[g].set(jnp.asarray(nxt)),
-            rstate=s.rstate.at[g].set(jnp.asarray(rstate)),
-            ract=s.ract.at[g].set(jnp.asarray(ract)),
-            snap_sent=s.snap_sent.at[g].set(jnp.asarray(snap_sent)),
-            vresp=s.vresp.at[g].set(jnp.asarray(vresp)),
-            vgrant=s.vgrant.at[g].set(jnp.asarray(vgrant)),
-            # ack bitmasks are slot-indexed: clear and let heartbeats
-            # re-confirm (membership changes are rare)
-            ri_acks=s.ri_acks.at[g].set(0),
-        )
-        self._state = s._replace(**upd)
-        self._m_leader[g] = new_leader
+            patch["to_follower"][g] = True
         # catchup/snapshot-feedback mirrors use slots: remap
         remapped = {}
         for p, v in lane.catchup.items():
@@ -4118,11 +4312,9 @@ class VectorEngine:
         if lane is None:
             return
         g = lane.g
-        P = self.kcfg.peers
-        W = self.kcfg.log_window
         mem = ss.membership or node.sm.get_membership()
         member_ids = set(mem.addresses) | set(mem.observers) | set(mem.witnesses)
-        lane.set_slots(member_ids)
+        perm = lane.set_slots(member_ids)
         lane.mem_sig = (
             frozenset(member_ids),
             frozenset(mem.observers),
@@ -4137,57 +4329,16 @@ class VectorEngine:
         lane.snap_inflight = {}
         self._catchups.discard(lane)
         self._snapfb.discard(lane)
-        member = np.zeros((P,), bool)
-        voting = np.zeros((P,), bool)
-        observer = np.zeros((P,), bool)
-        witness = np.zeros((P,), bool)
-        for nid, slot in lane.slots.items():
-            if slot >= P:
-                continue
-            member[slot] = True
-            if nid in mem.observers:
-                observer[slot] = True
-            elif nid in mem.witnesses:
-                witness[slot] = True
-                voting[slot] = True
-            else:
-                voting[slot] = True
-        lane.wit_slots = frozenset(np.nonzero(witness)[0].tolist())
-        self_slot = lane.self_slot()
-        if self_slot < 0:
-            self_slot = lane.slot_of(node.node_id(), provisional=True)
-        s = self._state
         # the lane may carry the snapshot sender's (higher) term, adopted
         # in _handle_install_snapshot; the restore ack must not be
-        # droppable as stale by the leader
-        term = max(int(np.asarray(s.term[g])), ss.term, lane.adopted_term)
+        # droppable as stale by the leader. The term mirror is the
+        # device's: nothing is in flight when a reconcile runs.
+        term = max(int(self._m_term[g]), ss.term, lane.adopted_term)
         lane.adopted_term = 0
-        upd = dict(
-            member=s.member.at[g].set(jnp.asarray(member)),
-            voting=s.voting.at[g].set(jnp.asarray(voting)),
-            observer=s.observer.at[g].set(jnp.asarray(observer)),
-            witness=s.witness.at[g].set(jnp.asarray(witness)),
-            self_slot=s.self_slot.at[g].set(max(self_slot, 0)),
-            term=s.term.at[g].set(term),
-            first_index=s.first_index.at[g].set(1),
-            marker_term=s.marker_term.at[g].set(ss.term),
-            last_index=s.last_index.at[g].set(0),
-            committed=s.committed.at[g].set(0),
-            processed=s.processed.at[g].set(0),
-            applied=s.applied.at[g].set(0),
-            unsaved_from=s.unsaved_from.at[g].set(1),
-            log_term=s.log_term.at[g].set(jnp.zeros((W,), jnp.int32)),
-            log_is_cc=s.log_is_cc.at[g].set(jnp.zeros((W,), bool)),
-            match=s.match.at[g].set(0),
-            next=s.next.at[g].set(1),
-            rstate=s.rstate.at[g].set(RSTATE.RETRY),
-            snap_sent=s.snap_sent.at[g].set(0),
-            ri_ctx=s.ri_ctx.at[g].set(0),
-            ri_index=s.ri_index.at[g].set(0),
-            ri_acks=s.ri_acks.at[g].set(0),
-            ri_count=s.ri_count.at[g].set(0),
-        )
-        self._state = s._replace(**upd)
+        patch = self._stage_remap(lane, perm, mem)
+        patch["restore"][g] = True
+        patch["term"][g] = term
+        patch["marker_term"][g] = ss.term
         # ---- numpy mirrors ------------------------------------------------
         self._m_base[g] = ss.index
         self._m_devfirst[g] = 1
@@ -4196,6 +4347,7 @@ class VectorEngine:
         self._m_last[g] = 0
         self._m_quiesced[g] = False
         lane.recovering = False
+        self._m_recovering[g] = False
         # base moved + recovering cleared: recompute routes/base deltas
         self._routes_dirty = True
         # restart/rejoin forensics: a lagging rejoiner whose log was
@@ -4253,7 +4405,7 @@ class VectorEngine:
             sampled = prof.sampling
             if sampled:
                 c0 = time.thread_time()
-                n_ents = n_run_ents = n_runs = 0
+                n_ents = n_run_ents = n_runs = n_ccs = 0
             for cid in cids:
                 node = self.get_node(cid)
                 if node is None or node.stopped:
@@ -4268,6 +4420,7 @@ class VectorEngine:
                     n_ents -= sm.applied_entries
                     n_run_ents -= sm.applied_run_entries
                     n_runs -= sm.applied_runs
+                    n_ccs -= sm.config_changes_applied
                 try:
                     node.handle_task(batch, apply)
                 except Exception:
@@ -4280,6 +4433,7 @@ class VectorEngine:
                     n_ents += sm.applied_entries
                     n_run_ents += sm.applied_run_entries
                     n_runs += sm.applied_runs
+                    n_ccs += sm.config_changes_applied
                 if sm.task_queue.size() > 0:
                     self.set_task_ready(cid)
             if sampled:
@@ -4290,29 +4444,78 @@ class VectorEngine:
                 prof.fold("n.apply_entries", n_ents)
                 prof.fold("n.apply_run_entries", n_run_ents)
                 prof.fold("n.apply_runs", n_runs)
+                prof.fold("n.config_changes_applied", n_ccs)
 
     def _snapshot_worker_main(self, worker: int) -> None:
-        while not self._stopped.is_set():
-            cids = self.snapshot_ready.wait_and_take(worker)
-            if not cids:
-                continue
+        """A replica waiting to be restored is out of service, a periodic
+        save is not: installs go first, and the worker looks for new ones
+        between two tasks. With two workers under one GIL and a save of
+        10 ms and more, a restore that queued behind the saves of every
+        node its worker had taken waited whole launches for its turn, and
+        a fleet's joiners piled up behind the fleet's saves."""
+        prof = self.profiler
+        ready = self.snapshot_ready
+        installs: list = []
+        saves: list = []
+
+        def admit(cids) -> None:
             for cid in cids:
                 node = self.get_node(cid)
-                if node is None or node.stopped:
-                    continue
-                if not node.sm.loaded(OffloadFrom.SNAPSHOT_WORKER):
-                    continue  # lost the race with NodeHost close
-                try:
-                    node.run_snapshot_work()
-                except Exception:
-                    import traceback
+                if node is not None and not node.stopped:
+                    if node.ss.recovering_from_snapshot():
+                        installs.append(node)
+                    else:
+                        saves.append(node)
 
-                    traceback.print_exc()
-                finally:
-                    node.sm.offloaded(OffloadFrom.SNAPSHOT_WORKER)
-                lane = self._lane_of(node)
-                if lane is not None:
-                    self._m_snap_pending[lane.g] = False
+        while not self._stopped.is_set():
+            admit(ready.wait_and_take(worker))
+            while installs or saves:
+                node = installs.pop() if installs else saves.pop()
+                # one span a task (`snap.save`, `snap.recover`,
+                # `snap.compact`) and the node's own plain counts, on
+                # sampled tasks only: the loop's flag of the moment
+                # decides, as for `rsm.handle`
+                observe = (
+                    self._observe_snapshot_task if prof.sampling else None
+                )
+                if node.sm.loaded(OffloadFrom.SNAPSHOT_WORKER):
+                    if observe is not None:
+                        before = (
+                            node.snapshots_saved, node.snapshots_installed,
+                            node.log_compactions,
+                        )
+                    try:
+                        node.run_snapshot_work(observe)
+                    except Exception:
+                        import traceback
+
+                        traceback.print_exc()
+                    finally:
+                        node.sm.offloaded(OffloadFrom.SNAPSHOT_WORKER)
+                    if observe is not None:
+                        prof.fold(
+                            "n.snapshots_saved",
+                            node.snapshots_saved - before[0],
+                        )
+                        prof.fold(
+                            "n.snapshots_installed",
+                            node.snapshots_installed - before[1],
+                        )
+                        prof.fold(
+                            "n.log_compactions",
+                            node.log_compactions - before[2],
+                        )
+                    lane = self._lane_of(node)
+                    if lane is not None:
+                        self._m_snap_pending[lane.g] = False
+                # (else: lost the race with NodeHost close)
+                admit(ready.take(worker))
+
+    def _observe_snapshot_task(self, name: str, t0: float, c0: float) -> None:
+        self.profiler.observe(
+            name, time.monotonic() - t0, time.thread_time() - c0,
+            engine="snapshot",
+        )
 
     # --------------------------------------------------------------- control
     def fairness_stats(self) -> dict:
@@ -4345,7 +4548,7 @@ class VectorEngine:
         steps and device-routed co-hosted traffic — and folded by the
         decode phase; reading them is a plain numpy sum over the
         cumulative mirror, zero device syncs."""
-        totals = self._ctr.sum(axis=0)
+        totals = self._ctr.sum(axis=0) + self._ctr_left
         return {name: int(totals[i]) for i, name in enumerate(CTR_NAMES)}
 
     def lane_counters(self) -> Dict[tuple, Dict[str, int]]:

@@ -619,7 +619,12 @@ def _handle_message(s: RaftTensors, m, out, cfg: KernelConfig):
             cur_match + 1,
             jnp.maximum(1, jnp.minimum(m["log_index"], m["hint"] + 1)),
         )
-        dec = valid_repl | valid_probe
+        # a peer parked for a snapshot stays parked: a reject of a probe
+        # sent before the park is stale (raft.go enterRetryState leaves a
+        # remote in the snapshot state alone). Un-parking it here made
+        # the next step ask for another snapshot of the same peer
+        parked = jnp.any(fr & (prev_rstate == RSTATE.SNAPSHOT), axis=1)
+        dec = (valid_repl | valid_probe) & ~parked
         s = s._replace(
             next=jnp.where(dec[:, None] & fr, nn[:, None], s.next),
             rstate=jnp.where(

@@ -250,10 +250,12 @@ class StateMachineManager:
         self._sync_req_index = 0
         # what this replica's apply path did, for the worker that drives
         # it to fold into the profiler: entries applied, those of them a
-        # run applied, and runs (one worker handles a node, so plain ints)
+        # run applied, runs, and config-change entries applied, accepted
+        # or not (one worker handles a node, so plain ints)
         self.applied_entries = 0
         self.applied_run_entries = 0
         self.applied_runs = 0
+        self.config_changes_applied = 0
         # Critical section for `sm.update + applied-index advance`, so a
         # snapshot can never capture an index older than the data it
         # saves (replay from it would apply the gap twice, a whole run
@@ -718,6 +720,7 @@ class StateMachineManager:
         with self._mu:
             accepted = self._members.handle_config_change(cc, e.index)
             self._set_applied(e.index, e.term)
+        self.config_changes_applied += 1
         if accepted:
             self._node.apply_config_change(cc)
         return accepted

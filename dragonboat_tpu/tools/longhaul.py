@@ -639,7 +639,17 @@ class _Round:
         nh = self.hosts.get(leader)
         if nh is not None and target != leader:
             nh.request_leader_transfer(CLUSTER, target)
+            # let the transfer settle before the next op: it completes
+            # when the target reads caught-up at an acknowledgement, or
+            # lapses at the leader's election timeout. An op that measures
+            # leader stability (the rejoin storm) otherwise books a
+            # transfer this op asked for as a disturbance.
+            deadline = time.monotonic() + 1.0
             time.sleep(0.2)
+            while time.monotonic() < deadline:
+                if _find_leader(self.hosts, deadline_s=0.1) == target:
+                    break
+                time.sleep(0.05)
 
     def _op_snapshot(self) -> None:
         leader = _find_leader(self.hosts, deadline_s=3.0)
