@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import os
 import random
+import threading
 import time
 
 import numpy as np
@@ -649,13 +650,18 @@ def _make_sm_cls():
     return SM
 
 
-def _bring_up(tmp_path, scope, k, members):
+def _bring_up(tmp_path, scope, k, members, sm_cls=None, **engine):
     from dragonboat_tpu.config import Config, EngineConfig, NodeHostConfig
     from dragonboat_tpu.nodehost import NodeHost
     from dragonboat_tpu.transport.loopback import loopback_factory, _Registry
 
     reg = _Registry()
-    sm_cls = _make_sm_cls()
+    sm_cls = sm_cls or _make_sm_cls()
+    shapes = dict(
+        max_groups=8, max_peers=4, log_window=64, inbox_depth=8,
+        max_entries_per_msg=8,
+    )
+    shapes.update(engine)
     hosts = {}
     for nid, addr in members.items():
         cfg = NodeHostConfig(
@@ -664,9 +670,7 @@ def _bring_up(tmp_path, scope, k, members):
             nodehost_dir=str(tmp_path / f"nh-{scope}-{nid}"),
             raft_rpc_factory=lambda a: loopback_factory(a, reg),
             engine=EngineConfig(
-                kind="vector", max_groups=8, max_peers=4, log_window=64,
-                inbox_depth=8, max_entries_per_msg=8, share_scope=scope,
-                steps_per_sync=k,
+                kind="vector", share_scope=scope, steps_per_sync=k, **shapes
             ),
         )
         hosts[nid] = NodeHost(cfg)
@@ -745,6 +749,183 @@ def test_multistep_engine_e2e(tmp_path):
 
         dc = diff_compiles(compile_mark, compile_watch().snapshot())
         assert not dc["per_function"], dc
+    finally:
+        for nh in hosts.values():
+            nh.stop()
+
+
+class _SaveOrder:
+    """What each replica's log holds durably, as a wrapped logdb saw it:
+    a command is `written` once a save has handed it to the store and
+    `durable` once the barrier that save owed has returned (one group,
+    so a save touches one shard of a host's logdb and owes one barrier)."""
+
+    def __init__(self, core):
+        self.core = core
+        self.mu = threading.Lock()
+        self.written = {}  # node id -> {cmd: launch that wrote it}
+        self.durable = {}  # node id -> {cmd: launch that wrote it}
+        self.done = []  # (batch id, replicas holding all of it durably)
+        self.batches = {}  # batch id -> its commands
+
+    def wrote(self, nid, updates):
+        launch = self.core.launch_no
+        with self.mu:
+            w = self.written.setdefault(nid, {})
+            for ud in updates:
+                for e in ud.entries_to_save:
+                    w[e.cmd] = launch
+
+    def synced(self, nid):
+        with self.mu:
+            self.durable.setdefault(nid, {}).update(
+                self.written.pop(nid, {})
+            )
+
+    def completed(self, bid):
+        with self.mu:
+            cmds = self.batches.get(bid)
+            if cmds is None:
+                return
+            self.done.append((bid, sum(
+                all(c in d for c in cmds) for d in self.durable.values()
+            )))
+
+
+class _SyncProxy:
+    def __init__(self, kv, order, nid):
+        self._kv, self._order, self._nid = kv, order, nid
+
+    def __getattr__(self, name):
+        return getattr(self._kv, name)
+
+    def sync(self):
+        self._kv.sync()
+        self._order.synced(self._nid)
+
+
+class _OrderLogDB:
+    """A NodeHost's logdb with both of its save doors recorded."""
+
+    def __init__(self, inner, order, nid):
+        self._inner, self._order, self._nid = inner, order, nid
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def save_raft_state(self, updates):
+        self._order.wrote(self._nid, updates)
+        self._inner.save_raft_state(updates)
+        self._order.synced(self._nid)
+
+    def save_raft_state_deferred(self, updates):
+        self._order.wrote(self._nid, updates)
+        kvs = self._inner.save_raft_state_deferred(updates)
+        if not kvs:  # nothing owed a barrier
+            self._order.synced(self._nid)
+        return [_SyncProxy(kv, self._order, self._nid) for kv in kvs]
+
+
+def _make_log_sm_cls():
+    from dragonboat_tpu.statemachine import IStateMachine, Result
+
+    class SM(IStateMachine):
+        def __init__(self, cluster_id, node_id):
+            self.cmds = []
+
+        def update(self, data):
+            self.cmds.append(bytes(data))
+            return Result(value=len(self.cmds))
+
+        def lookup(self, q):
+            return list(self.cmds)
+
+        def save_snapshot(self, w, fc, done):
+            raise NotImplementedError
+
+        def recover_from_snapshot(self, r, fc, done):
+            raise NotImplementedError
+
+        def close(self):
+            pass
+
+    return SM
+
+
+def test_k8_batch_is_acknowledged_in_one_launch_behind_its_save_wave(
+    tmp_path, monkeypatch
+):
+    """fleet-1024x3-k8's guarantee at the NodeHost level: three co-hosted
+    hosts at steps_per_sync 8, unsharded. Co-hosted replicas acknowledge
+    on the device before the host has written anything, so a batch of 64
+    commits inside the launch that packed it; "a majority has it durable"
+    holds at the client's boundary only because every replica's entries
+    are in that launch's one save wave and the wave returns before any
+    completion. Held here through a wrapped logdb: at every completion of
+    a batch all three replicas have the whole batch behind a returned
+    barrier, written by one and the same launch."""
+    from dragonboat_tpu.requests import BatchRequestState
+
+    members = {1: "ms8:1", 2: "ms8:2", 3: "ms8:3"}
+    hosts, lead = _bring_up(
+        tmp_path, "test-multistep8", 8, members, sm_cls=_make_log_sm_cls(),
+        log_window=256, inbox_depth=4, max_entries_per_msg=64,
+        profile_sample_ratio=1,
+    )
+    try:
+        core = hosts[1].engine.core
+        assert core._multi == 8 and core._mesh is None
+        core.request_sampler.ratio = 1  # stamp every batch's path
+        order = _SaveOrder(core)
+        for nid, nh in hosts.items():
+            node = nh._get_node(1)
+            node.logdb = _OrderLogDB(node.logdb, order, nid)
+        add_done = BatchRequestState.add_done
+
+        def recorded(self, completed=0, dropped=0):
+            if completed:
+                order.completed(self.batch_id)
+            add_done(self, completed, dropped)
+
+        monkeypatch.setattr(BatchRequestState, "add_done", recorded)
+        sess = hosts[lead].get_noop_session(1)
+        samples = core.profiler.samples
+
+        def seen(name):  # Σ of a count-and-sum sample
+            s = samples.get(name)
+            return s.mean() * len(s) if s is not None else 0.0
+
+        n0, l0 = seen("req.w.n"), seen("req.w.launches")
+        sent = []
+        for b in range(6):
+            cmds = [b"b%02d-%02d" % (b, i) for i in range(64)]
+            with order.mu:  # registered before a completion can look it up
+                h = hosts[lead].propose_batch_async(sess, cmds, 10)
+                order.batches[h.batch_id] = cmds
+            assert h.wait(10)
+            assert (h.completed, h.dropped) == (64, 0)
+            sent.extend(cmds)
+        # every completion found the whole batch durable on all three
+        assert len(order.done) >= 6
+        assert {n for _bid, n in order.done} == {3}, order.done
+        # and one launch wrote it on all three: the merged wave
+        for cmds in order.batches.values():
+            launches = {
+                d[c] for d in order.durable.values() for c in cmds
+            }
+            assert len(launches) == 1, launches
+        # pack to commit inside one launch, every batch
+        deadline = time.monotonic() + 5
+        while seen("req.w.n") - n0 < 6 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert seen("req.w.n") - n0 == 6
+        assert seen("req.w.launches") - l0 == 6
+        # read back from all three replicas
+        for nid, nh in hosts.items():
+            deadline = time.monotonic() + 10
+            while nh.stale_read(1, None) != sent:
+                assert time.monotonic() < deadline, nid
+                time.sleep(0.01)
     finally:
         for nh in hosts.values():
             nh.stop()
