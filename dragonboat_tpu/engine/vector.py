@@ -688,9 +688,7 @@ class _Lane:
         # because the engine's _m_term mirror is rebound from device state
         # every step (the device never saw the snapshot message).
         self.adopted_term = 0
-        # slot -> [next_to_send, goal, match_at_progress, progress_tick,
-        # progress_launch]
-        self.catchup: Dict[int, list] = {}
+        self.catchup: Dict[int, _Catchup] = {}  # slot -> host-log replay
         # snapshot-status feedback (cf. feedback.go:38-128): slot ->
         # (sent_tick, snapshot_index, sent_launch, sent_at: the host clock
         # of a sampled send, else 0.0); a peer that does not ack the
@@ -1086,19 +1084,41 @@ def build_save_updates(o: dict, base, lane_by_g):
     return updates, lane_saves
 
 
-# The per-peer recovery timers (catch-up stall, snapshot feedback retry)
+# The per-peer recovery timers (catch-up retry, snapshot feedback retry)
 # are counted in ticks, as the reference's (feedback.go:38-128), AND in
 # launches: a peer's acknowledgement cannot be back in fewer launches than
 # its round trip takes (replicate out, follower step, response in, leader
 # step), nor a restore's in fewer than the hand-offs it passes (pack, task
 # worker, snapshot worker, reconcile, acknowledgement, leader step). On a
 # loop whose launch outlasts the tick bound (a loaded fleet: 1-3 s a
-# launch, 2 s of ticks) the ticks alone declared every catching-up peer
-# lost before its first acknowledgement could return, and shipped it a
-# snapshot, and then another. Where launches are short the ticks decide,
-# as before.
+# launch, 1-2 s of ticks) the ticks alone declared every catching-up peer
+# silent before its first acknowledgement could return. Where launches
+# are short the ticks decide.
 _ACK_LAUNCHES = 4
 _RESTORE_LAUNCHES = 8
+
+
+class _Catchup:
+    """One peer served from its leader's host log (VectorEngine.
+    _run_catchups): `nxt` the next index to send, `goal` where device
+    replication takes over, `match` the peer's match when it last moved
+    and `tick`/`launch` when that was (or when the last retry went out),
+    `sent_hi` the highest index sent so far, `rewound` the launch of the
+    last rewind on a reject, `probing` once the peer has gone silent."""
+
+    __slots__ = ("nxt", "goal", "match", "tick", "launch", "sent_hi",
+                 "rewound", "probing")
+
+    def __init__(self, nxt: int, goal: int, match: int, tick: int,
+                 launch: int) -> None:
+        self.nxt = nxt
+        self.goal = goal
+        self.match = match
+        self.tick = tick
+        self.launch = launch
+        self.sent_hi = nxt - 1
+        self.rewound = 0
+        self.probing = False
 
 
 def _is_ack(m: Message) -> bool:
@@ -1282,6 +1302,11 @@ class VectorEngine:
             "leader_changes": 0,  # (leader, term) transitions observed
             "elections_started": 0,  # lanes that went leaderless
             "entries_applied": 0,  # entries handed to the RSM
+            # host-log catch-up of peers below the device window
+            "catchups_started": 0,
+            "catchup_entries": 0,  # entries sent from the host log
+            "replicate_resends": 0,  # Replicates below an index sent before
+            "snapshot_fallbacks": 0,  # peers handed to the snapshot path
             # multi-step engine: co-hosted messages routed ON DEVICE
             # between inner steps (zero host Message objects each)
             "msgs_routed_device": 0,
@@ -2638,6 +2663,12 @@ class VectorEngine:
             )
             return True
         if t == MT.REPLICATE_RESP:
+            if m.reject and from_slot in lane.catchup:
+                # the peer is served from the host log and the device has
+                # it parked (the kernel leaves a parked remote's next
+                # alone): its reject says where its log ends
+                self._rewind_catchup(lane, from_slot, m.hint)
+                return False
             if m.reject and m.hint < b and from_slot >= 0:
                 # the follower's log ends BELOW our device window: the
                 # kernel cannot back off past its own first_index, so a
@@ -3423,8 +3454,7 @@ class VectorEngine:
         goal = self._last_real(g)
         first, last = lane.node.log_reader.get_range()
         if start >= first and start <= last + 1:
-            lane.catchup[p] = [start, goal, m.hint, self.clock.tick, self.launch_no]
-            self._catchups.add(lane)
+            self._open_catchup(lane, p, start, goal, m.hint)
         else:
             self._send_snapshot(lane, p)
 
@@ -3459,18 +3489,48 @@ class VectorEngine:
         start = match + 1
         first, last = lane.node.log_reader.get_range()
         if start >= first and start <= last + 1:
-            # [next_to_send, goal, match_at_progress, progress_tick, _launch]
-            lane.catchup[p] = [start, goal, match, self.clock.tick, self.launch_no]
-            self._catchups.add(lane)
+            self._open_catchup(lane, p, start, goal, match)
         else:
             # the follower needs entries the host log no longer has
             # (compacted behind a snapshot): only a snapshot can help
             self._send_snapshot(lane, p)
 
+    def _count(self, name: str, n: int = 1) -> None:
+        """A replication counter: a plain int in step_stats() always, the
+        profiler's `n.<name>` on sampled iterations."""
+        self._sstats[name] += n
+        if self.profiler.sampling:
+            self.profiler.fold("n." + name, n)
+
+    def _open_catchup(
+        self, lane: _Lane, p: int, start: int, goal: int, match: int
+    ) -> None:
+        """Begin serving peer p from the host log, at index `start`."""
+        lane.catchup[p] = _Catchup(
+            start, goal, match, self.clock.tick, self.launch_no
+        )
+        self._catchups.add(lane)
+        self._count("catchups_started")
+
+    def _rewind_catchup(self, lane: _Lane, p: int, hint: int) -> None:
+        """A peer in catch-up refused a Replicate: one before it was lost,
+        and `hint` is where the peer's log ends. Go back there (never
+        below what it has acknowledged) and let the next sweep send again.
+        Everything sent behind the lost one is refused too, one reject a
+        message: those that arrive inside the round trip of the resend
+        rewind nothing."""
+        cu = lane.catchup[p]
+        back = max(cu.match + 1, hint + 1)
+        if back < cu.nxt and self.launch_no - cu.rewound >= 2:
+            cu.nxt = back
+            cu.rewound = self.launch_no
+            cu.probing = False  # it answers
+
     def _send_snapshot(self, lane: _Lane, p: int) -> None:
         to_nid = lane.rev.get(p)
         if to_nid is None:
             return
+        self._count("snapshot_fallbacks")
         ss = lane.node.snapshotter.get_most_recent_snapshot()
         if ss is None or ss.is_empty():
             ss = lane.node.log_reader.snapshot()
@@ -3519,54 +3579,68 @@ class VectorEngine:
         )
         self._snapfb.add(lane)
 
-    def _run_catchups(self, lane: _Lane, o) -> None:
+    def _run_catchups(self, lane: _Lane, o) -> int:
+        """One sweep over the peers this leader serves from its host log;
+        returns the entries it sent."""
         if not lane.catchup:
             self._catchups.discard(lane)
-            return
+            return 0
         g = lane.g
         b = int(self._m_base[g])
-        # a follower that stops acking for two election timeouts is treated
-        # as lost (the same silence bound the protocol uses to declare a
-        # leader dead) and falls back to the snapshot path
-        stall_ticks = max(2 * lane.cfg.election_rtt, 8)
+        # a peer whose match has not moved for an election timeout, and
+        # for the launches an acknowledgement's round trip takes, lost
+        # what was in flight (or its answer): go back to match + 1 and
+        # send again, as the reference's remote does from its retry state
+        # on a rejected or unanswered Replicate (remote.go:155-171), one
+        # message a retry until it answers. Only a host log that no longer
+        # has the entries hands the peer to the snapshot path; silence
+        # alone never does, so a deployment without snapshots recovers
+        # every loss from the log.
+        retry_ticks = max(lane.cfg.election_rtt, 4)
         now, launch = self.clock.tick, self.launch_no
+        W, E = self.kcfg.log_window, self.kcfg.max_entries_per_msg
+        sent = 0
         done = []
         for p, cu in lane.catchup.items():
-            nxt, goal, last_match, progress_tick, progress_launch = cu
             match = b + int(o["match"][g, p])
-            if match >= goal or int(self._m_role[g]) != ROLE.LEADER:
+            if match >= cu.goal or int(self._m_role[g]) != ROLE.LEADER:
                 done.append(p)
                 continue
-            if match > last_match:
-                cu[2], cu[3], cu[4] = match, now, launch
+            if match > cu.match:
+                cu.match, cu.tick, cu.launch = match, now, launch
+                cu.probing = False
             elif (
-                now - progress_tick > stall_ticks
-                and launch - progress_launch >= _ACK_LAUNCHES
+                now - cu.tick > retry_ticks
+                and launch - cu.launch >= _ACK_LAUNCHES
             ):
-                done.append(p)
-                self._send_snapshot(lane, p)
-                continue
-            if match + 1 > nxt:
-                nxt = match + 1
+                cu.nxt = match + 1
+                cu.tick, cu.launch = now, launch
+                cu.probing = True
+            elif cu.probing:
+                continue  # the probe is out: wait for its answer
+            nxt = max(cu.nxt, match + 1)
             to_nid = lane.rev.get(p)
             if to_nid is None:
                 done.append(p)
                 continue
             # half a window of entries a launch, in Replicates of
-            # max_entries_per_msg: the follower's pack takes what its own
-            # window has room for and keeps the rest in order. One message
-            # a launch left a joiner four batches behind five launches
-            # from its leader, on a loop whose launch is seconds long.
-            budget = max(self.kcfg.log_window // 2, 1)
-            while budget > 0:
+            # max_entries_per_msg, and never more than a window past what
+            # the peer has acknowledged: the follower's pack takes what
+            # its own window has room for and keeps the rest in order. One
+            # message a launch left a joiner four batches behind five
+            # launches from its leader, on a loop whose launch is seconds
+            # long.
+            budget = E if cu.probing else max(W // 2, 1)
+            while budget > 0 and nxt <= match + W:
                 first, last = lane.node.log_reader.get_range()
                 if nxt < first:
+                    # compacted behind a snapshot: only that can help
                     done.append(p)
                     self._send_snapshot(lane, p)
                     break
-                if nxt > last or nxt > goal:
+                if nxt > last or nxt > cu.goal:
                     break  # wait for the follower to ack what's in flight
-                hi = min(nxt + self.kcfg.max_entries_per_msg - 1, last, goal)
+                hi = min(nxt + E - 1, last, cu.goal)
                 try:
                     ents = lane.node.log_reader.entries(nxt, hi + 1, 1 << 20)
                     prev = nxt - 1
@@ -3581,6 +3655,9 @@ class VectorEngine:
                     done.append(p)
                     break
                 last_sent = ents[-1].index
+                if nxt <= cu.sent_hi:
+                    self._count("replicate_resends")
+                cu.sent_hi = max(cu.sent_hi, last_sent)
                 if p in lane.wit_slots:
                     # host catchup honors the witness shape too
                     ents = _make_metadata_entries(ents)
@@ -3598,11 +3675,14 @@ class VectorEngine:
                     )
                 )
                 budget -= len(ents)
-                nxt = cu[0] = last_sent + 1
+                sent += len(ents)
+                nxt = last_sent + 1
+            cu.nxt = nxt
         for p in done:
             lane.catchup.pop(p, None)
         if not lane.catchup:
             self._catchups.discard(lane)
+        return sent
 
     def _run_snapshot_feedback(self, lane: _Lane, o) -> None:
         """Delayed snapshot-status retry (cf. feedback.go:38-128): an
@@ -3661,8 +3741,20 @@ class VectorEngine:
     def _maintain(self, o) -> None:
         W = self.kcfg.log_window
         lane_by_g = self._lane_by_g
+        # the host-log catch-up sweep, timed on sampled iterations as the
+        # sub-span `catchup` of `maintain`
+        prof = self.profiler
+        if prof.sampling:
+            t0, c0 = time.monotonic(), time.thread_time()
+        sent = 0
         for lane in list(self._catchups):
-            self._run_catchups(lane, o)
+            sent += self._run_catchups(lane, o)
+        # (every sampled sweep folds its entries, none included: the
+        # readers tell a sweep that sent nothing from a program without it)
+        self._count("catchup_entries", sent)
+        if prof.sampling:
+            prof.add("catchup", time.monotonic() - t0)
+            prof.fold("catchup.cpu", time.thread_time() - c0)
         for lane in list(self._snapfb):
             self._run_snapshot_feedback(lane, o)
         # parked-peer watchdog: a remote in SNAPSHOT state whose host-side

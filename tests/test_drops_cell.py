@@ -1,0 +1,331 @@
+"""The drops cell's deployment at a small size (ISSUE 34): 8 groups x 5
+replicas, 128-byte commands, every follower losing one message in ten
+under the benchmark's own generator and hook, held after healing to the
+plain reference (all 128 bytes of every command counted on every replica)
+and, for the kernel's reject and back-off, to the scalar core; the hook's
+stream; and the fault the cell found in host-log catch-up: a catch-up
+Replicate that is lost must be sent again, not answered with a snapshot
+that a deployment without snapshots does not have."""
+import time
+import types
+
+import numpy as np
+import pytest
+
+from benchmark.lib import check, deploy, loadgen
+from benchmark.run import load_cell, load_plugin
+from dragonboat_tpu.ops.loopback import LoopbackCluster
+from dragonboat_tpu.ops.state import CTR
+from dragonboat_tpu.types import MessageType as MT
+from tests.test_differential import (
+    ELECTION, HEARTBEAT, N, ScalarCluster, kernel_observables, run_round,
+)
+
+CELL = "fleet1024x5.drops"
+GROUPS = 8
+drops = load_plugin("generators", "closed_loop_drops")
+kv128 = load_plugin("statemachines", "kv128")
+
+
+def _cell(**traffic_over):
+    _spec, _cell_, config, traffic = load_cell(CELL)
+    # run_bound_s 0: no process watchdog inside pytest
+    return config, {**traffic, "run_bound_s": 0, **traffic_over}
+
+
+# ---- the cell, small, against the plain reference --------------------------
+
+
+@pytest.mark.parametrize("kind", ["vector", "vector-overlap"])
+def test_followers_that_lose_one_message_in_ten_hold_the_reference(
+        kind, tmp_path):
+    config, traffic = _cell()
+    seed = 2147483734  # past 31 bits, as the driver's are
+    ledger = loadgen.Ledger(loadgen.Payloads(seed, GROUPS), GROUPS)
+    gen = drops.Generator(traffic, GROUPS, ledger, seed, 3.0, 1.0)
+    assert isinstance(ledger.payloads, drops.kv128.Payloads)
+    over = {"overlap_decode": True} if kind == "vector-overlap" else {}
+    cluster = deploy.Cluster(
+        config, GROUPS, kv128.StateMachine, str(tmp_path), over)
+    try:
+        assert cluster.core._overlap is (kind == "vector-overlap")
+        cluster.start()
+        cluster.wait_leaders(120.0)
+        gen.warm(cluster)
+        gen.measure(cluster, lambda t: None, lambda t: None)
+        got = gen.results()
+        assert cluster.core._local_drop_hook is None  # healed
+        # every replica's (applied, sum64 of all sixteen words) equals the
+        # ledger's, and linearizable reads on leader and follower hosts
+        checked = check.read_back(cluster, ledger, seed)
+        assert checked["groups_exact"] == GROUPS
+        for g in range(GROUPS):
+            want = ledger.expected(g)
+            assert want[0] >= 64 * 4  # 2 warm rounds and 2 under loss
+            for nh in cluster.hosts.values():
+                assert nh.stale_read(g + 1, None) == want
+        # nothing a client would feel
+        assert got["failed"] == 0 and got["attempted"] > 0
+        assert got["failed_batches"] == [] and got["slow_batches"] == []
+        assert got["term_changes_under_loss"] == []
+        assert got["program_under_loss"]["elections_started"] == 0
+        # the loss was offered, found by the followers, and repaired from
+        # the log alone
+        assert 0.07 < got["client.dropped_share"] < 0.13
+        assert got["messages_to_leaders_in_window"] > 0
+        assert got["program_in_window"]["replicate_rejects"] > 0
+        stats = cluster.core.step_stats()
+        assert stats["snapshot_fallbacks"] == 0
+        assert stats["loop_exceptions"] == 0
+        assert got["client.heal_to_converged_ms"] is not None
+        for nh in cluster.hosts.values():
+            for g in range(GROUPS):
+                assert nh._get_node(g + 1).snapshots_installed == 0
+    finally:
+        cluster.stop()
+
+
+# ---- the kernel's reject and back-off against the scalar core --------------
+
+
+def test_rejects_under_follower_drops_match_the_scalar_core():
+    """The differential harness (tests/test_differential.py: the kernel
+    and core/raft.py in lockstep) with the cell's hook deciding, a round
+    and a link, whether the leader's messages to that follower are lost:
+    every observable agrees after every round, and both sides refused
+    Replicates on the same replicas."""
+    kc = LoopbackCluster(
+        n_replicas=N, n_groups=1, election=ELECTION, heartbeat=HEARTBEAT
+    )
+    sc = ScalarCluster(seed_of_group=int(np.asarray(kc.states[0].seed)[0]))
+    hook = drops.DropHook(34, 1, N, 0.25)
+    for rnd in range(150):
+        lead = kc.leader_of(0)
+        links = set()
+        if lead is not None:
+            links = {
+                (lead, f) for f in range(N)
+                if f != lead and hook.decide(f, rnd)
+            }
+        kc.dropped_links = set(links)
+        sc.dropped_links = set(links)
+        run_round(kc, sc, proposals=2 if rnd >= 14 and rnd % 3 == 0 else 0)
+        ko, so = kernel_observables(kc), sc.observables()
+        assert ko == so, f"round {rnd}: kernel={ko} scalar={so}"
+    kc.dropped_links = set()
+    sc.dropped_links = set()
+    for rnd in range(4 * HEARTBEAT):
+        run_round(kc, sc)
+    ko, so = kernel_observables(kc), sc.observables()
+    assert ko == so
+    assert len({(o["committed"], o["last"]) for o in ko}) == 1  # healed
+    assert ko[0]["committed"] >= 60
+    # both cores refused Replicates on the same replicas (how many
+    # messages a leader sends to a peer it is probing differs between
+    # them, a Replicate a step against one a proposal, so the counts need
+    # not agree; the state after every round does)
+    rejects = [int(kc.counters[h][0][CTR.REPLICATE_REJECTS]) for h in range(N)]
+    scalar = [sc.rafts[h + 1].replicate_rejects for h in range(N)]
+    assert sum(rejects) > 0
+    assert [n > 0 for n in rejects] == [n > 0 for n in scalar]
+    for h in range(N):
+        hi = so[0]["committed"]
+        assert kc.ring_terms(h, 0, 1, hi) == sc.log_terms(h + 1, 1, hi)
+
+
+# ---- task 2: a catch-up that loses a message sends it again ----------------
+
+SMALL = {
+    "deployment": {"groups": 1, "replicas": 3},
+    "statemachine": "kv128",
+    "nodehost": {"rtt_millisecond": 5},
+    # no snapshots: a lagging member can only be served from the log
+    "raft": {"election_rtt": 40, "heartbeat_rtt": 4, "snapshot_entries": 0},
+    "engine": {"max_peers": 4, "log_window": 32, "inbox_depth": 8,
+               "max_entries_per_msg": 8},
+}
+
+
+@pytest.mark.parametrize("kind", ["vector", "vector-overlap"])
+def test_a_lost_catchup_replicate_is_sent_again_without_a_snapshot(
+        kind, tmp_path):
+    """A follower cut off until it is three device windows behind, in a
+    deployment without snapshots, whose first catch-up Replicate the hook
+    drops. The tree before PR 34 waited two election timeouts for an
+    acknowledgement that could not come, handed the peer to
+    _send_snapshot (`needs a snapshot but none exists`) and left it
+    behind until the feedback timer's retry; now the leader goes back to
+    match + 1 and the replica converges from the log."""
+    over = {"overlap_decode": True} if kind == "vector-overlap" else {}
+    cluster = deploy.Cluster(SMALL, 1, kv128.StateMachine, str(tmp_path), over)
+    payloads = kv128.Payloads(5, 1)
+    try:
+        cluster.start()
+        leader = cluster.wait_leaders(60.0)[0]
+        core = cluster.core
+        victim = next(n for n in cluster.hosts if n != leader)
+        nh = cluster.hosts[leader]
+        state = types.SimpleNamespace(cut=True, dropped=0)
+
+        def hook(m):
+            if m.to != victim:
+                return False
+            if state.cut:
+                return True  # everything: the follower falls behind
+            if (m.type == MT.REPLICATE and len(m.entries) > 0
+                    and m.log_index < 64 and state.dropped < 1):
+                state.dropped += 1  # the first catch-up Replicate
+                return True
+            return False
+
+        core.set_local_drop_hook(hook)
+        rows = 0
+        for _ in range(7):  # 112 entries: over three windows of 32
+            h = nh.propose_batch_async(
+                cluster.session(leader, 0),
+                payloads.cmds(0, rows, rows + 16), 10.0)
+            assert h.wait(20) and h.completed == 16  # the other two commit
+            rows += 16
+        assert cluster.hosts[victim].stale_read(1, None)[0] == 0
+        state.cut = False
+        want = (rows, payloads.sum64(0, rows))
+        assert nh.stale_read(1, None) == want
+        # well inside the two election timeouts (0.4 s) plus the feedback
+        # timer (0.8 s) that the snapshot fallback cost before
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline:
+            if cluster.hosts[victim].stale_read(1, None) == want:
+                break
+            time.sleep(0.02)
+        assert cluster.hosts[victim].stale_read(1, None) == want
+        stats = core.step_stats()
+        assert state.dropped == 1
+        assert stats["catchups_started"] >= 1
+        assert stats["replicate_resends"] >= 1
+        assert stats["snapshot_fallbacks"] == 0
+        assert cluster.hosts[victim]._get_node(1).snapshots_installed == 0
+        assert stats["loop_exceptions"] == 0
+    finally:
+        cluster.stop()
+
+
+# ---- the hook's stream ------------------------------------------------------
+
+
+def _msg(g, to, kind=MT.HEARTBEAT):
+    return types.SimpleNamespace(cluster_id=g + 1, to=to, type=kind)
+
+
+def _stream(seed, n=100_000, groups=16, replicas=5):
+    hook = drops.DropHook(seed, groups, replicas, 0.10)
+    hook.leaders = [g % replicas + 1 for g in range(groups)]
+    rng = np.random.default_rng(7)  # the same messages for every seed
+    gs = rng.integers(0, groups, n).tolist()
+    tos = rng.integers(1, replicas + 1, n).tolist()
+    kinds = (MT.REPLICATE, MT.HEARTBEAT, MT.REQUEST_VOTE, MT.REQUEST_PREVOTE,
+             MT.TIMEOUT_NOW, MT.REPLICATE_RESP)
+    out = []
+    for i, (g, to) in enumerate(zip(gs, tos)):
+        out.append(hook(_msg(g, to, kinds[i % len(kinds)])))
+    return hook, gs, tos, out
+
+
+def test_the_hook_drops_one_in_ten_and_never_to_a_leader():
+    hook, gs, tos, out = _stream(2147483734)
+    to_leader = [to == hook.leaders[g] for g, to in zip(gs, tos)]
+    assert not any(d for d, lead in zip(out, to_leader) if lead)
+    assert hook.to_leader == sum(to_leader)
+    assert hook.seen == len(out) - hook.to_leader
+    assert hook.dropped == sum(out)
+    assert 0.09 < hook.dropped / hook.seen < 0.11
+    # whatever its type
+    per_type = hook.dropped_by_type
+    assert set(per_type) == {"REPLICATE", "HEARTBEAT", "REQUEST_VOTE",
+                             "REQUEST_PREVOTE", "TIMEOUT_NOW",
+                             "REPLICATE_RESP"}
+    assert all(0.07 < 6 * n / hook.seen < 0.13 for n in per_type.values())
+
+
+def test_the_same_seed_decides_the_same_losses():
+    _h, _g, _t, first = _stream(11)
+    _h, _g, _t, again = _stream(11)
+    _h, _g, _t, other = _stream(12)
+    assert first == again
+    assert first != other
+
+
+def test_a_links_losses_do_not_depend_on_the_other_links():
+    """The decision is a hash of (seed, group, receiving replica, that
+    link's own ordinal): interleaving another link's messages changes
+    nothing."""
+    a = drops.DropHook(5, 4, 5, 0.10)
+    b = drops.DropHook(5, 4, 5, 0.10)
+    alone = [a(_msg(2, 3)) for _ in range(2000)]
+    mixed = []
+    for _ in range(2000):
+        b(_msg(1, 4))
+        mixed.append(b(_msg(2, 3)))
+    assert alone == mixed
+    assert alone == [a.decide(2 * 5 + 2, n) for n in range(2000)]
+
+
+# ---- the plain reference rejects what it exists to reject -------------------
+
+
+@pytest.mark.parametrize("fault", [
+    "none", "byte_120_replaced", "command_applied_twice", "command_lost",
+])
+def test_kv128_counts_every_byte(fault):
+    payloads = kv128.Payloads(9, 2)
+    cmds = payloads.cmds(1, 0, 70)
+    assert all(len(c) == 128 for c in cmds)
+    assert [c[:8] for c in cmds] == [k.to_bytes(8, "little") for k in range(70)]
+    assert len({c[16:] for c in cmds}) == 70  # the 112 bytes are seeded
+    assert payloads.cmds(0, 0, 1) != payloads.cmds(1, 0, 1)
+    if fault == "byte_120_replaced":
+        cmds[5] = cmds[5][:120] + bytes([cmds[5][120] ^ 1]) + cmds[5][121:]
+    elif fault == "command_applied_twice":
+        cmds.insert(6, cmds[5])
+    elif fault == "command_lost":
+        del cmds[5]
+    sm = kv128.StateMachine(1, 1)
+    sm.update([types.SimpleNamespace(cmd=c, result=None) for c in cmds])
+    ledger = loadgen.Ledger(payloads, 2)
+    lo, hi, _ = ledger.take(1, 70)
+    ledger.settle(1, lo, hi, 70, 0)
+    if fault == "none":
+        ledger.check(1, "a sound replica", sm.lookup(None))
+        assert sm.lookup((5).to_bytes(8, "little")) == cmds[5][8:16]
+    else:
+        with pytest.raises(loadgen.CheckFailure):
+            ledger.check(1, "a replica", sm.lookup(None))
+
+
+# ---- the generator's accounting on made-up batches -------------------------
+
+
+def test_a_batch_counts_by_the_share_of_its_life_inside_the_window():
+    _config, traffic = _cell()
+    ledger = loadgen.Ledger(loadgen.Payloads(1, 3), 3)
+    gen = drops.Generator(traffic, 3, ledger, 1, 10.0, 1.0)
+    gen.hook = drops.DropHook(1, 3, 5, 0.10)
+    gen.t_open, gen.t_close = 10.0, 20.0
+    zero = {"replicate_rejects": 0, "elections_started": 0, "elections_won": 0}
+    gen._at = {"open": ((0, 0, 0), dict(zero)),
+               "close": ((1000, 100, 500), {**zero, "replicate_rejects": 40}),
+               "loss": dict(zero), "end": dict(zero)}
+    gen.batches = [
+        (0, 11.0, 14.0, 64, 0), (0, 14.0, 18.0, 64, 0),
+        (0, 18.0, 22.0, 64, 0),  # submitted inside, looked after: no cycle
+        (1, 8.0, 15.0, 64, 0), (1, 15.0, 26.0, 0, 64),  # expired
+        (2, 12.0, 19.0, 64, 0),
+    ]
+    got = gen.results()
+    assert got["stalled_groups"] == 1
+    assert got["cycles"] == 3
+    assert got["whole_cycle_ops_per_s"] == pytest.approx(128 / 7.0 + 64 / 7.0)
+    work = 64 + 64 + 64 * 2 / 4 + 64 * 5 / 7 + 0 + 64
+    assert got["committed_ops_per_s"] == pytest.approx(work / 10.0)
+    assert (got["attempted"], got["failed"]) == (64 * 5, 64)
+    assert got["client.dropped_share"] == pytest.approx(0.1)
+    assert got["program_in_window"]["replicate_rejects"] == 40
