@@ -148,24 +148,32 @@ class EngineConfig:
     # ring-slot scatter is O(G*W) regardless of this value, so raising it
     # widens per-step ingestion at the cost of inbox transfer size only.
     max_entries_per_msg: int = 8
-    # Device-resident multi-step: K protocol steps per kernel launch.
-    # At K=1 (default) the engine runs the classic one-step loop,
-    # bit-identical to every release before the knob existed. At K>1 the
-    # step body runs under a lax.scan and co-hosted replica traffic
-    # (Replicate/acks/heartbeats/votes between lanes of one shared core)
-    # is routed ON DEVICE between inner steps — zero host Message objects
-    # for shared-core traffic — while host-only work (WAL save, SM apply,
-    # client notify, cross-host sends) accumulates in per-step output
-    # slots and drains once per super-step: one kernel dispatch + ONE
-    # _fetch_output device sync per K protocol steps, and one merged
-    # fsync barrier per window. Trade-off: host events (proposals,
-    # reads, ticks) enter only at super-step boundaries, so client
-    # completion latency grows with K while dispatch/fetch host wall
-    # shrinks by ~K. K must be a static int (it is compiled into the
-    # scan length). Composes with shard_over_mesh: the sharded K-step
-    # kernel routes cross-shard lane traffic device-to-device between
-    # inner steps and stays bit-identical to the unsharded reference.
-    steps_per_sync: int = 1
+    # Protocol steps per kernel launch (K). At K=1 the engine runs the
+    # one-step loop: every message between replicas becomes a host Message
+    # and rides the next launch. At K>1 the step body runs under a
+    # lax.scan and co-hosted replica traffic (Replicate/acks/heartbeats/
+    # votes between lanes of one shared core) is routed ON DEVICE between
+    # inner steps, while host-only work (WAL save, SM apply, client
+    # notify, cross-host sends) accumulates in per-step output slots and
+    # drains once a launch: one dispatch, one fetch and ONE merged save
+    # wave, fsyncs included, before anything of that launch is sent as a
+    # response, applied or notified.
+    # None = auto: the engine holds both programs and chooses at every
+    # launch boundary from what its route table says. K=3 (a commit is
+    # leader append, follower append and acknowledge, leader commit:
+    # three protocol steps, PERF.md section 5) while every peer slot of
+    # every active lane is routable on the device: co-hosted on this
+    # core, not a witness, its host not partitioned, no lane under
+    # restore, no chaos hook. K=1 otherwise. A switch down first drains
+    # what the last K=3 launch left parked on the device. The K=3
+    # program is built when the first routable peer appears (an engine
+    # without one never builds it) and compiled by its first launch,
+    # during bring-up. With shard_over_mesh, None stays at K=1.
+    # An integer forces exactly that K for the engine's life (it is the
+    # scan length compiled into the program); with shard_over_mesh the
+    # sharded K-step kernel routes cross-shard lane traffic device-to-
+    # device and stays bit-identical to the unsharded reference.
+    steps_per_sync: "Optional[int]" = None
     # Hide the kernel behind host work (K=1): dispatch kernel step t, run
     # step t-1's maintenance (window compaction, snapshot triggers,
     # catch-up of parked peers) while the device computes, and fetch and
@@ -178,8 +186,9 @@ class EngineConfig:
     # late only means a little less room). On the cpu backend the "wait"
     # is the host computing the kernel, so there is nothing to hide.
     # None = auto: on for accelerators, off for cpu. False on the chip
-    # shows the kernel on every step (PERF.md, PR 25). Ignored at
-    # steps_per_sync > 1.
+    # shows the kernel on every step (PERF.md, PR 25). Ignored by every
+    # launch of more than one step, whether steps_per_sync set it or the
+    # engine chose it: the next pack needs that launch's fetch.
     overlap_decode: "Optional[bool]" = None
     # Stage-profiler sampling for the vector engine hot loop: 0 = sparse
     # default (1 in 32 iterations — steady-state cost is two clock reads

@@ -1034,15 +1034,24 @@ def gather_resp_sends(
     return sends
 
 
-def build_save_updates(o: dict, base, lane_by_g):
+def build_save_updates(o: dict, base, lane_by_g, commit_cap=None, owed=None):
     """Phase-2 hard-state/entry persistence as (updates, lane_saves): the
     whole step's saves gathered columnar, written downstream as ONE
-    multi-group write wave."""
+    multi-group write wave. `commit_cap` (device units a lane) bounds
+    the commit index a hard state carries; lanes under `owed` get their
+    hard state written whether or not this step changed it (see
+    VectorEngine._decode_super for both)."""
     updates: List[Update] = []
     lane_saves: List[Tuple[_Lane, List[Entry], State]] = []
-    gs = np.nonzero((o["save_from"] > 0) | o["hard_changed"])[0]
+    changed = o["hard_changed"]
+    if owed is not None:
+        changed = changed | owed
+    gs = np.nonzero((o["save_from"] > 0) | changed)[0]
     if not gs.size:
         return updates, lane_saves
+    commits = o["commit_index"][gs]
+    if commit_cap is not None:
+        commits = np.minimum(commits, commit_cap[gs])
     cols = zip(
         gs.tolist(),
         base[gs].tolist(),
@@ -1050,8 +1059,8 @@ def build_save_updates(o: dict, base, lane_by_g):
         o["save_to"][gs].tolist(),
         o["vote"][gs].tolist(),
         o["term"][gs].tolist(),
-        o["commit_index"][gs].tolist(),
-        o["hard_changed"][gs].tolist(),
+        commits.tolist(),
+        changed[gs].tolist(),
     )
     for g, b, sf, st_, vote_slot, term, commit, hard_changed in cols:
         lane = lane_by_g[g]
@@ -1096,6 +1105,21 @@ def build_save_updates(o: dict, base, lane_by_g):
 # are short the ticks decide.
 _ACK_LAUNCHES = 4
 _RESTORE_LAUNCHES = 8
+
+# What the engine runs a launch when it chooses for itself
+# (EngineConfig.steps_per_sync None) and every peer is routable on the
+# device: the protocol steps a commit takes, leader append, follower
+# append and acknowledge, leader commit (PERF.md section 5). A step past
+# the third carries heartbeats at most and costs a router pass. Not a
+# knob.
+_AUTO_STEPS = 3
+
+
+def _steps_option(ecfg) -> Optional[int]:
+    """EngineConfig.steps_per_sync as the engine reads it: None = the
+    engine chooses, else a launch's protocol steps (at least one)."""
+    k = getattr(ecfg, "steps_per_sync", None) if ecfg is not None else None
+    return None if k is None else max(1, int(k))
 
 
 class _Catchup:
@@ -1237,24 +1261,24 @@ class VectorEngine:
         self._groups_requested = groups_requested
         self._padded_groups = self.kcfg.groups - groups_requested
         self.clock = _SharedClock()
-        # device-resident multi-step: K protocol steps per kernel launch
-        # (EngineConfig.steps_per_sync). K=1 keeps the classic one-step
-        # loop byte-identical; K>1 runs the scanned super-step path.
-        self._multi = (
-            max(1, int(getattr(ecfg, "steps_per_sync", 1) or 1))
-            if ecfg
-            else 1
-        )
+        # protocol steps of the next launch (EngineConfig.steps_per_sync):
+        # an integer is that for the engine's life; None lets the engine
+        # move between 1 and _AUTO_STEPS at launch boundaries, by what
+        # _rebuild_routes observes. K=1 runs the one-step loop, K>1 the
+        # scanned super-step path. A mesh without an integer stays at 1.
+        self._steps_cfg = _steps_option(ecfg)
+        self._auto = self._steps_cfg is None and self._mesh is None
+        self._multi = self._steps_cfg or 1
         ov = getattr(ecfg, "overlap_decode", None) if ecfg else None
         if ov is None:
             ov = jax.default_backend() != "cpu"  # auto: see EngineConfig
-        if self._multi > 1:
-            # the super-step IS the pipelining: dispatch/fetch amortize
-            # over K steps, and the pack path needs the PREVIOUS fetch's
-            # residual-inbox occupancy (overlap would make it two steps
-            # stale and clobber device-routed residual rows)
-            ov = False
-        self._overlap = bool(ov)
+        # what the K=1 program runs; a launch of more steps is its own
+        # pipelining (dispatch and fetch amortize over K steps), and the
+        # pack behind it needs ITS fetch's residual-inbox occupancy: a
+        # step in flight would make that two steps stale and clobber
+        # device-routed residual rows
+        self._overlap_k1 = bool(ov)
+        self._overlap = self._overlap_k1 and self._multi == 1
         self._pending = None  # in-flight (work, packs, StepOutput future)
         self._rebase_due = False
         # stage profiler for the hot loop (cf. reference execengine.go
@@ -1373,32 +1397,20 @@ class VectorEngine:
         self._m_resid = np.zeros(G, np.int32)
         self._pending_rep_copies: list = []
         self._routes_dirty = True
+        # auto: does the route table route every peer slot of every
+        # active lane (and at least one)? Set by _rebuild_routes.
+        self._all_routable = False
+        # lanes whose persisted commit the last merged save wave held
+        # below the device's (see _decode_super): the next wave owes
+        # them a hard state
+        self._m_commit_owed = np.zeros(G, bool)
+        self._multi_fn = None
+        self._resid = None
+        self.census = None  # made below, once the planes exist
+        self._np_route = np.full((G, self.kcfg.peers), -1, np.int32)
+        self._np_rdelta = np.zeros((G, self.kcfg.peers), np.int32)
         if self._multi > 1:
-            if self._mesh is not None:
-                # K-step kernel over the mesh: cross-shard lane traffic
-                # moves device-to-device inside the launch (all-gather);
-                # the host path stays the fallback for lanes the route
-                # table marks -1
-                self._multi_fn = make_sharded_multi_step_fn(
-                    self.kcfg, self._multi, self._mesh
-                )
-                name = f"multi_step[g{G}.k{self._multi}.d{self._mesh_devices}]"
-            else:
-                self._multi_fn = make_multi_step_fn(self.kcfg, self._multi)
-                name = f"multi_step[g{G}.k{self._multi}]"
-            # no comma in the name: it becomes a Prometheus label value
-            compile_watch().register(name, self._multi_fn)
-            self._np_route = np.full((G, self.kcfg.peers), -1, np.int32)
-            self._np_rdelta = np.zeros((G, self.kcfg.peers), np.int32)
-            resid = make_empty_inbox(self.kcfg)
-            if self._sharding is not None:
-                # the residual inbox must live on the mesh like the rest
-                # of the lane state, or every launch would reshard it
-                self._resid = jax.device_put(
-                    resid, jax.tree_util.tree_map(self._sharding, resid)
-                )
-            else:
-                self._resid = jax.device_put(resid)
+            self._build_multi(self._multi)
         self._state: RaftTensors = init_state(self.kcfg)
         if self._sharding is not None:
             self._state = jax.tree.map(
@@ -1465,29 +1477,13 @@ class VectorEngine:
         self._alloc_buffers()
         self._alloc_mirrors()
         # HBM census (profile.DeviceCensus): plane bytes are STATIC
-        # tensor metadata (shapes never change over the engine's life),
-        # reported once here from `.nbytes` — device_census() later folds
+        # tensor metadata (shapes never change over the engine's life;
+        # the residual inbox joins them when auto first builds it),
+        # reported from `.nbytes` — device_census() later folds
         # the logical log fill from the decode-maintained mirrors, so
         # reading the census costs zero device syncs at any point
         self.census = DeviceCensus()
-        planes = {
-            f"state.{name}": int(arr.nbytes)
-            for name, arr in self._state._asdict().items()
-        }
-        if self._multi > 1:
-            for name, arr in self._resid._asdict().items():
-                planes[f"resid.{name}"] = int(arr.nbytes)
-        staging = sum(
-            int(plane.nbytes)
-            for plane in list(self._buf.values()) + [self._ticks]
-        )
-        self.census.set_planes(
-            planes,
-            log_planes=("state.log_term", "state.log_is_cc"),
-            devices=max(1, self._mesh_devices),
-            log_window=self.kcfg.log_window,
-            host_staging_bytes=staging,
-        )
+        self._report_planes()
         # worker pools for apply + snapshot work (same split as ExecEngine)
         self._n_task = num_task_workers or min(
             soft.step_engine_task_worker_count, 4
@@ -1513,6 +1509,61 @@ class VectorEngine:
             )
             t.start()
             self._threads.append(t)
+
+    def _build_multi(self, steps: int) -> None:
+        """The K-step program and the residual inbox it carries from one
+        launch to the next. An integer steps_per_sync builds them with
+        the engine; auto at the first route rebuild that finds a peer to
+        route, so an engine whose peers are all elsewhere never does, and
+        a co-hosted deployment traces and compiles the program at its
+        first all-routable launch, during bring-up."""
+        G = self.kcfg.groups
+        if self._mesh is not None:
+            # K-step kernel over the mesh: cross-shard lane traffic
+            # moves device-to-device inside the launch (all-gather);
+            # the host path stays the fallback for lanes the route
+            # table marks -1
+            self._multi_fn = make_sharded_multi_step_fn(
+                self.kcfg, steps, self._mesh
+            )
+            name = f"multi_step[g{G}.k{steps}.d{self._mesh_devices}]"
+        else:
+            self._multi_fn = make_multi_step_fn(self.kcfg, steps)
+            name = f"multi_step[g{G}.k{steps}]"
+        # no comma in the name: it becomes a Prometheus label value
+        compile_watch().register(name, self._multi_fn)
+        resid = make_empty_inbox(self.kcfg)
+        if self._sharding is not None:
+            # the residual inbox must live on the mesh like the rest
+            # of the lane state, or every launch would reshard it
+            self._resid = jax.device_put(
+                resid, jax.tree_util.tree_map(self._sharding, resid)
+            )
+        else:
+            self._resid = jax.device_put(resid)
+        if self.census is not None:
+            self._report_planes()  # built after the engine: auto
+
+    def _report_planes(self) -> None:
+        """The device planes this engine holds, to its HBM census."""
+        planes = {
+            f"state.{name}": int(arr.nbytes)
+            for name, arr in self._state._asdict().items()
+        }
+        if self._resid is not None:
+            for name, arr in self._resid._asdict().items():
+                planes[f"resid.{name}"] = int(arr.nbytes)
+        staging = sum(
+            int(plane.nbytes)
+            for plane in list(self._buf.values()) + [self._ticks]
+        )
+        self.census.set_planes(
+            planes,
+            log_planes=("state.log_term", "state.log_is_cc"),
+            devices=max(1, self._mesh_devices),
+            log_window=self.kcfg.log_window,
+            host_staging_bytes=staging,
+        )
 
     def _alloc_buffers(self) -> None:
         # numpy staging buffers for the inbox. ONE set in every loop: a
@@ -1921,8 +1972,28 @@ class VectorEngine:
             # empty ~every step, bounded by in-flight snapshot workers
             with node._mu:
                 node._process_snapshot_status()
-        if self._multi > 1 and self._routes_dirty:
+        if self._routes_dirty and (self._auto or self._multi > 1):
             self._rebuild_routes()
+        if self._auto:
+            # the engine's own choice, at the launch boundary: the whole
+            # commit in one launch while the device can carry every
+            # message of it, and once more after that to take in what
+            # the last such launch left parked there (the table routes
+            # nothing by then, so that launch parks nothing new)
+            steps = (
+                _AUTO_STEPS
+                if self._all_routable or self._m_resid.any()
+                else 1
+            )
+            if steps != self._multi:
+                if owed is not None:
+                    # up: nothing in flight and the mirrors current
+                    # before a pack that reads residual occupancy
+                    self._decode_maintain(owed)
+                    owed = None
+                    prof.begin("prepare")
+                self._multi = steps
+                self._overlap = self._overlap_k1 and steps == 1
         with self._dirty_mu:
             dirty = self._dirty
             self._dirty = set()
@@ -2009,6 +2080,9 @@ class VectorEngine:
         # returns futures) are timed apart, as sub-spans of dispatch.
         self.launch_no += 1
         sampling = prof.sampling
+        if sampling:
+            prof.fold("n.launches", 1)
+            prof.fold("n.launch_steps", self._multi)
         t0 = time.monotonic() if sampling else 0.0
         if self._multi > 1:
             # K protocol steps per launch: the route/delta planes ride
@@ -2786,8 +2860,15 @@ class VectorEngine:
         self._decode_send_rep(o)
         # ---- phase 2: one batched fsynced write for every lane -----------
         prof.begin("save")
+        owed = self._m_commit_owed
+        if owed.any():
+            # the first one-step launch after a switch down: the last
+            # merged wave's held-back commit indexes (_decode_super)
+            self._m_commit_owed = np.zeros_like(owed)
+        else:
+            owed = None
         updates, lane_saves = build_save_updates(
-            o, self._m_base, self._lane_by_g
+            o, self._m_base, self._lane_by_g, owed=owed
         )
         self._commit_saves(updates, lane_saves)
         # ---- phase 3: post-fsync sends (votes, responses, heartbeats) ----
@@ -2821,11 +2902,25 @@ class VectorEngine:
             so responses of EVERY inner step leave only after the
             window's final — maximal — hard state is durable (the
             persist-before-ack invariant holds against a state at least
-            as new as what each response reflects);
+            as new as what each response reflects). One thing the wave
+            must not write: co-hosted followers acknowledge on the
+            device before the host has written their entries, so a
+            leader's commit index of THIS launch may cover entries that
+            only this same wave makes durable, on other hosts' stores.
+            A crash that tears the wave (one store written, another
+            not) would leave a replica with a persisted commit above
+            what a quorum holds, and its restart would apply entries a
+            new leader may overwrite. So a hard state of this wave
+            carries a commit no higher than the lane's at the end of
+            the last launch, whose wave has returned; the lanes held
+            back are owed their hard state by the next wave
+            (_m_commit_owed), which by then is safe to write in full.
+            Nothing is applied or acknowledged before the wave returns,
+            so only what a restart replays by itself is a launch late;
           * post-fsync sends, RSM apply and confirmed reads then run per
             inner step in order.
         """
-        K = self._multi
+        K = len(o["term"])  # the launch's own step count
         prof = self.profiler
         prof.begin("place")
         steps = []
@@ -2838,6 +2933,7 @@ class VectorEngine:
         st = self._sstats
         base = self._m_base
         lane_by_g = self._lane_by_g
+        safe_commit = self._m_commit  # the last launch's, before the refresh
         # ---- place + phase 1, per inner step in order --------------------
         for t, (ot, plt) in enumerate(steps):
             prof.begin("place")
@@ -2858,10 +2954,17 @@ class VectorEngine:
         prof.begin("save")
         updates: List[Update] = []
         lane_saves: List[Tuple[_Lane, List[Entry], State]] = []
+        owed = self._m_commit_owed if self._m_commit_owed.any() else None
         for ot, _plt in steps:
-            u, ls = build_save_updates(ot, base, lane_by_g)
+            u, ls = build_save_updates(
+                ot, base, lane_by_g, commit_cap=safe_commit,
+                owed=owed if ot is self.last_output else None,
+            )
             updates.extend(u)
             lane_saves.extend(ls)
+        self._m_commit_owed = (
+            self.last_output["commit_index"] > safe_commit
+        ) & self._m_active
         self._commit_saves(updates, lane_saves)
         # ---- phases 3-5 per inner step in order --------------------------
         prof.begin("send_resp")
@@ -2879,17 +2982,22 @@ class VectorEngine:
 
     # ------------------------------------------------ multi-step routing
     def _rebuild_routes(self) -> None:
-        """Recompute the on-device routing table (multi-step engine):
-        for every active lane and peer slot, the co-hosted destination
-        lane index and the window-base delta the kernel adds to
-        index-valued fields. Conservative by construction — any
-        condition the host delivery path special-cases (chaos drop
-        hook, partitioned host, stopped node, in-flight snapshot
-        restore, unknown peer) routes -1, so that traffic falls back to
-        the host path and its exact semantics."""
+        """Recompute the on-device routing table: for every active lane
+        and peer slot, the co-hosted destination lane index and the
+        window-base delta the kernel adds to index-valued fields.
+        Conservative by construction — any condition the host delivery
+        path special-cases (chaos drop hook, partitioned host, stopped
+        node, in-flight snapshot restore, witness, unknown peer, a peer
+        that numbers the slots differently) routes -1, so that traffic
+        falls back to the host path and its exact semantics.
+
+        Where the engine chooses its own steps a launch (auto) this is
+        also what it chooses from: _all_routable says that the table
+        routes every peer slot of every lane it looked at, and at least
+        one. Short of that the table is left routing nothing, so a K-step
+        launch that only drains the residual inbox parks nothing new."""
         self._routes_dirty = False
-        if self._multi <= 1:
-            return
+        self._all_routable = False
         route = self._np_route
         rdelta = self._np_rdelta
         route.fill(-1)
@@ -2902,33 +3010,51 @@ class VectorEngine:
         with self._lanes_mu:
             lanes = list(self._lanes.values())
             rt = dict(self._route)
+        routed = unrouted = 0
         for lane in lanes:
             if not lane.active or lane.node.stopped:
                 continue
-            if lane.key[0] in blocked:
-                continue  # partitioned host: neither sends nor receives
             g = lane.g
             self_slot = lane.self_slot()
+            # partitioned host: neither sends nor receives
+            cut = lane.key[0] in blocked
             for p, nid in lane.rev.items():
                 if p == self_slot or p < 0 or p >= P:
                     continue
-                if p in lane.wit_slots:
-                    # witness peers stay on the host path: its senders
-                    # strip payloads to METADATA (the zero-payload
-                    # witness contract); the device route would copy
-                    # full entries into the witness arena
-                    continue
-                dst = rt.get((lane.node.cluster_id, nid))
+                # witness peers stay on the host path: its senders strip
+                # payloads to METADATA (the zero-payload witness
+                # contract); the device route would copy full entries
+                # into the witness arena
+                dst = (
+                    None if cut or p in lane.wit_slots
+                    else rt.get((lane.node.cluster_id, nid))
+                )
                 if (
                     dst is None
                     or not dst.active
                     or dst.recovering
                     or dst.node.stopped
                     or dst.key[0] in blocked
+                    # a routed message names its sender by the SENDER's
+                    # slot numbering (rank among the members it has
+                    # applied); between one replica's apply of a config
+                    # change and the other's the two number differently,
+                    # and only the host path translates through node ids
+                    or dst.slots != lane.slots
                 ):
+                    unrouted += 1
                     continue
+                routed += 1
                 route[g, p] = dst.g
                 rdelta[g, p] = int(base[g] - base[dst.g])
+        if not self._auto:
+            return
+        if routed and self._multi_fn is None:
+            self._build_multi(_AUTO_STEPS)
+        self._all_routable = routed > 0 and unrouted == 0
+        if not self._all_routable:
+            route.fill(-1)
+            rdelta.fill(0)
 
     def _routed_rep_plan(self, o: dict, plan: dict) -> list:
         """Replay the kernel's deterministic inbox-slot assignment for
@@ -3869,7 +3995,7 @@ class VectorEngine:
             # window bases moved: the routing table's per-peer base
             # deltas must be recomputed before the next dispatch
             self._routes_dirty = True
-            if self._multi > 1 and self._m_resid.any():
+            if self._m_resid.any():
                 # the device-resident residual inbox carries indexes in
                 # DESTINATION units: shift the index-valued fields of
                 # each parked message by its destination lane's delta
@@ -3966,12 +4092,38 @@ class VectorEngine:
             self._patch = v
         return v
 
+    def _drop_parked(self, lane: _Lane, gone: bool = False) -> None:
+        """Forget what the last K-step launch left parked on the device
+        for `lane`: its rows of the residual inbox and the payload copies
+        that wait on their acceptance; of a lane that is `gone` also the
+        copies it was to be the source of. Lost messages to Raft, which
+        resends."""
+        g = lane.g
+        self._m_resid[g] = 0
+        if self._resid is not None:
+            r = self._resid
+            self._resid = r._replace(
+                mtype=r.mtype.at[g].set(jnp.int32(MSG.NONE))
+            )
+            self._pending_rep_copies = [
+                c
+                for c in self._pending_rep_copies
+                if c[3] is not lane and not (gone and c[2] is lane)
+            ]
+
     def _stage_remap(self, lane: _Lane, perm: Dict[int, int], mem) -> dict:
         """Stage lane's re-ranked slots (perm: old slot -> new slot, from
         _Lane.set_slots) and its membership flags; returns the staged
         patch for the caller's own fields. A lane patched twice in one
         iteration takes two calls: the second re-ranks the first's result."""
         g = lane.g
+        if self._m_resid[g]:
+            # a parked row names its sender by the slot it had when the
+            # row was routed; under the new numbering that slot may be
+            # another member's (an acknowledgement booked to a joiner
+            # that holds nothing), and only the host path carries node
+            # ids to translate by
+            self._drop_parked(lane)
         if self._patch is not None and self._patch["remap"][g]:
             self._flush_patch()
         v = self._staged_patch()
@@ -4327,6 +4479,7 @@ class VectorEngine:
         self._m_host[g] = 0
         self._m_leader_change_tick[g] = 0
         self._m_recovering[g] = False
+        self._m_commit_owed[g] = False
         self._ctr_left += self._ctr[g]
         self._ctr[g] = 0
         self._carry.discard(lane)
@@ -4334,17 +4487,7 @@ class VectorEngine:
         self._snapfb.discard(lane)
         # multi-step: the freed lane must not hand its device-routed
         # residual rows or pending payload copies to the next tenant
-        self._m_resid[g] = 0
-        if self._multi > 1:
-            r = self._resid
-            self._resid = r._replace(
-                mtype=r.mtype.at[g].set(jnp.int32(MSG.NONE))
-            )
-            self._pending_rep_copies = [
-                c
-                for c in self._pending_rep_copies
-                if c[2] is not lane and c[3] is not lane
-            ]
+        self._drop_parked(lane, gone=True)
         self._routes_dirty = True
         lane.node._vec_lane = None
         with self._lanes_mu:
@@ -4620,8 +4763,13 @@ class VectorEngine:
         messages by plane, lanes with commit advance, elections started,
         entries handed to the RSM) — derived host-side from the decoded
         StepOutput, so reading them costs nothing on the device. Also
-        the kernel launches dispatched."""
-        return dict(self._sstats, launches=self.launch_no)
+        the kernel launches dispatched, and the protocol steps the next
+        one will run (the engine's own choice where steps_per_sync is
+        None)."""
+        return dict(
+            self._sstats, launches=self.launch_no,
+            steps_per_launch=self._multi,
+        )
 
     def lease_stats(self) -> dict:
         """Cumulative lease read counters across all lanes: 'local' =
@@ -5008,14 +5156,10 @@ def get_vector_engine(logdb, nh_config: NodeHostConfig) -> VectorEngineHandle:
                         core.kcfg.readindex_depth,
                         want.readindex_depth,
                     ),
-                    # the super-step length is compiled into the shared
-                    # core's executable: every co-hosted host runs at
-                    # the same K by construction
-                    (
-                        "steps_per_sync",
-                        core._multi,
-                        max(1, int(getattr(want, "steps_per_sync", 1) or 1)),
-                    ),
+                    # one core runs one loop: every co-hosted host
+                    # asks for the same steps a launch, or all leave
+                    # the choice to the engine
+                    ("steps_per_sync", core._steps_cfg, _steps_option(want)),
                 )
                 if got != exp
             ]

@@ -177,8 +177,10 @@ class Options:
     # vector-engine composition knobs: the smoke rotation soaks the
     # sharded K-step kernel (shard_over_mesh + steps_per_sync>1) under
     # the same chaos schedule as the host path — scalar engines ignore
-    # both
-    steps_per_sync: int = 1
+    # both. None leaves the steps a launch to the engine, as a NodeHost
+    # with a default EngineConfig does (one core a host here, so nothing
+    # is routable on the device and it runs the one-step loop)
+    steps_per_sync: Optional[int] = None
     shard_over_mesh: bool = False
     # run `tools.check` (the full static-analysis gate, interprocedural
     # families included) before round 1 and refuse to start on findings:
@@ -1422,7 +1424,7 @@ class _Round:
         )
         # the engine composition is part of the repro: a sharded K-step
         # failure must replay on the sharded K-step engine
-        if self.opts.steps_per_sync > 1:
+        if self.opts.steps_per_sync is not None:
             cmd += f" --steps-per-sync {self.opts.steps_per_sync}"
         if self.opts.shard_over_mesh:
             cmd += " --shard-over-mesh"
@@ -1632,9 +1634,9 @@ def main(argv=None) -> int:
     ap.add_argument("--inject-failure", action="store_true",
                     help="force a failing verdict each round (drills the "
                          "artifact bundle + replay-command path)")
-    ap.add_argument("--steps-per-sync", type=int, default=1,
-                    help="vector engine K-step super-steps (K protocol "
-                         "steps per device sync; scalar ignores)")
+    ap.add_argument("--steps-per-sync", type=int, default=None,
+                    help="vector engine: protocol steps per kernel launch "
+                         "(default: the engine chooses; scalar ignores)")
     ap.add_argument("--shard-over-mesh", action="store_true",
                     help="shard the vector engine's lane axis over the "
                          "local device mesh (composes with "

@@ -163,6 +163,13 @@ def test_a_lost_catchup_replicate_is_sent_again_without_a_snapshot(
         cluster.start()
         leader = cluster.wait_leaders(60.0)[0]
         core = cluster.core
+        # the engine's three-step program is compiled by its first
+        # launch: let that pass, or the stall meets the silenced
+        # follower's election timeout (0.2 s) mid-batch
+        deadline = time.monotonic() + 60
+        while core.step_stats()["steps_per_launch"] != 3:
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
         victim = next(n for n in cluster.hosts if n != leader)
         nh = cluster.hosts[leader]
         state = types.SimpleNamespace(cut=True, dropped=0)

@@ -136,6 +136,13 @@ def test_an_unsampled_catchup_records_nothing(ratio, tmp_path):
         cluster.start()
         leader = cluster.wait_leaders(60.0)[0]
         core = cluster.core
+        # (the engine's three-step program is compiled by its first
+        # launch; that stall would meet the silenced follower's election
+        # timeout mid-batch)
+        deadline = time.monotonic() + 60
+        while core.step_stats()["steps_per_launch"] != 3:
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
         victim = next(n for n in cluster.hosts if n != leader)
         cut = [True]
         core.set_local_drop_hook(lambda m: cut[0] and m.to == victim)

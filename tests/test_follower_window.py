@@ -40,8 +40,10 @@ def test_a_released_backlog_never_overruns_the_window(kind, tmp_path):
             cluster.session(leader, 0), payloads.cmds(0, 0, 16), 10.0)
         assert h.wait(20) and h.completed == 16
         # a restore that takes a while: the lane's messages are held
+        # (as _handle_install_snapshot marks it: off the device's routes)
         lane.recovering = True
         core._m_recovering[lane.g] = True
+        core._routes_dirty = True
         for i in range(1, 1 + BATCHES):
             h = nh.propose_batch_async(
                 cluster.session(leader, 0),
@@ -57,6 +59,7 @@ def test_a_released_backlog_never_overruns_the_window(kind, tmp_path):
         assert held > 2 * CONFIG["engine"]["log_window"], held
         lane.recovering = False
         core._m_recovering[lane.g] = False
+        core._routes_dirty = True
         core.set_node_ready(lane.key)
         want = nh.stale_read(1, None)
         assert want[0] == 16 * (1 + BATCHES)

@@ -386,6 +386,9 @@ def _mk_host(nid, reg, tmp, scope):
                 max_peers=4,
                 log_window=64,
                 share_scope=scope,
+                # the chain under test is the host path's: a Replicate
+                # routed on the device leaves no replicate_send event
+                steps_per_sync=1,
                 profile_sample_ratio=1,  # sample EVERY step
             ),
         )

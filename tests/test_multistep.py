@@ -694,6 +694,12 @@ def _bring_up(tmp_path, scope, k, members, sm_cls=None, **engine):
             break
         time.sleep(0.02)
     assert lead, "no leader elected"
+    # steps left to the engine: taken up at the first launch boundary
+    # that finds every peer routable
+    core = hosts[lead].engine.core
+    while core.step_stats()["steps_per_launch"] != (k or 3):
+        assert time.monotonic() < deadline, core.step_stats()
+        time.sleep(0.02)
     return hosts, lead
 
 
@@ -852,11 +858,14 @@ def _make_log_sm_cls():
     return SM
 
 
+@pytest.mark.parametrize("k, steps", [(8, 8), (None, 3)], ids=["k8", "auto"])
 def test_k8_batch_is_acknowledged_in_one_launch_behind_its_save_wave(
-    tmp_path, monkeypatch
+    tmp_path, monkeypatch, k, steps
 ):
     """fleet-1024x3-k8's guarantee at the NodeHost level: three co-hosted
-    hosts at steps_per_sync 8, unsharded. Co-hosted replicas acknowledge
+    hosts at steps_per_sync 8, unsharded, and the same hosts with the
+    steps left to the engine (None: three a launch here, the default
+    path of every co-hosted deployment). Co-hosted replicas acknowledge
     on the device before the host has written anything, so a batch of 64
     commits inside the launch that packed it; "a majority has it durable"
     holds at the client's boundary only because every replica's entries
@@ -866,15 +875,16 @@ def test_k8_batch_is_acknowledged_in_one_launch_behind_its_save_wave(
     barrier, written by one and the same launch."""
     from dragonboat_tpu.requests import BatchRequestState
 
-    members = {1: "ms8:1", 2: "ms8:2", 3: "ms8:3"}
+    members = {n: f"ms{steps}:{n}" for n in (1, 2, 3)}
     hosts, lead = _bring_up(
-        tmp_path, "test-multistep8", 8, members, sm_cls=_make_log_sm_cls(),
+        tmp_path, f"test-multistep{steps}", k, members,
+        sm_cls=_make_log_sm_cls(),
         log_window=256, inbox_depth=4, max_entries_per_msg=64,
         profile_sample_ratio=1,
     )
     try:
         core = hosts[1].engine.core
-        assert core._multi == 8 and core._mesh is None
+        assert core._multi == steps and core._mesh is None
         core.request_sampler.ratio = 1  # stamp every batch's path
         order = _SaveOrder(core)
         for nid, nh in hosts.items():
@@ -941,9 +951,11 @@ def test_multistep_matches_k1_outcome(tmp_path):
     for k, scope, members in (
         (1, "test-ms-k1", {1: "msk1:1", 2: "msk1:2", 3: "msk1:3"}),
         (4, "test-ms-k4", {1: "msk4:1", 2: "msk4:2", 3: "msk4:3"}),
+        (None, "test-ms-auto", {1: "mska:1", 2: "mska:2", 3: "mska:3"}),
     ):
         hosts, lead = _bring_up(tmp_path, scope, k, members)
         try:
+            assert hosts[1].engine.core._multi == (k or 3)
             sess = hosts[lead].get_noop_session(1)
             vals = []
             for i in range(40):
@@ -954,4 +966,4 @@ def test_multistep_matches_k1_outcome(tmp_path):
         finally:
             for nh in hosts.values():
                 nh.stop()
-    assert results[1] == results[4]
+    assert results[1] == results[4] == results[None]

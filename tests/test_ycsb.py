@@ -604,22 +604,30 @@ def test_a_broken_state_machine_is_found(sm_cls, tmp_path):
         c.stop()
 
 
-@pytest.mark.parametrize("overlap", [False, True])
-def test_a_saturated_lane_fills_every_launch(overlap, tmp_path):
+@pytest.mark.parametrize("overlap, steps", [
+    (False, 1), (True, 1), (True, None),
+], ids=["False", "True", "auto"])
+def test_a_saturated_lane_fills_every_launch(overlap, steps, tmp_path):
     """One group offered far more than a launch can take: its leader
     puts a row of E = 8 proposals into (nearly) every launch, in the
-    plain loop and in the overlapped one, through the inbox slot kept
-    for its proposals. Left to its followers' acknowledgements the inbox
-    let a window's worth (W - 1 = 31) through every eight launches, under
-    4 a launch."""
+    plain one-step loop and in the overlapped one, through the inbox
+    slot kept for its proposals. Left to its followers' acknowledgements
+    the inbox let a window's worth (W - 1 = 31) through every eight
+    launches, under 4 a launch. With the steps left to the engine
+    (three a launch here, the acknowledgements arriving through the
+    residual rows) a launch takes at least as much."""
     # a heartbeat a millisecond: every launch brings the leader both
     # followers' heartbeat responses beside their Replicate responses,
     # as a 0.5 s step does at the benchmark's 200 ms heartbeats
-    c = _Cluster(tmp_path, f"saturated-{overlap}", kv.StateMachine,
+    c = _Cluster(tmp_path, f"saturated-{overlap}-{steps}", kv.StateMachine,
                  rtt_ms=1, election_rtt=400, heartbeat_rtt=1,
-                 overlap_decode=overlap)
+                 overlap_decode=overlap, steps_per_sync=steps)
     try:
-        assert c.core._overlap == overlap
+        deadline = time.monotonic() + 60
+        while c.core._multi != (steps or 3):  # auto: at a launch boundary
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
+        assert c.core._overlap == (overlap and steps == 1)
         wl = kv.Workload(SEED, GROUPS, RECORDS)
         nh = c.hosts[c.leaders[0]]
         n = 640
