@@ -198,6 +198,24 @@ def _default_targets() -> Targets:
         (TRACE, "Profiler.begin"),
         (TRACE, "Profiler.add"),
         (PROFILE, "PhasePlane.on_phase"),
+        # what `save` is made of (ISSUE 37, part 2): the timed wave and the
+        # storage doors under it, once a launch or a shard write, on
+        # sampled iterations only
+        (VECTOR, "VectorEngine._commit_saves"),
+        (VECTOR, "VectorEngine._book_wave"),
+        (LOGDB, "_Shard.save_raft_state_deferred"),
+        (KV, "WalKV.commit_write_batch_deferred"),
+        (KV, "sync_all"),
+        # the progress watch (ISSUE 37), once a launch at the head of
+        # `place` (and _maintain's catch-up sweep beside it): whole-G
+        # numpy, and three folds on sampled iterations. What a
+        # stall's crossing leaves (_report_stalls: events and one
+        # warning, sampled or not) is anomaly-only and stays outside.
+        (VECTOR, "VectorEngine._maintain"),
+        (VECTOR, "VectorEngine._watch_progress"),
+        (VECTOR, "VectorEngine._sweep_progress"),
+        (VECTOR, "VectorEngine._sweep_peers"),
+        (VECTOR, "VectorEngine._level_applied"),
     }
     # request entry points that mint trace ids + the decode/send phases
     # that propagate them: unsampled requests stay allocation/event-free

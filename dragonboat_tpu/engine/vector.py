@@ -118,6 +118,8 @@ from ..profile import (
 from ..requests import LogicalClock
 from ..settings import soft
 from ..storage.kv import sync_all as _kv_sync_all
+from ..storage.kv import close_wave as _kv_close_wave
+from ..storage.kv import open_wave as _kv_open_wave
 from ..trace import LatencySampler, Profiler, flight_recorder
 from ..types import (
     Entry,
@@ -1106,6 +1108,28 @@ def build_save_updates(o: dict, base, lane_by_g, commit_cap=None, owed=None):
 _ACK_LAUNCHES = 4
 _RESTORE_LAUNCHES = 8
 
+# The progress watch (VectorEngine._watch_progress): a debt of progress
+# that a stepped lane has owed for this many of the watch's sweeps running
+# (launches at least an election timeout apart) is a stall, twice the
+# four launches that the repair of one lost Replicate takes (heartbeat,
+# response, probe, reject and resend: PERF.md section 6, PR 34); and how
+# many of a sweep's new stalls leave a `progress_stall` event in full
+# (all of them are counted). Not knobs.
+_STALL_LAUNCHES = 8
+_STALL_EVENTS_PER_LAUNCH = 8
+# The watch sweeps in blocks of at most this many elements a numpy call,
+# through slices and through fancy indexes into ONE dimension. numpy gives
+# up the GIL around every inner loop of more than 500 elements and around
+# every fancy index into two dimensions whatever its size, and while
+# apply or snapshot workers are busy the loop thread gets it back only
+# after their slices: swept over whole planes the watch stood 300 ms a
+# launch in the fleet behind `apply` and 12-25 ms in the two 5-replica
+# cells in `place`, and with `match[rows]` in it 25-56 ms in the churn
+# cell, on 0.3 to 2 ms of work (PERF.md section 6, PR 37). Should numpy
+# move its threshold the watch stays right and gets slow, and
+# `engine.watch_ms_per_launch` says so.
+_SWEEP_ELEMENTS = 480
+
 # What the engine runs a launch when it chooses for itself
 # (EngineConfig.steps_per_sync None) and every peer is routable on the
 # device: the protocol steps a commit takes, leader append, follower
@@ -1331,6 +1355,15 @@ class VectorEngine:
             "catchup_entries": 0,  # entries sent from the host log
             "replicate_resends": 0,  # Replicates below an index sent before
             "snapshot_fallbacks": 0,  # peers handed to the snapshot path
+            # the progress watch (_watch_progress): over the launches,
+            # the debts of progress standing at or past _STALL_LAUNCHES
+            # (a leader's peer slots, lanes whose commit stands below
+            # their last index, lanes whose state machine stands below
+            # their commit), and how often a debt crossed that line
+            "peer_stall_steps": 0,
+            "commit_stall_steps": 0,
+            "apply_stall_steps": 0,
+            "stalls_seen": 0,
             # multi-step engine: co-hosted messages routed ON DEVICE
             # between inner steps (zero host Message objects each)
             "msgs_routed_device": 0,
@@ -1646,6 +1679,38 @@ class VectorEngine:
         # lanes whose state machine is being restored from a snapshot:
         # they are given no ticks (see the tick plane in _run_once)
         self._m_recovering = np.zeros((G,), bool)
+        # ---- the progress watch (_watch_progress) ------------------------
+        # the peer slots a lane would owe progress to if it led: voting
+        # members other than itself, kept where the device's `voting`
+        # plane is staged (_compute_activation, _stage_remap)
+        P = self.kcfg.peers
+        self._m_owes = np.zeros((G, P), bool)
+        # what the last sweep saw (device units, as the StepOutput's) and
+        # for how many sweeps running each debt has stood unpaid
+        self._w_match = np.zeros((G, P), np.int32)
+        self._w_commit = np.zeros((G,), np.int32)
+        self._w_peer_age = np.zeros((G, P), np.int32)
+        self._w_commit_age = np.zeros((G,), np.int32)
+        # the lanes that led at the last sweep (a peer debt is a leading
+        # lane's: the row of one that leads no more is cleared)
+        self._w_led = np.zeros((G,), bool)
+        # a sweep is a launch at least `_w_period` ticks of the engine's
+        # clock after the sweep before it: the longest election timeout
+        # among the lanes activated so far (see _watch_progress); between
+        # two sweeps the debts stand as counted
+        self._w_tick = 0
+        self._w_period = 1
+        self._w_peer_n = 0
+        self._w_commit_n = 0
+        # the apply debt is a level taken every _STALL_LAUNCHES-th sweep,
+        # block of lanes by block in turn: the applied index each lane
+        # had then (real units), whether it stood below its commit, and
+        # which lanes stand stalled since
+        self._w_sweeps = 0
+        self._w_applied = np.zeros((G,), np.int64)
+        self._w_apply_owed = np.zeros((G,), bool)
+        self._w_apply_stalled = np.zeros((G,), bool)
+        self._w_apply_n = 0
         self._ctr = np.zeros((G, CTR.COUNT), np.uint64)
         # what the lanes that have left had counted: counter_stats() stays
         # cumulative when a lane is freed or reused
@@ -2853,6 +2918,7 @@ class VectorEngine:
         note_engine_steps(1)
         prof = self.profiler
         prof.begin("place")
+        self._watch_progress(o)  # first: see there
         self._decode_place(o, packs)
         self._refresh_mirrors(o)
         # ---- phase 1: Replicate messages leave BEFORE the fsync ----------
@@ -2860,6 +2926,7 @@ class VectorEngine:
         self._decode_send_rep(o)
         # ---- phase 2: one batched fsynced write for every lane -----------
         prof.begin("save")
+        mark = self._wave_mark()
         owed = self._m_commit_owed
         if owed.any():
             # the first one-step launch after a switch down: the last
@@ -2870,7 +2937,7 @@ class VectorEngine:
         updates, lane_saves = build_save_updates(
             o, self._m_base, self._lane_by_g, owed=owed
         )
-        self._commit_saves(updates, lane_saves)
+        self._commit_saves(updates, lane_saves, mark)
         # ---- phase 3: post-fsync sends (votes, responses, heartbeats) ----
         prof.begin("send_resp")
         self._decode_send_post(o)
@@ -2928,6 +2995,7 @@ class VectorEngine:
             ot = {k: v[t] for k, v in o.items()}
             plt = {k: v[t] for k, v in pl.items()}
             steps.append((ot, plt))
+        self._watch_progress(steps[-1][0])  # first: see there
         self.last_output = steps[-1][0]
         note_engine_steps(K)
         st = self._sstats
@@ -2952,6 +3020,7 @@ class VectorEngine:
         self._refresh_mirrors(steps[-1][0])
         # ---- phase 2: ONE merged save wave for the whole window ----------
         prof.begin("save")
+        mark = self._wave_mark()
         updates: List[Update] = []
         lane_saves: List[Tuple[_Lane, List[Entry], State]] = []
         owed = self._m_commit_owed if self._m_commit_owed.any() else None
@@ -2965,7 +3034,7 @@ class VectorEngine:
         self._m_commit_owed = (
             self.last_output["commit_index"] > safe_commit
         ) & self._m_active
-        self._commit_saves(updates, lane_saves)
+        self._commit_saves(updates, lane_saves, mark)
         # ---- phases 3-5 per inner step in order --------------------------
         prof.begin("send_resp")
         for ot, _plt in steps:
@@ -3309,21 +3378,73 @@ class VectorEngine:
         st["msgs_replicate"] += len(rep_sends)
         self._dispatch_sends(rep_sends)
 
-    def _commit_saves(self, updates, lane_saves) -> None:
+    def _wave_mark(self) -> Optional[float]:
+        """Where a save wave begins on the wall clock, before its
+        gather: sampled iterations only."""
+        if self.profiler.sampling:
+            return time.monotonic()
+        return None
+
+    def _commit_saves(self, updates, lane_saves, mark=None) -> None:
         """Phase 2: one batched fsynced write wave + log-reader mirror
         append, in update order (a multi-step window passes every inner
         step's updates through ONE call, so conflict-truncation rewrites
-        apply sequentially inside a single barrier)."""
-        if updates:
-            if self.profiler.sampling:
-                self.profiler.fold("n.save_bytes", sum(
-                    len(e.cmd) for u in updates for e in u.entries_to_save
-                ))
-            self._save_updates(updates, lane_saves)
+        apply sequentially inside a single barrier). A wave with a
+        `mark` (_wave_mark) is timed part by part: the storage layer
+        under it finds the wave's parts in its thread-local
+        (storage.kv._Wave) and adds to them."""
+        parts = None
+        if mark is not None:
+            t1, c1 = time.monotonic(), time.thread_time()
+            parts = _kv_open_wave()
+        try:
+            if updates:
+                self._save_updates(updates, lane_saves)
+        finally:
+            if parts is not None:
+                _kv_close_wave()
+        if mark is not None:
+            t2, c2 = time.monotonic(), time.thread_time()
         for lane, ents, state in lane_saves:
             if ents:
                 lane.node.log_reader.append(ents)
             lane.node.log_reader.set_state(state)
+        if mark is not None:
+            self._book_wave(updates, parts, (mark, t1, t2), c2 - c1)
+
+    def _book_wave(self, updates, parts, at, write_cpu) -> None:
+        """What a sampled save wave was made of, as sub-spans of `save`.
+        Three stretches follow one another with the write between the
+        first two, and leave an event at full sampling: save.gather
+        (build_save_updates, since the wave's mark), save.sync (the
+        barrier: storage.kv.sync_all books it, with the thread's CPU
+        seconds, on the deferred path and inside a logdb's own
+        save_raft_state alike) and save.mirror. The write (_save_updates
+        up to its barrier) has its CPU seconds (save.write.cpu: the
+        thread's between gather and mirror, `write_cpu`, less the
+        barrier's) and its three pieces, which the storage layer tells
+        apart shard by shard: the encode into write batches, the stores'
+        commits less their in-memory tables (the WAL append; a store
+        that cannot tell has its whole commit here) and the tables.
+        Everything is recorded here, in one go behind the work, so that
+        the window's edge that falls between this wave's parts and the
+        close of its `save` span is microseconds wide."""
+        prof = self.profiler
+        t0, t1, t2 = at
+        t3 = time.monotonic()
+        n_bytes = sum(len(e.cmd) for u in updates for e in u.entries_to_save)
+        sync, sync_cpu = parts["sync"], parts["sync_cpu"]
+        prof.add("save.gather", t1 - t0, end=t1)
+        prof.fold("save.write.cpu", write_cpu - sync_cpu)
+        prof.add("save.encode", parts["encode"])
+        prof.add("save.append", parts["commit"] - parts["table"])
+        prof.add("save.table", parts["table"])
+        prof.add("save.sync", sync, sync_cpu, end=t2)
+        prof.add("save.mirror", t3 - t2, end=t3)
+        if updates:
+            prof.fold("n.save_bytes", n_bytes)
+        prof.fold("n.save_wal_bytes", parts["wal_bytes"])
+        prof.fold("n.save_wal_records", parts["wal_records"])
 
     def _decode_send_post(self, o: dict) -> None:
         """Phase 3: post-fsync sends (votes, responses, heartbeats) plus
@@ -3894,8 +4015,14 @@ class VectorEngine:
         parked = (o["rstate"] == RSTATE.SNAPSHOT) & (
             (o["role"] == ROLE.LEADER)[:, None]
         )
-        if self.profiler.sampling:
-            self.profiler.fold("n.peer_steps_parked", np.count_nonzero(parked))
+        # (n.peer_steps_parked is every leader's slot in the SNAPSHOT
+        # state this launch, tracked or not: the recovery paths' own
+        # business. The progress watch, _watch_progress at the head of
+        # `place`, counts the slots and lanes that owe progress OUTSIDE
+        # those paths and make none, and leaves parked slots, catch-ups
+        # and restores out.)
+        if prof.sampling:
+            prof.fold("n.peer_steps_parked", np.count_nonzero(parked))
         for g, p in zip(*np.nonzero(parked)):
             lane = lane_by_g[g]
             if (
@@ -3972,6 +4099,300 @@ class VectorEngine:
             # threshold leaves orders of magnitude more headroom than the
             # one extra step this defers by.
             self._rebase_due = True
+
+    # ------------------------------------------------------ progress watch
+    def _set_owes(self, g: int, voting, self_slot: int) -> None:
+        """Lane g's peer slots that it owes progress to while it leads:
+        the voting members but itself."""
+        row = self._m_owes[g]
+        row[:] = voting
+        if 0 <= self_slot < row.size:
+            row[self_slot] = False
+
+    def _watch_progress(self, o: dict) -> None:
+        """Once a launch, on its final StepOutput: which stepped lanes owe
+        progress and made none since the last sweep. Three debts, each
+        with the sweeps it has stood unpaid: a leader's peer slot whose
+        match is below the leader's last index and did not move; a lane
+        that knows a leader and whose commit index is below its last
+        index and did not move; a lane whose state machine's applied
+        index is below its commit and did not move (a level taken every
+        _STALL_LAUNCHES-th sweep against the one before: the one debt
+        that needs a pass over the lanes, an eighth of them a sweep). A
+        debt at _STALL_LAUNCHES is
+        a stall: counted in step_stats() at every launch it stands,
+        reported once where it crosses (_report_stalls).
+
+        A sweep is a launch at least an election timeout (`_w_period`
+        ticks of the engine's clock, which reads no clock) after the
+        sweep before it. In a serving fleet that is every launch (1.9 s
+        against 1 s). Where launches are short (bring-up, an idle loop's
+        one a tick, 95 ms upstream) eight of them are no time at all:
+        counted by launches alone the watch called 1 527 state machines
+        of the fleet stalled at launch 16 of every bring-up, their
+        bootstrap entries waiting their turn behind start_clusters
+        (PERF.md section 6, PR 37). Between two sweeps the debts stand
+        as the last one counted them.
+
+        It is the first thing `place` does, right behind the fetch and
+        before `apply` wakes the apply workers, and it hands the GIL to
+        nobody: every numpy call in it is one around which numpy keeps
+        the GIL (_SWEEP_ELEMENTS), so what it costs is its own CPU, one
+        to three ms, and the loop thread has just taken the GIL back
+        from the fetch's blocking copy, so those lie inside one switch
+        interval. It reads the step's output and the mirrors the host
+        keeps (active, recovering, base, who votes), none of which this
+        launch's decode has yet to write; the commit and applied indexes
+        it compares are levels, so the side of `apply` it reads them on
+        changes nothing it counts.
+
+        Lanes that are not active, under restore or quiesced owe nothing,
+        nor does a slot parked for a snapshot or served by a catch-up:
+        those have their own trackers and counters. A lane without a
+        leader owes no commit: elections are counted where they happen.
+        (A rebase shifts match and commit alike: it reads as movement,
+        and the debts start again.) No per-entry or per-message work,
+        and no clock but the sub-span's pair on a sampled iteration."""
+        prof = self.profiler
+        if prof.sampling:
+            t0 = time.monotonic()
+        now = self.clock.tick
+        if now - self._w_tick >= self._w_period:
+            self._w_tick = now
+            self._sweep_progress(o)
+        st = self._sstats
+        st["peer_stall_steps"] += self._w_peer_n
+        st["commit_stall_steps"] += self._w_commit_n
+        st["apply_stall_steps"] += self._w_apply_n
+        if prof.sampling:
+            # 0 included: the anchor by which the readers tell a launch
+            # without a stall from a program without the watch
+            prof.fold("n.peer_stall_steps", self._w_peer_n)
+            prof.fold("n.commit_stall_steps", self._w_commit_n)
+            prof.fold("n.apply_stall_steps", self._w_apply_n)
+            prof.add("watch", time.monotonic() - t0)
+
+    def _sweep_progress(self, o: dict) -> None:
+        """One sweep of the progress watch (see _watch_progress), in
+        blocks of at most _SWEEP_ELEMENTS elements a numpy call: the
+        lanes' own debts block of lanes by block, then the peer debts
+        over the leading lanes' rows alone, a third to a fifth of the
+        [G, P] planes."""
+        self._w_sweeps += 1
+        G = self._m_owes.shape[0]
+        quiesced, role, leader = o["quiesced"], o["role"], o["leader"]
+        last_index, commit_index = o["last_index"], o["commit_index"]
+        led, leads_now = self._w_led, np.zeros((G,), bool)
+        leaders = []
+        n_commit = 0
+        commits: List[int] = []  # debts that crossed the line
+        applies: List[int] = []
+        for b, lo in enumerate(range(0, G, _SWEEP_ELEMENTS)):
+            sl = slice(lo, lo + _SWEEP_ELEMENTS)
+            stepped = (
+                self._m_active[sl] & ~self._m_recovering[sl] & ~quiesced[sl]
+            )
+            last, commit = last_index[sl], commit_index[sl]
+            leads = stepped & (role[sl] == ROLE.LEADER)
+            leads_now[sl] = leads
+            leaders.append(np.flatnonzero(leads) + lo)
+            lost = led[sl] & ~leads
+            if lost.any():
+                # no longer leading: nothing is owed
+                for g in np.flatnonzero(lost).tolist():
+                    self._w_peer_age[lo + g] = 0
+            # ---- commit debts ---------------------------------------------
+            cage, cseen = self._w_commit_age[sl], self._w_commit[sl]
+            cage += 1
+            cage *= (
+                stepped & (leader[sl] != 0)
+                & (last > commit) & (commit == cseen)
+            )
+            cseen[:] = commit
+            if cage.max() >= _STALL_LAUNCHES:
+                commits.extend(
+                    (np.flatnonzero(cage == _STALL_LAUNCHES) + lo).tolist())
+                n_commit += int(np.count_nonzero(cage >= _STALL_LAUNCHES))
+            # ---- apply debts: a block's level every _STALL_LAUNCHES-th
+            # sweep, the blocks taking turns so that no sweep looks at
+            # more than an eighth of the state machines ------------------
+            if (self._w_sweeps + b) % _STALL_LAUNCHES == 0:
+                self._level_applied(lo, sl, stepped, commit, applies)
+        self._w_led, self._w_commit_n = leads_now, n_commit
+        peers = self._sweep_peers(o, np.concatenate(leaders))
+        if peers or commits or applies:
+            try:
+                self._report_stalls(o, peers, commits, applies)
+            except Exception:
+                # a report that cannot be made (a lane torn down under
+                # it) must not cost the loop its decode
+                import traceback
+
+                traceback.print_exc()
+
+    def _sweep_peers(self, o: dict, leaders) -> List[Tuple[int, int]]:
+        """The peer debts of one sweep, over the rows of the lanes that
+        lead (`leaders`, lane indexes): keeps how many stand at or past
+        the line (`_w_peer_n`) and returns those that crossed it, as
+        (lane, slot). The [G, P] planes are read and written through
+        their flat views by element index: numpy gives up the GIL around
+        a fancy index into two dimensions whatever its size, and keeps
+        it around one into a single dimension of a block's length."""
+        lane_by_g = self._lane_by_g
+        P = self._m_owes.shape[1]
+        last_index = o["last_index"]
+        matches, rstate = o["match"].reshape(-1), o["rstate"].reshape(-1)
+        owes, seen = self._m_owes.reshape(-1), self._w_match.reshape(-1)
+        ages = self._w_peer_age.reshape(-1)
+        chunk = max(1, _SWEEP_ELEMENTS // P)
+        slots = np.tile(np.arange(P), chunk)
+        n_peer = 0
+        peers: List[Tuple[int, int]] = []
+        for i in range(0, leaders.size, chunk):
+            rows = leaders[i:i + chunk]
+            at = np.repeat(rows * P, P) + slots[:rows.size * P]
+            match = matches[at]
+            age = ages[at] + 1
+            age *= (
+                owes[at] & (rstate[at] != RSTATE.SNAPSHOT)
+                & (match < np.repeat(last_index[rows], P))
+                & (match == seen[at])
+            )
+            seen[at] = match
+            if age.max() >= _STALL_LAUNCHES:
+                for k in np.flatnonzero(age == _STALL_LAUNCHES).tolist():
+                    g, p = divmod(int(at[k]), P)
+                    lane = lane_by_g[g]
+                    if (
+                        lane is None
+                        or p in lane.catchup
+                        or p in lane.snap_inflight
+                    ):
+                        # served from the host log or waiting for its
+                        # snapshot's acknowledgement: its tracker's,
+                        # which retries by itself; the debt starts again
+                        age[k] = 0
+                    else:
+                        peers.append((g, p))
+                n_peer += int(np.count_nonzero(age >= _STALL_LAUNCHES))
+            ages[at] = age
+        self._w_peer_n = n_peer
+        return peers
+
+    def _level_applied(self, lo: int, sl, stepped, commit, new: list) -> None:
+        """The apply debt's level over one block of lanes: those whose
+        state machine stands below their commit index now, stood below
+        it at the block's last level, and has not applied an entry
+        since. Appends the lanes that are newly so to `new` and keeps
+        how many stand so over all blocks (`_w_apply_n`)."""
+        lane_by_g = self._lane_by_g
+        real_commit = self._m_base[sl] + commit
+        applied = self._w_applied[sl]
+        was = applied.copy()
+        owed = np.zeros(was.shape, bool)
+        # a lane whose commit is at or below the applied index it had at
+        # the last level owes nothing whatever it has applied since
+        look = [
+            g for g in np.flatnonzero(stepped & (real_commit > was)).tolist()
+            if lane_by_g[lo + g] is not None
+        ]
+        if look:
+            applied[look] = [
+                lane_by_g[lo + g].node.sm.applied_level() for g in look
+            ]
+            owed[look] = real_commit[look] > applied[look]
+        stalled = owed & self._w_apply_owed[sl] & (applied == was)
+        before = self._w_apply_stalled[sl]
+        new.extend((np.flatnonzero(stalled & ~before) + lo).tolist())
+        self._w_apply_n += int(
+            np.count_nonzero(stalled)) - int(np.count_nonzero(before))
+        self._w_apply_owed[sl] = owed
+        self._w_apply_stalled[sl] = stalled
+
+    def _report_stalls(self, o: dict, peers, commits, applies) -> None:
+        """Debts that crossed _STALL_LAUNCHES at this sweep (peer debts
+        as (lane, slot), the others as lanes): each is counted
+        (step_stats()['stalls_seen']), the first
+        _STALL_EVENTS_PER_LAUNCH leave a `progress_stall` event with what
+        a person needs to say why, and the launch leaves ONE warning: the
+        count and the first in full. Traced or not, sampled or not: a run
+        that dies at its read-back has said which replica stood still and
+        since which launch. Rare by construction."""
+        lane_by_g = self._lane_by_g
+        launch = self.launch_no
+        seen = len(peers) + len(commits) + len(applies)
+        events: List[dict] = []
+
+        def lane_fields(kind: str, g: int) -> dict:
+            node = lane_by_g[g].node
+            return dict(
+                kind=kind,
+                cluster=node.cluster_id,
+                node=node.node_id(),
+                lane=g,
+                launch=launch,
+                # sweeps the debt has stood, and the launch at which
+                # it cannot have begun later (the apply level is taken
+                # every _STALL_LAUNCHES-th sweep: one to two stretches)
+                age=_STALL_LAUNCHES,
+                since=launch - _STALL_LAUNCHES,
+                role=int(o["role"][g]),
+                term=int(o["term"][g]),
+                leader=int(o["leader"][g]) - 1,
+                base=int(self._m_base[g]),
+                last=int(o["last_index"][g]),
+                commit=int(o["commit_index"][g]),
+                applied=node.sm.last_applied_index(),
+                steps=self._multi,
+            )
+
+        room = _STALL_EVENTS_PER_LAUNCH
+        if peers:
+            # the one plane the StepOutput does not carry
+            # lint: allow(device-sync/cross-function) a stall's crossing
+            # only: one blocking read a sweep that found a new stall
+            nxt = np.asarray(self._state.next)
+        for g, p in peers[:room]:
+            lane = lane_by_g[g]
+            ev = lane_fields("peer", g)
+            peer_nid = lane.rev.get(p)
+            ev.update(
+                peer_slot=p,
+                peer=peer_nid if peer_nid is not None else 0,
+                match=int(o["match"][g, p]),
+                next=int(nxt[g, p]),
+                rstate=int(o["rstate"][g, p]),
+                route=int(self._np_route[g, p]),
+            )
+            dst = self._route.get((lane.node.cluster_id, peer_nid))
+            if dst is not None:
+                d = dst.g
+                ev.update(
+                    peer_lane=d,
+                    peer_resid=int(self._m_resid[d]),
+                    peer_active=bool(self._m_active[d]),
+                    peer_recovering=bool(self._m_recovering[d]),
+                    peer_role=int(o["role"][d]),
+                    peer_term=int(o["term"][d]),
+                    peer_last=int(o["last_index"][d]),
+                    peer_commit=int(o["commit_index"][d]),
+                )
+            events.append(ev)
+        for kind, gs in (("commit", commits), ("apply", applies)):
+            for g in gs[: room - len(events)]:
+                events.append(lane_fields(kind, g))
+        rec = flight_recorder()
+        for ev in events:
+            rec.record("progress_stall", **ev)
+        _plog.warningf(
+            "progress watch: %d debt(s) of progress unpaid for %d sweeps "
+            "(launches an election timeout apart or more) at launch %d; "
+            "the first: %s",
+            seen, _STALL_LAUNCHES, launch,
+            " ".join(f"{k}={v}" for k, v in events[0].items()),
+        )
+        # last: whoever reads the count finds the events and the line
+        self._sstats["stalls_seen"] += seen
 
     def _do_rebase(self) -> None:
         """Shift device indexes down so they never near 2**31. The delta is
@@ -4150,6 +4571,7 @@ class VectorEngine:
         if self_slot < 0:
             self_slot = lane.slot_of(lane.node.node_id(), provisional=True)
         v["self_slot"][g] = max(self_slot, 0)
+        self._set_owes(g, v["voting"][g], self_slot)
         # the leader mirror is a slot+1 reference too; it stays readable
         # (get_leader_id, leader_snapshot) against the new lane.rev
         old_leader = int(self._m_leader[g]) - 1
@@ -4325,6 +4747,8 @@ class VectorEngine:
         self._m_snap_pending[g] = False
         self._m_quiesced[g] = False  # a reused lane must not inherit this
         self._m_leader_change_tick[g] = self.clock.tick
+        self._set_owes(g, voting, self_slot)
+        self._w_period = max(self._w_period, int(cfg.election_rtt))
         return dict(
             self_slot=max(self_slot, 0),
             member=member,
@@ -4480,6 +4904,8 @@ class VectorEngine:
         self._m_leader_change_tick[g] = 0
         self._m_recovering[g] = False
         self._m_commit_owed[g] = False
+        self._m_owes[g] = False
+        self._w_apply_owed[g] = False
         self._ctr_left += self._ctr[g]
         self._ctr[g] = 0
         self._carry.discard(lane)

@@ -77,6 +77,15 @@ VECTOR_SUBSPANS = (
     "launch",       # inside dispatch: the jitted call returning its futures
     "device_wait",  # inside fetch: until the step's output is ready
     "copy",         # inside fetch: the device_get after that
+    # inside save, one after another with the write between the first
+    # two (each also a `vector.sub` span event at full sampling):
+    # build_save_updates; the barrier (storage.kv.sync_all); the
+    # log-reader mirror
+    "save.gather", "save.sync", "save.mirror",
+    # inside the write, summed over the shards written: the encode into
+    # write batches, the WAL append, the in-memory table
+    "save.encode", "save.append", "save.table",
+    "watch",        # inside place: the progress watch's sweep
 )
 VECTOR_PHASES = (
     "wait",       # blocked in _ready.wait, a fairness yield, and idle

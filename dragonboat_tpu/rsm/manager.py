@@ -275,6 +275,14 @@ class StateMachineManager:
         with self._mu:
             return self._index
 
+    def applied_level(self) -> int:
+        """The applied index read without `_mu`: a moment old at worst
+        (an int attribute's read is atomic). For the engine's progress
+        watch, which compares levels taken seconds apart over thousands
+        of lanes from the loop thread and must never wait there for a
+        worker that was descheduled holding the lock."""
+        return self._index
+
     def get_last_applied(self) -> Tuple[int, int]:
         with self._mu:
             return self._index, self._term

@@ -39,6 +39,44 @@ _OP_RANGE_DEL = 2
 _OP_COMMIT = 3
 
 
+class _Wave(threading.local):
+    """`parts` is what the save wave that THIS thread is timing has cost
+    so far, or None: seconds under "encode" (write batches built),
+    "commit" (the stores' commits of them, of which "table" is the
+    in-memory table's where the store can tell), "sync" and "sync_cpu"
+    (the barrier), and "wal_bytes" and "wal_records" appended. The
+    engine loop sets it around a sampled wave (open_wave, close_wave); the write
+    path under it adds to it and reads a clock only where it finds one,
+    so an unsampled wave pays a few `is None` tests a shard write and a
+    barrier wave, and another thread's write to the same store (a
+    snapshot worker's, a compaction's) finds None."""
+
+    parts: Optional[dict] = None
+
+
+_wave = _Wave()
+
+
+def open_wave() -> dict:
+    """Begin timing the calling thread's save wave: its parts, all zero,
+    for the write path under it to add to until close_wave()."""
+    parts = _wave.parts = {
+        "encode": 0.0, "commit": 0.0, "table": 0.0, "sync": 0.0,
+        "sync_cpu": 0.0, "wal_bytes": 0, "wal_records": 0,
+    }
+    return parts
+
+
+def close_wave() -> None:
+    _wave.parts = None
+
+
+def wave_parts() -> Optional[dict]:
+    """The parts of the save wave that the calling thread is timing, or
+    None: what the write path under open_wave() adds to."""
+    return _wave.parts
+
+
 class WriteBatch:
     """Ordered list of mutations applied atomically
     (cf. kv.go IWriteBatch)."""
@@ -421,13 +459,13 @@ class WalKV(IKVStore):
         rec = _REC.pack(_REC.size + len(k) + len(v) + 4, op, len(k), len(v)) + k + v
         self._f.write(rec + struct.pack("<I", zlib.crc32(rec)))
 
-    def _append_group(self, wb: WriteBatch) -> None:
+    def _append_group(self, wb: WriteBatch) -> int:
         """Append wb's records + the commit seal as one group; on ANY
         append failure roll the file back to the pre-group offset before
         re-raising. Without the rollback the unsealed records would sit
         at the tail and the NEXT batch's seal would merge them into its
         group — resurrecting a batch the caller was told failed. Caller
-        holds self._mu."""
+        holds self._mu. Returns the offset the group begins at."""
         start = self._f.tell()
         try:
             fault = self._append_fault
@@ -453,6 +491,7 @@ class WalKV(IKVStore):
                     f.truncate(start)
                 self._f = open(self._path, "ab")
             raise
+        return start
 
     def commit_write_batch(self, wb: WriteBatch) -> None:
         with self._mu:
@@ -465,11 +504,20 @@ class WalKV(IKVStore):
     def commit_write_batch_deferred(self, wb: WriteBatch) -> bool:
         """Append + flush the batch but leave the fsync to sync(): the
         caller groups barriers across shards into one parallel wave. The
-        batch is NOT durable until that sync() returns."""
+        batch is NOT durable until that sync() returns. Inside a save
+        wave that its thread is timing (_Wave) it also says what the
+        group put on the file and how long the table took."""
+        parts = wave_parts()
         with self._mu:
-            self._append_group(wb)
+            start = self._append_group(wb)
+            if parts is not None:
+                parts["wal_bytes"] += self._f.tell() - start
+                parts["wal_records"] += len(wb.ops) + 1
+                t0 = time.monotonic()
             self._mem.commit_write_batch(wb)
             self._since_compact += len(wb.ops)
+            if parts is not None:
+                parts["table"] += time.monotonic() - t0
         return self._fsync
 
     def sync(self) -> None:
@@ -584,6 +632,9 @@ def sync_all(kvs) -> None:
     unique = list(dict.fromkeys(kvs))
     if not unique:
         return
+    parts = wave_parts()
+    if parts is not None:  # the barrier of a save wave its thread is timing
+        parts["sync_cpu"] -= time.thread_time()
     t0 = time.monotonic()
     try:
         if len(unique) == 1:
@@ -602,6 +653,9 @@ def sync_all(kvs) -> None:
             raise first_exc
     finally:
         dt = time.monotonic() - t0
+        if parts is not None:
+            parts["sync"] += dt
+            parts["sync_cpu"] += time.thread_time()
         _barrier_stats.note_wave(dt)
         for kv in unique:  # one wave = one host's save fan-out
             bs = getattr(kv, "bstats", None)
@@ -616,5 +670,8 @@ __all__ = [
     "WalKV",
     "barrier_stats",
     "reset_barrier_stats",
+    "close_wave",
+    "open_wave",
     "sync_all",
+    "wave_parts",
 ]

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from .. import codec
@@ -23,7 +24,7 @@ from ..raftio import (
 from ..settings import hard
 from ..types import Bootstrap, Entry, Snapshot, State, Update
 from . import keys
-from .kv import IKVStore, MemKV, WalKV, WriteBatch, sync_all
+from .kv import IKVStore, MemKV, WalKV, WriteBatch, sync_all, wave_parts
 
 
 class _Shard:
@@ -68,14 +69,24 @@ class _Shard:
     def save_raft_state_deferred(self, updates: Sequence[Update]):
         """Write one batch for `updates` with the durability barrier
         deferred; returns the kv store owing a sync(), or None when
-        nothing was written (or the store needs no separate barrier)."""
+        nothing was written (or the store needs no separate barrier).
+        Inside a save wave that its thread is timing (kv._Wave) the
+        encode into the write batch is told apart from the store's
+        commit of it."""
         with self._wmu:
+            parts = wave_parts()
+            if parts is not None:
+                t0 = time.monotonic()
             wb = WriteBatch()
             for ud in updates:
                 self._record_update(wb, ud)
-            if wb.count() > 0 and self.kv.commit_write_batch_deferred(wb):
-                return self.kv
-            return None
+            if parts is not None:
+                t1 = time.monotonic()
+                parts["encode"] += t1 - t0
+            owes = wb.count() > 0 and self.kv.commit_write_batch_deferred(wb)
+            if parts is not None:
+                parts["commit"] += time.monotonic() - t1
+            return self.kv if owes else None
 
     def _save_entries(self, wb: WriteBatch, cid: int, nid: int, ents) -> None:
         """Pack entries into batch records, merging the head batch with any

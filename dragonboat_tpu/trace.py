@@ -231,15 +231,28 @@ class Profiler:
         if flight_recorder().span(engine, stage, t0, t1):
             self._shed += 1
 
-    def add(self, stage: str, dt: float) -> None:
+    def add(
+        self,
+        stage: str,
+        dt: float,
+        cpu: Optional[float] = None,
+        end: Optional[float] = None,
+    ) -> None:
         """Record a sub-span the CALLER measured on the loop thread
         (nested inside another stage, e.g. the bulk deliver seam inside
         the send phases, or the device_put inside dispatch): samples and
-        a histogram under `<kind>.sub`, no span event. Sampled
-        iterations only; callers gate their own time.monotonic() pair on
-        `self.sampling` so the off path stays clock-read-free."""
+        a histogram under `<kind>.sub`, with the thread's `cpu` seconds
+        where the caller read them. A sub-span that is one stretch of
+        time says when it ended (`end`, on time.monotonic()) and at full
+        sampling leaves a `phase_span` event under `<kind>.sub`, so a
+        dump shows it inside its stage; one that is a sum of pieces
+        (save.encode over the shards) has no end and leaves none.
+        Sampled iterations only; callers gate their own time.monotonic()
+        pair on `self.sampling` so the off path stays clock-read-free."""
         if self.sampling:
-            self.observe(stage, dt, engine=self._sub_kind)
+            self.observe(stage, dt, cpu, engine=self._sub_kind)
+            if end is not None and self._span_gate:
+                self._span(self._sub_kind, stage, end - dt, end)
 
     def observe(
         self,

@@ -16,7 +16,12 @@ import pytest
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
-    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+    _SPEC = json.load(_f)
+CELLS = [w["name"] for w in _SPEC["workloads"]]
+# per launch by the program's own count (ISSUE 35's and ISSUE 37's):
+# declared for every cell, and a number in every traced run of one
+PER_LAUNCH = [m["name"] for m in _SPEC["per_layer"]
+              if m["name"].endswith("_per_launch")]
 
 
 def _run(*args):
@@ -49,6 +54,42 @@ def test_cell_rehearses(cell, trace):
     assert line["correct"] is True
     assert line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
+    if trace:
+        _check_save_parts(cell, line["metrics"])
+
+
+# the progress watch's three (ISSUE 37): 0 wherever no message is lost
+# and no replica replaced
+STALLS = (
+    "replication.stalled_peers_per_launch",
+    "replication.commit_stalled_lanes_per_launch",
+    "rsm.apply_stalled_lanes_per_launch",
+)
+FAULTED = ("fleet1024x5.drops", "fleet1024x5.churn")
+
+
+def _check_save_parts(cell, metrics):
+    """What `save` is made of and the progress watch (ISSUE 37): every
+    one of the sixteen a number (and `engine.steps_per_launch`, ISSUE
+    35's), nothing shed, no stall where there is no fault, and the six
+    parts together the `save` span but a twentieth."""
+    from benchmark.run import load_cell
+
+    assert len(PER_LAUNCH) == 17 and set(STALLS) < set(PER_LAUNCH)
+    for name in PER_LAUNCH:
+        assert isinstance(metrics[name]["value"], (int, float)), name
+    assert metrics["run.spans_dropped"]["value"] == 0
+    if cell not in FAULTED:
+        for name in STALLS:
+            assert metrics[name]["value"] == 0, name
+    # `save` a launch: where the cell's file sets `steps_per_sync` the
+    # harness's step is a launch already, else a protocol step
+    _spec, _cell, config, _traffic = load_cell(cell)
+    save = metrics["storage.save_ms_per_step"]["value"]
+    if config["engine"].get("steps_per_sync") is None:
+        save *= metrics["engine.steps_per_launch"]["value"]
+    uncovered = metrics["storage.save_parts_uncovered_ms_per_launch"]["value"]
+    assert -0.05 * save <= uncovered <= max(0.10 * save, 0.5)
 
 
 def test_without_a_tpu_a_cell_exits_non_zero_and_prints_no_result():

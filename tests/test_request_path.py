@@ -157,9 +157,14 @@ def test_loop_cover(cluster):
     wall = top[-1]["t"] - top[0]["t0"]
     covered = sum(e["dur"] for e in top)
     assert abs(wall - covered) <= 0.02 * wall, (wall, covered)
-    # sub-spans and the apply workers' spans leave no event: samples, and
-    # histograms under labels of their own
-    assert [e for e in spans if e["engine"] != "vector"] == []
+    # sub-spans and the apply workers' spans leave no event, but the save
+    # wave's three stretches that are one piece of time each (ISSUE 37),
+    # under the sub-spans' own kind: samples, and histograms under labels
+    # of their own
+    other = [e for e in spans if e["engine"] != "vector"]
+    assert {e["engine"] for e in other} == {"vector.sub"}
+    stretches = {"save.gather", "save.sync", "save.mirror"}
+    assert {e["phase"] for e in other} == stretches
     sums = {
         name: s.mean() * len(s)
         for name, s in cluster.core.profiler.samples.items()
@@ -168,7 +173,10 @@ def test_loop_cover(cluster):
     for sub in VECTOR_SUBSPANS:
         if sub == "deliver" and cluster.mode in ("k8", "auto"):
             continue  # routed on the device: the host delivers nothing
-        assert sums[sub] > 0.0 and sub + ".cpu" not in sums
+        assert sums[sub] > 0.0
+        # the barrier alone carries the thread's CPU seconds (it
+        # sleeps, and two metrics take its sleep out of the loop's stall)
+        assert (sub + ".cpu" in sums) == (sub == "save.sync")
         assert plane.histogram("vector.sub", sub).count > 0
         assert plane.histogram("vector", sub) is None
     # the seam's halves lie inside the phases they split
@@ -453,8 +461,8 @@ def _spy_decode(core, log) -> None:
         log.append(("place", o))
         place(o, packs)
 
-    def spy_saves(updates, lane_saves):
-        saves(updates, lane_saves)
+    def spy_saves(updates, lane_saves, mark=None):
+        saves(updates, lane_saves, mark)
         log.append(("saved", len(updates)))
 
     def spy_sends(batch):
