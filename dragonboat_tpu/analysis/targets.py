@@ -204,6 +204,9 @@ def _default_targets() -> Targets:
         (VECTOR, "VectorEngine._commit_saves"),
         (VECTOR, "VectorEngine._book_wave"),
         (LOGDB, "_Shard.save_raft_state_deferred"),
+        # what the wave's replicas share (ISSUE 38): two counts a run
+        # of entries, on sampled iterations only
+        (LOGDB, "_Shard._save_entries"),
         (KV, "WalKV.commit_write_batch_deferred"),
         (KV, "sync_all"),
         # the progress watch (ISSUE 37), once a launch at the head of

@@ -151,10 +151,18 @@ def encode_entries(entries: List[Entry]) -> bytes:
     return b"".join(parts)
 
 
-def join_encoded_entries(parts: List[bytes]) -> bytes:
-    """Assemble an entry-list record from per-entry encode_entry() outputs
-    (the logdb batch cache keeps those parts to avoid re-encoding)."""
-    return _U32.pack(len(parts)) + b"".join(parts)
+def frame_encoded_entries(n: int, *bodies) -> bytes:
+    """An entry-list record of `n` entries from their encode_entry()
+    outputs, each alone or already joined, as one or more `bodies` (the
+    logdb merges a record's retained prefix, which it keeps joined, with
+    the entries a run appends), in one allocation."""
+    return b"".join((_U32.pack(n), *bodies))
+
+
+def encoded_entries_body(record: bytes) -> memoryview:
+    """An entry-list record without its count (the entries' encodings,
+    joined), as a view: nothing is copied."""
+    return memoryview(record)[_U32.size:]
 
 
 @_checked

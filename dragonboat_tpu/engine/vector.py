@@ -118,8 +118,11 @@ from ..profile import (
 from ..requests import LogicalClock
 from ..settings import soft
 from ..storage.kv import sync_all as _kv_sync_all
+from ..storage.kv import close_bodies as _kv_close_bodies
 from ..storage.kv import close_wave as _kv_close_wave
+from ..storage.kv import open_bodies as _kv_open_bodies
 from ..storage.kv import open_wave as _kv_open_wave
+from ..storage.logdb import RecordBodies
 from ..trace import LatencySampler, Profiler, flight_recorder
 from ..types import (
     Entry,
@@ -1467,6 +1470,9 @@ class VectorEngine:
         # crash teardown flag (stop(flush=False)): the loop discards its
         # un-decoded in-flight step instead of landing it
         self._discard_pending = False
+        # the batch-record bodies that the loop's save waves share among
+        # co-hosted NodeHosts' logdbs (_save_updates): two waves' worth
+        self._record_bodies = RecordBodies()
         # ---- host sharing (handles) --------------------------------------
         self._hosts_mu = threading.Lock()
         self._host_refs: Set[int] = set()
@@ -1992,6 +1998,7 @@ class VectorEngine:
             import traceback
 
             traceback.print_exc()
+        self._record_bodies.clear()
         prof.close()
 
     def snapshot_status_ready(self, node) -> None:
@@ -3398,8 +3405,7 @@ class VectorEngine:
             t1, c1 = time.monotonic(), time.thread_time()
             parts = _kv_open_wave()
         try:
-            if updates:
-                self._save_updates(updates, lane_saves)
+            self._save_updates(updates, lane_saves)
         finally:
             if parts is not None:
                 _kv_close_wave()
@@ -3445,6 +3451,8 @@ class VectorEngine:
             prof.fold("n.save_bytes", n_bytes)
         prof.fold("n.save_wal_bytes", parts["wal_bytes"])
         prof.fold("n.save_wal_records", parts["wal_records"])
+        prof.fold("n.save_entries", parts["entries"])
+        prof.fold("n.save_entries_shared", parts["entries_shared"])
 
     def _decode_send_post(self, o: dict) -> None:
         """Phase 3: post-fsync sends (votes, responses, heartbeats) plus
@@ -3643,27 +3651,40 @@ class VectorEngine:
         touched logdb shard with the durability barrier deferred, then one
         parallel sync over every touched WAL — group commit across shards
         AND across co-hosted NodeHosts' logdbs (a shared core hosts lanes
-        from several hosts, each with its own WAL)."""
+        from several hosts, each with its own WAL). The replicas of a
+        group that the core co-hosts save the same Entry objects, in this
+        wave or (one step a launch) in the next: the logdbs under the
+        wave find the loop's record bodies on the thread and encode a
+        group's entries once, not once a replica."""
         if self._next_host <= 1:
-            self._logdb.save_raft_state(updates)
+            if updates:
+                self._logdb.save_raft_state(updates)
             return
-        if len(lane_saves) == 1:
-            lane_saves[0][0].node.logdb.save_raft_state(updates)
+        bodies = self._record_bodies
+        bodies.turn()
+        if not updates:
             return
-        by_db: Dict[int, tuple] = {}
-        for (lane, _e, _s), ud in zip(lane_saves, updates):
-            db = lane.node.logdb
-            ent = by_db.get(id(db))
-            if ent is None:
-                ent = by_db[id(db)] = (db, [])
-            ent[1].append(ud)
-        pending = []
-        for db, uds in by_db.values():
-            deferred = getattr(db, "save_raft_state_deferred", None)
-            if deferred is not None:
-                pending.extend(deferred(uds))
-            else:
-                db.save_raft_state(uds)
+        _kv_open_bodies(bodies)
+        try:
+            if len(lane_saves) == 1:
+                lane_saves[0][0].node.logdb.save_raft_state(updates)
+                return
+            by_db: Dict[int, tuple] = {}
+            for (lane, _e, _s), ud in zip(lane_saves, updates):
+                db = lane.node.logdb
+                ent = by_db.get(id(db))
+                if ent is None:
+                    ent = by_db[id(db)] = (db, [])
+                ent[1].append(ud)
+            pending = []
+            for db, uds in by_db.values():
+                deferred = getattr(db, "save_raft_state_deferred", None)
+                if deferred is not None:
+                    pending.extend(deferred(uds))
+                else:
+                    db.save_raft_state(uds)
+        finally:
+            _kv_close_bodies()
         _kv_sync_all(pending)
 
     def _fetch_from_log(self, lane: _Lane, lo: int, hi: int):

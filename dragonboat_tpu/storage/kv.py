@@ -49,9 +49,16 @@ class _Wave(threading.local):
     path under it adds to it and reads a clock only where it finds one,
     so an unsampled wave pays a few `is None` tests a shard write and a
     barrier wave, and another thread's write to the same store (a
-    snapshot worker's, a compaction's) finds None."""
+    snapshot worker's, a compaction's) finds None.
+
+    `bodies` is the table of batch-record bodies that the wave on THIS
+    thread shares among the logdbs it writes (logdb.RecordBodies), or
+    None: the engine loop sets it around every wave that spans co-hosted
+    NodeHosts (open_bodies, close_bodies), sampled or not, and reads no
+    clock for it."""
 
     parts: Optional[dict] = None
+    bodies: Optional[object] = None
 
 
 _wave = _Wave()
@@ -63,6 +70,7 @@ def open_wave() -> dict:
     parts = _wave.parts = {
         "encode": 0.0, "commit": 0.0, "table": 0.0, "sync": 0.0,
         "sync_cpu": 0.0, "wal_bytes": 0, "wal_records": 0,
+        "entries": 0, "entries_shared": 0,
     }
     return parts
 
@@ -75,6 +83,22 @@ def wave_parts() -> Optional[dict]:
     """The parts of the save wave that the calling thread is timing, or
     None: what the write path under open_wave() adds to."""
     return _wave.parts
+
+
+def open_bodies(bodies) -> None:
+    """Share `bodies` among the logdbs that the calling thread's save
+    wave writes, until close_bodies()."""
+    _wave.bodies = bodies
+
+
+def close_bodies() -> None:
+    _wave.bodies = None
+
+
+def wave_bodies():
+    """The record bodies that the calling thread's save wave shares, or
+    None."""
+    return _wave.bodies
 
 
 class WriteBatch:
@@ -670,8 +694,11 @@ __all__ = [
     "WalKV",
     "barrier_stats",
     "reset_barrier_stats",
+    "close_bodies",
     "close_wave",
+    "open_bodies",
     "open_wave",
     "sync_all",
+    "wave_bodies",
     "wave_parts",
 ]

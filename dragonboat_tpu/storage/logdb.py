@@ -24,7 +24,45 @@ from ..raftio import (
 from ..settings import hard
 from ..types import Bootstrap, Entry, Snapshot, State, Update
 from . import keys
-from .kv import IKVStore, MemKV, WalKV, WriteBatch, sync_all, wave_parts
+from .kv import (
+    IKVStore, MemKV, WalKV, WriteBatch, sync_all, wave_bodies, wave_parts,
+)
+
+
+class RecordBodies:
+    """What the batch records that the engine loop's save waves have made
+    hold, for the co-hosted replicas of a group to take instead of
+    encoding the same Entry objects again: for every slice of a run (at
+    most BATCH consecutive entries, cut at multiples of BATCH) the value
+    of the record that holds just that slice, count and joined
+    encodings, as made in the wave under way (`now`) or in the wave
+    before it (`old`; in the one-step loop a follower saves one launch
+    after its leader). It is the bytes object itself that is shared, so
+    a replica that takes it allocates nothing and the stores' tables
+    hold one copy a group, not one a replica. A value is found by the
+    identity of its slice's first and last Entry. The row keeps both
+    alive, so that neither id can be reused while it stands, and the key
+    also holds the index and term of both ends as they were, so an entry
+    that was placed again since does not match: Raft's log matching
+    makes what lies between equal too. Nothing outlives the second wave;
+    it belongs to one thread."""
+
+    __slots__ = ("now", "old")
+
+    def __init__(self) -> None:
+        self.now: dict = {}
+        self.old: dict = {}
+
+    def __len__(self) -> int:
+        return len(self.now) + len(self.old)
+
+    def turn(self) -> None:
+        """A wave opens: the older wave's bodies go."""
+        if self.now or self.old:
+            self.old, self.now = self.now, {}
+
+    def clear(self) -> None:
+        self.now, self.old = {}, {}
 
 
 class _Shard:
@@ -44,7 +82,8 @@ class _Shard:
         # (cf. internal/logdb/rdbcache.go:24-116)
         self._state_cache = {}
         self._max_index_cache = {}
-        # (cid, nid) -> (batch_id, entries of that batch as last written)
+        # (cid, nid) -> (batch_id, entries of that batch as last written,
+        # the record's value; None once a compaction has cut the record)
         self._batch_cache = {}
         self._mu = threading.Lock()
         # writer lock: the append path's boundary-batch read-modify-write
@@ -75,11 +114,12 @@ class _Shard:
         commit of it."""
         with self._wmu:
             parts = wave_parts()
+            bodies = wave_bodies()
             if parts is not None:
                 t0 = time.monotonic()
             wb = WriteBatch()
             for ud in updates:
-                self._record_update(wb, ud)
+                self._record_update(wb, ud, bodies, parts)
             if parts is not None:
                 t1 = time.monotonic()
                 parts["encode"] += t1 - t0
@@ -88,55 +128,124 @@ class _Shard:
                 parts["commit"] += time.monotonic() - t1
             return self.kv if owes else None
 
-    def _save_entries(self, wb: WriteBatch, cid: int, nid: int, ents) -> None:
-        """Pack entries into batch records, merging the head batch with any
-        retained prefix (a rewrite from mid-batch keeps the entries below
-        the rewrite point, cf. batch.go:60-126 merge rules). The cache
-        keeps each entry's ENCODED bytes alongside it, so rewriting a batch
-        head re-joins cached parts instead of re-encoding every retained
-        entry (the encode was a measured save-path hot spot)."""
+    def _retained(self, cid: int, nid: int, bid: int, first: int):
+        """What a run that starts mid-record at `first` keeps of record
+        `bid`: the entries below `first` and their joined encodings (the
+        merge rules of batch.go:60-126). Appending to the record that
+        the cache holds whole takes them from the cached value; a
+        rewrite from inside it, a cache that a compaction cut, and a
+        cold cache read back from the store encode the kept entries one
+        by one."""
+        with self._mu:
+            cached = self._batch_cache.get((cid, nid))
+        if cached is not None and cached[0] == bid:
+            existing, value = cached[1], cached[2]
+        else:
+            raw = self.kv.get_value(keys.batch_key(cid, nid, bid))
+            existing = codec.decode_entries(raw)[0] if raw else []
+            value = None
+        keep = 0
+        for e in existing:  # ascending; retained prefix is e.index < first
+            if e.index >= first:
+                break
+            keep += 1
+        if keep == len(existing) and value is not None:
+            return existing, codec.encoded_entries_body(value)
+        cur = existing[:keep]
+        return cur, b"".join([codec.encode_entry(e) for e in cur])
+
+    def _save_entries(
+        self, wb: WriteBatch, cid: int, nid: int, ents, bodies=None, parts=None,
+    ) -> None:
+        """Pack a run of entries into batch records, merging the head
+        record with any retained prefix. The run is walked by record
+        slice (at most BATCH consecutive entries, cut at multiples of
+        BATCH), and a record's value is taken from the wave's shared
+        bodies where a co-hosted replica of the group has already made
+        it from the same Entry objects: the value of a batch record is
+        the same for every replica, only its key differs. The cache
+        keeps the tail record's entries with its value, so appending to
+        it re-encodes nothing. `bodies` and `parts` are the wave's own
+        (kv._Wave), where the caller writes inside one."""
+        B = self.BATCH
+        first = ents[0].index
+        n = len(ents)
+        bid = first // B
+        cur, body = self._retained(cid, nid, bid, first) if first % B else ([], b"")
+        if ents[-1].index - first + 1 != n:
+            self._save_entries_walk(wb, cid, nid, ents, bid, cur, body)
+            if parts is not None:
+                parts["entries"] += n
+            return
+        enc = codec.encode_entry
+        frame = codec.frame_encoded_entries
+        bkey = keys.batch_key
+        put = wb.put
+        if bodies is not None:
+            now, old = bodies.now, bodies.old
+        taken = 0
+        lo, hi = 0, B - first % B
+        while True:
+            if hi > n:
+                hi = n
+            row = key = None
+            count = len(cur) + hi - lo
+            if bodies is not None:
+                # the record's own ends: a retained prefix is the Entry
+                # objects this replica saved before, its co-hosted
+                # peers' too
+                a, z = cur[0] if cur else ents[lo], ents[hi - 1]
+                if z.index - a.index + 1 == count:
+                    key = (id(a), id(z), a.index, a.term, z.index, z.term)
+                    row = now.get(key) or old.get(key)
+            if row is None:
+                value = frame(count, body, *[enc(e) for e in ents[lo:hi]])
+                if key is not None:
+                    now[key] = (value, a, z)
+            else:
+                value = row[0]
+                taken += hi - lo
+            put(bkey(cid, nid, bid), value)
+            if hi == n:
+                break
+            bid += 1
+            cur, body = [], b""
+            lo, hi = hi, hi + B
+        with self._mu:
+            self._batch_cache[(cid, nid)] = (bid, cur + ents[lo:hi], value)
+        if parts is not None:
+            parts["entries"] += n
+            parts["entries_shared"] += taken
+
+    def _save_entries_walk(self, wb, cid, nid, ents, bid, cur, body) -> None:
+        """The per-entry walk, for a run whose indexes are not
+        consecutive (no caller in the package hands one in): every entry
+        goes to the record of its own index, and nothing is shared."""
         B = self.BATCH
         enc = codec.encode_entry
-        first = ents[0].index
-        bid = first // B
-        cur: list = []
-        parts: list = []
-        if first % B:
-            with self._mu:
-                cached = self._batch_cache.get((cid, nid))
-            if cached is not None and cached[0] == bid:
-                existing, eparts = cached[1], cached[2]
-            else:
-                raw = self.kv.get_value(keys.batch_key(cid, nid, bid))
-                existing = codec.decode_entries(raw)[0] if raw else []
-                eparts = None
-            keep = 0
-            for e in existing:  # ascending; retained prefix is e.index < first
-                if e.index >= first:
-                    break
-                keep += 1
-            cur = existing[:keep]
-            parts = (
-                eparts[:keep] if eparts is not None else [enc(e) for e in cur]
-            )
+        cur = list(cur)
+        pieces = [body]
         for e in ents:
             b = e.index // B
             if b != bid:
                 wb.put(
                     keys.batch_key(cid, nid, bid),
-                    codec.join_encoded_entries(parts),
+                    codec.frame_encoded_entries(len(cur), *pieces),
                 )
-                bid, cur, parts = b, [], []
+                bid, cur, pieces = b, [], []
             cur.append(e)
-            parts.append(enc(e))
-        wb.put(keys.batch_key(cid, nid, bid), codec.join_encoded_entries(parts))
+            pieces.append(enc(e))
+        value = codec.frame_encoded_entries(len(cur), *pieces)
+        wb.put(keys.batch_key(cid, nid, bid), value)
         with self._mu:
-            self._batch_cache[(cid, nid)] = (bid, cur, parts)
+            self._batch_cache[(cid, nid)] = (bid, cur, value)
 
-    def _record_update(self, wb: WriteBatch, ud: Update) -> None:
+    def _record_update(
+        self, wb: WriteBatch, ud: Update, bodies=None, parts=None
+    ) -> None:
         cid, nid = ud.cluster_id, ud.node_id
         if ud.entries_to_save:
-            self._save_entries(wb, cid, nid, ud.entries_to_save)
+            self._save_entries(wb, cid, nid, ud.entries_to_save, bodies, parts)
             last = ud.entries_to_save[-1].index
             self._set_max_index(wb, cid, nid, last)
         if ud.snapshot is not None and not ud.snapshot.is_empty():
@@ -503,4 +612,4 @@ class ShardedLogDB(ILogDB):
             sh._max_index_cache[(cid, node_id)] = ss.index
 
 
-__all__ = ["ShardedLogDB"]
+__all__ = ["RecordBodies", "ShardedLogDB"]

@@ -50,6 +50,7 @@ NEVER = 10 ** 9  # a sampling ratio no run reaches
 EMPTY = {
     "encode": 0.0, "commit": 0.0, "table": 0.0, "sync": 0.0,
     "sync_cpu": 0.0, "wal_bytes": 0, "wal_records": 0,
+    "entries": 0, "entries_shared": 0,
 }
 
 
