@@ -200,6 +200,10 @@ class ExecEngine:
             self._nodes[node.cluster_id] = node
         self.set_node_ready(node.cluster_id)
 
+    def add_nodes(self, nodes) -> None:
+        for node in nodes:
+            self.add_node(node)
+
     def remove_node(self, cluster_id: int) -> None:
         with self._nodes_mu:
             self._nodes.pop(cluster_id, None)

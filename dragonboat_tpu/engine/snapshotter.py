@@ -73,20 +73,22 @@ class FileCollection(ISnapshotFileCollection):
 class Snapshotter:
     """Per-node snapshot manager (cf. snapshotter.go:55-78)."""
 
-    def __init__(self, root_dir: str, cluster_id: int, node_id: int, logdb) -> None:
+    def __init__(self, root_dir: str, cluster_id: int, node_id: int, logdb,
+                 listed: Optional[set] = None) -> None:
         self.cluster_id = cluster_id
         self.node_id = node_id
         self._logdb = logdb
-        self._dir = os.path.join(
-            root_dir, f"snapshot-part-{cluster_id:020d}-{node_id:020d}"
-        )
+        name = f"snapshot-part-{cluster_id:020d}-{node_id:020d}"
+        self._dir = os.path.join(root_dir, name)
         self._mu = threading.Lock()
         self._sm = None
         # lazy dir: a node that never snapshots never touches the fs — at
         # 50k groups the per-cluster mkdir+orphan scan was a measured third
         # of fleet bring-up. Orphan processing only matters if the dir
-        # already exists (a previous incarnation wrote into it).
-        if os.path.isdir(self._dir):
+        # already exists (a previous incarnation wrote into it). `listed`
+        # is root_dir's entries where the caller listed it once for many
+        # nodes: one system call a bring-up, not one a replica.
+        if (name in listed) if listed is not None else os.path.isdir(self._dir):
             self.process_orphans()
 
     def bind_sm(self, sm) -> None:

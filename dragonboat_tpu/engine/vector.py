@@ -125,6 +125,8 @@ from ..storage.kv import open_wave as _kv_open_wave
 from ..storage.logdb import RecordBodies
 from ..trace import LatencySampler, Profiler, flight_recorder
 from ..types import (
+    ConfigChange,
+    ConfigChangeType,
     Entry,
     EntryType,
     Message,
@@ -487,8 +489,25 @@ class VectorNode(Node):
         """A config change committed and passed the membership legality
         checks: reconcile the device lane (slot remap) on the engine loop.
         The new member's address registers host-wide first (base-class
-        seam): the replicated entry is every replica's routing source."""
+        seam): the replicated entry is every replica's routing source.
+
+        A bootstrap entry that adds a member the lane was activated with
+        changes nothing on the device: the lane starts with every member
+        of its bootstrap, as the reference's peer does, and the applied
+        image only grows back to it, one entry at a time. Remapping at
+        each would renumber the lane's slots on the way, and replicas
+        that apply at different times would number differently meanwhile
+        (no device route between them, and a lane that counts one voter)."""
         self._register_cc_address(cc)
+        lane = self._vec_lane
+        if (
+            cc.initialize
+            and cc.type == ConfigChangeType.ADD_NODE
+            and lane is not None
+            and lane.mem_sig is not None
+            and cc.node_id in lane.mem_sig[0]
+        ):
+            return
         self.engine.membership_changed(self)
 
     def config_change_processed(self, key: int, accepted: bool) -> None:
@@ -1432,6 +1451,10 @@ class VectorEngine:
         G = self.kcfg.groups
         self._m_resid = np.zeros(G, np.int32)
         self._pending_rep_copies: list = []
+        # what _drop_parked staged for _flush_patch: lanes whose residual
+        # rows go, and (lane, gone) whose payload copies go
+        self._resid_drop: List[int] = []
+        self._copies_drop: list = []
         self._routes_dirty = True
         # auto: does the route table route every peer slot of every
         # active lane (and at least one)? Set by _rebuild_routes.
@@ -1456,6 +1479,15 @@ class VectorEngine:
         # from several NodeHosts (hosts = handle ids), so cluster_id alone
         # does not identify a lane
         self._lanes: Dict[tuple, _Lane] = {}
+        # the same lanes by host: a host's own look at its lanes
+        # (leader_snapshot, lane_stats through its handle) costs its lanes,
+        # not every lane of the core
+        self._host_lanes: Dict[int, Dict[tuple, _Lane]] = {}
+        # bumped wherever a lane joins, leaves, activates or deactivates:
+        # the active lanes of a host, as _active_lanes last listed them,
+        # stand until it moves
+        self._lanes_gen = 0
+        self._active_cache: Dict[Optional[int], tuple] = {}
         # (cluster_id, node_id) -> lane, for in-core message short-circuit
         self._route: Dict[tuple, _Lane] = {}
         # ghost lanes from the sharded round-up are NOT capacity: the
@@ -1465,6 +1497,16 @@ class VectorEngine:
         self._lanes_mu = threading.RLock()
         self._reconq: deque = deque()  # host->device ops, loop-applied
         self._patch: Optional[dict] = None  # see _staged_patch
+        # the bring-up account (bringup_stats): each host's start_clusters
+        # and its parts, the wall seconds of lane activation, and the
+        # launches from the first lane activated to the first launch after
+        # which every active lane knows a leader. Recorded once a host and
+        # once a bring-up: nothing per launch once the fleet has led.
+        self._bring_hosts: Dict[int, dict] = {}
+        self._bring_activate_s = 0.0
+        self._bring_activated = 0
+        self._bring_launch0: Optional[int] = None
+        self._bring_elect: Optional[int] = None
         self._stopped = threading.Event()
         self._ready = threading.Event()
         # crash teardown flag (stop(flush=False)): the loop discards its
@@ -1731,18 +1773,41 @@ class VectorEngine:
 
     # --------------------------------------------------------- registration
     def add_node(self, node: VectorNode, host: int = 0) -> None:
+        self.add_nodes([node], host)
+
+    def add_nodes(self, nodes, host: int = 0) -> None:
+        """Give each node a lane of `host`; the loop activates them in ONE
+        batch (one scatter, one compile bucket, one launch that finds them
+        all), however many a NodeHost's start_clusters brings."""
+        lanes: List[_Lane] = []
+        try:
+            for node in nodes:
+                lanes.append(self._take_lane(node, host))
+        finally:
+            if lanes:
+                self._reconq.append(("activate", lanes))
+                # dirty, not armed for request GC: a new node has no
+                # requests yet, and its first one arms it
+                # (set_node_ready)
+                with self._dirty_mu:
+                    self._dirty.update(lane.key for lane in lanes)
+                self._kick()
+
+    def _take_lane(self, node: VectorNode, host: int) -> _Lane:
         key = (host, node.cluster_id)
-        lane = None
         for attempt in range(2):
             with self._lanes_mu:
                 if self._free:
                     g = self._free.pop()
                     lane = _Lane(g, node, key=key)
                     self._lanes[key] = lane
+                    self._host_lanes.setdefault(host, {})[key] = lane
                     self._lane_by_g[g] = lane
                     self._route[(node.cluster_id, node.node_id())] = lane
                     self._m_host[g] = host
-                    break
+                    self._lanes_gen += 1
+                    node._vec_lane = lane
+                    return lane
             if attempt == 0:
                 # the free list can be momentarily empty while freed lanes
                 # sit in the reconcile queue (stop_cluster immediately
@@ -1750,28 +1815,25 @@ class VectorEngine:
                 # restart is never failed by its own predecessor's
                 # not-yet-reaped lane
                 self.drain(10.0)
-        if lane is None:
-            raise RuntimeError(
-                f"vector engine lane capacity ({self.kcfg.groups}) exhausted"
-            )
-        node._vec_lane = lane
-        self._reconq.append(("activate", lane))
-        self.set_node_ready(key)
+        raise RuntimeError(
+            f"vector engine lane capacity ({self.kcfg.groups}) exhausted"
+        )
 
     def remove_node(self, key) -> None:
         with self._lanes_mu:
             lane = self._lanes.pop(key, None)
             if lane is not None:
+                self._host_lanes[key[0]].pop(key, None)
+                self._lanes_gen += 1
                 rk = (lane.node.cluster_id, lane.node.node_id())
                 if self._route.get(rk) is lane:
                     del self._route[rk]
         if lane is not None:
             self._reconq.append(("deactivate", lane))
-            self._ready.set()
+            self._kick()
 
     def get_node(self, key):
-        with self._lanes_mu:
-            lane = self._lanes.get(key)
+        lane = self._lanes.get(key)  # one dict read: no lock to queue on
         return lane.node if lane is not None else None
 
     def lease_valid(self, key) -> bool:
@@ -1783,18 +1845,28 @@ class VectorEngine:
         return lane is not None and bool(self._m_lease_ok[lane.g])
 
     # -------------------------------------------------------------- wakeups
+    def _kick(self) -> None:
+        """Wake the loop for work queued just before. An event that is set
+        needs no second set: the loop clears it before it drains, so what
+        was queued before this look is drained by the iteration that
+        follows. The look is a plain read; a set takes the event's lock,
+        which every submitting thread and apply worker would otherwise
+        queue on while the loop is busy."""
+        if not self._ready.is_set():
+            self._ready.set()
+
     def set_node_ready(self, key) -> None:
         with self._dirty_mu:
             self._dirty.add(key)
             self._gc_set.add(key)
-        self._ready.set()
+        self._kick()
 
     def _wake(self, key) -> None:
         """Like set_node_ready but without arming request GC — the hot path
         for message delivery (messages alone never need a timeout sweep)."""
         with self._dirty_mu:
             self._dirty.add(key)
-        self._ready.set()
+        self._kick()
 
     def global_tick(self, host: int = 0) -> None:
         """One logical tick for every lane of `host` (replaces per-lane
@@ -1802,7 +1874,7 @@ class VectorEngine:
         array, per owning host)."""
         with self._dirty_mu:
             self._pending_ticks[host] = self._pending_ticks.get(host, 0) + 1
-        self._ready.set()
+        self._kick()
 
     def set_task_ready(self, key) -> None:
         self.task_ready.notify(key)
@@ -1883,7 +1955,7 @@ class VectorEngine:
         if woke:
             with self._dirty_mu:
                 self._dirty.update(woke)
-            self._ready.set()
+            self._kick()
         return rest
 
     def set_host_partitioned(self, host: int, partitioned: bool) -> None:
@@ -1949,19 +2021,19 @@ class VectorEngine:
         """Called on a task worker when a config change applies; the loop
         recomputes the canonical slot mapping from the SM membership."""
         self._reconq.append(("membership", node))
-        self._ready.set()
+        self._kick()
 
     def snapshot_restored(self, node: VectorNode, ss: Snapshot) -> None:
         self._reconq.append(("restore", node, ss))
-        self._ready.set()
+        self._kick()
 
     def cc_processed(self, node: VectorNode) -> None:
         self._reconq.append(("cc_done", node))
-        self._ready.set()
+        self._kick()
 
     def recover_done(self, node: VectorNode) -> None:
         self._reconq.append(("recover_done", node))
-        self._ready.set()
+        self._kick()
 
     # ---------------------------------------------------------------- loop
     def _loop(self) -> None:
@@ -3373,6 +3445,11 @@ class VectorEngine:
                 lane.node._leader_event(lane.rev.get(lslot - 1, 0), term)
             st["leader_changes"] += lead_n
             st["elections_started"] += elect_n
+        if self._bring_elect is None and self._bring_launch0 is not None:
+            # the bring-up account's election count, until it is known
+            act = self._m_active
+            if not (act & (self._m_leader == 0)).any():
+                self._bring_elect = self.launch_no - self._bring_launch0
 
     def _decode_send_rep(self, o: dict) -> None:
         """Phase 1: Replicate messages leave BEFORE the fsync."""
@@ -4475,7 +4552,7 @@ class VectorEngine:
             except IndexError:
                 break
             if op[0] == "activate":
-                batch.append(op[1])
+                batch.extend(op[1])
                 continue
             if op[0] == "cc_done":
                 # staged below with the other lane patches: one fixed-
@@ -4539,19 +4616,14 @@ class VectorEngine:
         for `lane`: its rows of the residual inbox and the payload copies
         that wait on their acceptance; of a lane that is `gone` also the
         copies it was to be the source of. Lost messages to Raft, which
-        resends."""
+        resends. Staged, and applied by _flush_patch for every lane of
+        the iteration at once: a fleet's stop drops tens of thousands."""
         g = lane.g
-        self._m_resid[g] = 0
-        if self._resid is not None:
-            r = self._resid
-            self._resid = r._replace(
-                mtype=r.mtype.at[g].set(jnp.int32(MSG.NONE))
-            )
-            self._pending_rep_copies = [
-                c
-                for c in self._pending_rep_copies
-                if c[3] is not lane and not (gone and c[2] is lane)
-            ]
+        if self._m_resid[g]:
+            self._m_resid[g] = 0
+            self._resid_drop.append(g)
+        if self._pending_rep_copies:
+            self._copies_drop.append((lane, gone))
 
     def _stage_remap(self, lane: _Lane, perm: Dict[int, int], mem) -> dict:
         """Stage lane's re-ranked slots (perm: old slot -> new slot, from
@@ -4605,6 +4677,22 @@ class VectorEngine:
             self._state = _make_patch_fn(self.kcfg)(
                 self._state, {k: jnp.asarray(a) for k, a in v.items()}
             )
+        if self._resid_drop:
+            gs, self._resid_drop = self._resid_drop, []
+            mask = np.zeros((self.kcfg.groups, 1), bool)
+            mask[gs] = True
+            r = self._resid
+            self._resid = r._replace(
+                mtype=jnp.where(mask, jnp.int32(MSG.NONE), r.mtype)
+            )
+        if self._copies_drop:
+            drop, self._copies_drop = self._copies_drop, []
+            to = {lane for lane, _ in drop}
+            gone = {lane for lane, was in drop if was}
+            self._pending_rep_copies = [
+                c for c in self._pending_rep_copies
+                if c[3] not in to and c[2] not in gone
+            ]
 
     def _lane_of(self, node) -> Optional[_Lane]:
         lane = node._vec_lane
@@ -4660,8 +4748,6 @@ class VectorEngine:
             # initial start: membership enters the log as config-change
             # entries at term 1, committed immediately (core/peer.py:273-294)
             addrs = sorted(node._vec_addresses, key=lambda a: a.node_id)
-            from ..types import ConfigChange, ConfigChangeType
-
             for i, pa in enumerate(addrs):
                 cc = ConfigChange(
                     type=ConfigChangeType.ADD_NODE,
@@ -4836,7 +4922,17 @@ class VectorEngine:
     def _activate_batch(self, lanes: List[_Lane]) -> None:
         """Activate many lanes with ONE jitted scatter call — the engine
         analogue of ops/state.configure_groups_uniform. Batches pad to
-        power-of-4 buckets from 16 up so the compile caches hit."""
+        power-of-4 buckets from 16 up so the compile caches hit. Timed
+        into the bring-up account."""
+        t0 = time.monotonic()
+        if self._bring_launch0 is None:
+            self._bring_launch0 = self.launch_no
+        try:
+            self._activate_lanes(lanes)
+        finally:
+            self._bring_activate_s += time.monotonic() - t0
+
+    def _activate_lanes(self, lanes: List[_Lane]) -> None:
         vals: List[dict] = []
         gs: List[int] = []
         for lane in lanes:
@@ -4851,9 +4947,11 @@ class VectorEngine:
                 vals.append(v)
                 gs.append(lane.g)
                 lane.active = True
+                self._lanes_gen += 1
         if not vals:
             return
         n = len(vals)
+        self._bring_activated += n
         if self.profiler.sampling:
             self.profiler.fold("n.lanes_joined", n)
         # no bucket below 16: replicas that join a running core come one
@@ -4897,6 +4995,7 @@ class VectorEngine:
                 return
         self._staged_patch()["deact"][g] = True
         lane.active = False
+        self._lanes_gen += 1
         if self.profiler.sampling:
             self.profiler.fold("n.lanes_left", 1)
         # zero the freed lane's host planes so nothing leaks into the next
@@ -5281,48 +5380,64 @@ class VectorEngine:
             "staged_backlog": self._p_staged_backlog,
         }
 
-    def lane_stats(self) -> Dict[tuple, dict]:
+    def _active_lanes(self, host: Optional[int] = None):
+        """(active lanes, their lane indexes) of the core, or of one
+        host's, listed once for as long as no lane joins, leaves,
+        activates or deactivates; the caller reads the columns by index."""
+        gen = self._lanes_gen  # first: a bump after it lists again
+        hit = self._active_cache.get(host)
+        if hit is not None and hit[0] == gen:
+            return hit[1], hit[2]
+        with self._lanes_mu:
+            src = self._lanes if host is None else self._host_lanes.get(host, {})
+            lanes = [lane for lane in src.values() if lane.active]
+        gs = np.fromiter((lane.g for lane in lanes), np.int64, len(lanes))
+        self._active_cache[host] = (gen, lanes, gs)
+        return lanes, gs
+
+    def lane_stats(self, host: Optional[int] = None) -> Dict[tuple, dict]:
         """Per-lane introspection derived ENTIRELY from the numpy mirrors
         the decode phase already maintains — zero device syncs: lane key ->
-        {node_id, leader_id, term, commit_gap, ticks_since_leader_change}.
+        {node_id, leader_id, term, commit_gap, ticks_since_leader_change}
+        of every active lane, or of one host's.
         commit_gap is last_index - commit_index in device units (how far
         the lane's accepted log runs ahead of its quorum commit — a
         persistently large gap flags a lane that cannot reach quorum).
         Exported ~1/s by NodeHost._export_health_gauges as cluster_id-
         labelled engine_lane_* gauges."""
-        out: Dict[tuple, dict] = {}
-        with self._lanes_mu:
-            lanes = list(self._lanes.values())
-        leader = self._m_leader
-        term = self._m_term
-        commit = self._m_commit
-        last = self._m_last
-        role = self._m_role
-        chg = self._m_leader_change_tick
+        lanes, gs = self._active_lanes(host)
+        last = self._m_last[gs]
         tick = self.clock.tick
-        for lane in lanes:
-            if not lane.active:
-                continue
-            g = lane.g
-            out[lane.key] = {
+        cols = zip(
+            lanes,
+            (self._m_leader[gs] - 1).tolist(),
+            self._m_term[gs].tolist(),
+            np.maximum(last - self._m_commit[gs], 0).tolist(),
+            last.tolist(),
+            np.maximum(tick - self._m_leader_change_tick[gs], 0).tolist(),
+            self._m_role[gs].tolist(),
+        )
+        return {
+            lane.key: {
                 "node_id": lane.node.node_id(),
-                "leader_id": lane.rev.get(int(leader[g]) - 1, 0),
-                "term": int(term[g]),
-                "commit_gap": max(int(last[g] - commit[g]), 0),
+                "leader_id": lane.rev.get(lslot, 0),
+                "term": term,
+                "commit_gap": gap,
                 # monotonic append high-water mark in device units: the
                 # placement plane's ingest-rate signal is the DELTA of
                 # this between two load folds (serving/placement.py) —
                 # still a pure mirror read, zero device syncs
-                "last_index": int(last[g]),
-                "ticks_since_leader_change": max(int(tick - chg[g]), 0),
+                "last_index": li,
+                "ticks_since_leader_change": since,
                 # lane-variant probes: the replica's role (observer/witness
                 # lanes included) and resident client-payload bytes — a
                 # witness lane must report payload_bytes == 0 (the
                 # observer_witness_churn verdict and tests assert on it)
-                "role": int(role[g]),
+                "role": role,
                 "payload_bytes": lane.arena.payload_bytes,
             }
-        return out
+            for lane, lslot, term, gap, li, since, role in cols
+        }
 
     def hot_lane_stats(
         self, k: int, host: Optional[int] = None
@@ -5383,23 +5498,47 @@ class VectorEngine:
             }
         return out, total
 
-    def leader_snapshot(self) -> Dict[tuple, Tuple[int, int]]:
+    def leader_snapshot(
+        self, host: Optional[int] = None
+    ) -> Dict[tuple, Tuple[int, int]]:
         """One vectorized pass over the numpy mirrors: lane key ->
-        (leader_node_id, term) for every active lane. Replaces per-group
-        get_leader_id polling at fleet bring-up (50k lanes = one call)."""
-        out: Dict[tuple, Tuple[int, int]] = {}
-        with self._lanes_mu:
-            lanes = list(self._lanes.values())
-        leader = self._m_leader
-        term = self._m_term
-        for lane in lanes:
-            if not lane.active:
-                continue
-            g = lane.g
-            out[lane.key] = (
-                lane.rev.get(int(leader[g]) - 1, 0), int(term[g])
+        (leader_node_id, term) for every active lane, or for one host's.
+        Replaces per-group get_leader_id polling at fleet bring-up (50k
+        lanes = one call)."""
+        lanes, gs = self._active_lanes(host)
+        return {
+            lane.key: (lane.rev.get(lslot, 0), term)
+            for lane, lslot, term in zip(
+                lanes,
+                (self._m_leader[gs] - 1).tolist(),
+                self._m_term[gs].tolist(),
             )
-        return out
+        }
+
+    def note_start_clusters(self, host: int, parts: dict) -> None:
+        """A NodeHost's start_clusters, timed by its parts (seconds and
+        nodes), into the bring-up account: summed over its calls."""
+        mine = self._bring_hosts.setdefault(host, {})
+        for name, v in parts.items():
+            mine[name] = mine.get(name, 0) + v
+
+    def bringup_stats(self) -> dict:
+        """The bring-up account: `start_clusters_s` (the wall seconds of
+        every host's start_clusters, summed over hosts) with each host's
+        parts under `hosts`; `activate_s` (lane activation: the host half
+        and the batched scatter's dispatch, summed over batches) and
+        `activated` lanes; `elect_launches`, the kernel launches from the
+        first lane activated to the first launch after which every
+        active lane knows a leader (None until then)."""
+        hosts = {h: dict(p) for h, p in self._bring_hosts.items()}
+        return {
+            "start_clusters_s": sum(p.get("total_s", 0.0) for p in hosts.values()),
+            "hosts": hosts,
+            "activate_s": self._bring_activate_s,
+            "activated": self._bring_activated,
+            "first_activation_launch": self._bring_launch0,
+            "elect_launches": self._bring_elect,
+        }
 
     def attach_host(self) -> int:
         with self._hosts_mu:
@@ -5484,6 +5623,12 @@ class VectorEngineHandle:
     def add_node(self, node) -> None:
         self.core.add_node(node, self.host)
 
+    def add_nodes(self, nodes) -> None:
+        self.core.add_nodes(nodes, self.host)
+
+    def note_start_clusters(self, parts: dict) -> None:
+        self.core.note_start_clusters(self.host, parts)
+
     def remove_node(self, cluster_id: int) -> None:
         self.core.remove_node((self.host, cluster_id))
 
@@ -5520,16 +5665,13 @@ class VectorEngineHandle:
         """cluster_id -> (leader_node_id, term) for this host's lanes."""
         return {
             key[1]: v
-            for key, v in self.core.leader_snapshot().items()
-            if key[0] == self.host
+            for key, v in self.core.leader_snapshot(self.host).items()
         }
 
     def lane_stats(self) -> Dict[int, dict]:
         """cluster_id -> per-lane introspection for this host's lanes."""
         return {
-            key[1]: v
-            for key, v in self.core.lane_stats().items()
-            if key[0] == self.host
+            key[1]: v for key, v in self.core.lane_stats(self.host).items()
         }
 
     def lane_counters(self) -> Dict[int, Dict[str, int]]:

@@ -168,6 +168,11 @@ class MetricsRegistry:
         with self._mu:
             self._gauges.setdefault(name, {})[key] = value
 
+    def set_gauges(self, name: str, values: Dict[_LabelKey, float]) -> None:
+        """Many series of one gauge family under one lock round trip."""
+        with self._mu:
+            self._gauges.setdefault(name, {}).update(values)
+
     def counter_value(self, name: str, key: _LabelKey) -> float:
         with self._mu:
             return self._counters.get(name, {}).get(key, 0.0)

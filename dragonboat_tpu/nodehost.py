@@ -528,9 +528,9 @@ class NodeHost(IMessageHandler):
             self.logdb.save_bootstrap_info(
                 cfg.cluster_id, cfg.node_id, bootstrap
             )
-        self._launch_node(
+        self.engine.add_node(self._launch_node(
             initial_members, join, sm_factory, cfg, bootstrap, new_node
-        )
+        ))
 
     def _prepare_cluster(self, initial_members, join, sm_factory, cfg: Config):
         """Shared validation + SM-type probing + bootstrap construction for
@@ -560,6 +560,7 @@ class NodeHost(IMessageHandler):
         launch, README.md:48-51)."""
         if self._stopped.is_set():
             raise ErrClusterClosed()
+        t0 = time.monotonic()
         prepared = []
         boots = []
         seen: set = set()
@@ -575,18 +576,42 @@ class NodeHost(IMessageHandler):
             prepared.append(
                 (initial_members, join, sm_factory, cfg, bootstrap, new_node)
             )
+        t1 = time.monotonic()
         # durability order preserved: every bootstrap record is on disk
         # before any of these nodes writes raft state
         if boots:
             self.logdb.save_bootstrap_infos(boots)
-        for initial_members, join, sm_factory, cfg, bootstrap, new in prepared:
-            self._launch_node(
-                initial_members, join, sm_factory, cfg, bootstrap, new
-            )
+        t2 = time.monotonic()
+        # the engine takes the launched nodes in one call, so a fleet's
+        # lanes activate together (every node's, if one launch raises)
+        try:
+            listed = set(os.listdir(self.snapshot_dir_root()))
+        except FileNotFoundError:
+            listed = set()
+        nodes = []
+        try:
+            for members, join, sm_factory, cfg, bootstrap, new in prepared:
+                nodes.append(self._launch_node(
+                    members, join, sm_factory, cfg, bootstrap, new, listed
+                ))
+        finally:
+            self.engine.add_nodes(nodes)
+        t3 = time.monotonic()
+        note = getattr(self.engine, "note_start_clusters", None)
+        if note is not None:
+            note({
+                "prepare_s": t1 - t0, "bootstrap_s": t2 - t1,
+                "launch_s": t3 - t2, "total_s": t3 - t0,
+                "nodes": len(nodes),
+            })
 
     def _launch_node(
-        self, initial_members, join, sm_factory, cfg, bootstrap, new_node
-    ) -> None:
+        self, initial_members, join, sm_factory, cfg, bootstrap, new_node,
+        listed=None,
+    ):
+        """Build one replica's node and register it with this host; the
+        caller hands it to the engine. `listed`: the snapshot root's
+        entries, where the caller listed them once for many nodes."""
         cluster_id, node_id = cfg.cluster_id, cfg.node_id
         addresses = bootstrap.addresses if not join else {}
         peer_addresses = [
@@ -597,7 +622,7 @@ class NodeHost(IMessageHandler):
             self.transport.nodes.add_node(cluster_id, nid, addr)
         log_reader = LogReader(cluster_id, node_id, self.logdb)
         snapshotter = Snapshotter(
-            self.snapshot_dir_root(), cluster_id, node_id, self.logdb
+            self.snapshot_dir_root(), cluster_id, node_id, self.logdb, listed
         )
         # restart path: position the window from snapshot + persisted log
         # BEFORE the protocol core launches and reads it (node.go:553-583)
@@ -639,7 +664,7 @@ class NodeHost(IMessageHandler):
         # (The activation path keeps its own idempotent call as the
         # race fallback.)
         node.recover_initial_snapshot()
-        self.engine.add_node(node)
+        return node
 
     def _bootstrap_cluster(
         self, initial_members, join, cfg: Config, smtype: int
@@ -1620,20 +1645,17 @@ class NodeHost(IMessageHandler):
             plane.export_gauges(self.metrics)
         lane_stats = getattr(self.engine, "lane_stats", None)
         if lane_stats is not None:
-            for cid, s in lane_stats().items():
-                key = (cid, s["node_id"])
-                self.metrics.set_gauge(
-                    "engine_lane_leader_id", key, float(s["leader_id"])
-                )
-                self.metrics.set_gauge(
-                    "engine_lane_term", key, float(s["term"])
-                )
-                self.metrics.set_gauge(
-                    "engine_lane_commit_gap", key, float(s["commit_gap"])
-                )
-                self.metrics.set_gauge(
-                    "engine_lane_ticks_since_leader_change", key,
-                    float(s["ticks_since_leader_change"]),
+            # one registry update a gauge family, not one a lane
+            rows = [((cid, s["node_id"]), s) for cid, s in lane_stats().items()]
+            for name, col in (
+                ("engine_lane_leader_id", "leader_id"),
+                ("engine_lane_term", "term"),
+                ("engine_lane_commit_gap", "commit_gap"),
+                ("engine_lane_ticks_since_leader_change",
+                 "ticks_since_leader_change"),
+            ):
+                self.metrics.set_gauges(
+                    name, {key: float(s[col]) for key, s in rows}
                 )
 
 

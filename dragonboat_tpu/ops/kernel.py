@@ -1376,7 +1376,8 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
     (the host dispatch order). Returns (dest, fields, (entry_terms,
     entry_cc)): ``dest`` is the destination lane per candidate (-1 = not
     a candidate), ``fields`` the ten scalar message columns in Inbox
-    staging order, and the entry planes carry Replicate payload metadata.
+    staging order, and the entry planes carry Replicate payload metadata
+    for the Replicate candidates alone, the first G * P of them.
     Lane indexes in ``route``/``dest`` are GLOBAL — on a sharded mesh a
     local block emits candidates addressed across the whole fleet."""
     G, P = s.member.shape
@@ -1439,18 +1440,15 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
     rep_terms = jnp.where(e_live, ring_t, 0)
     rep_cc = e_live & ring_cc
 
-    no_ents_gp = jnp.zeros((G, P, E), i32)
-    no_cc_gp = jnp.zeros((G, P, E), bool)
-
     # candidate field planes, kind-major (= the host dispatch order)
     kinds = (
         # (want, dest, mtype, from, term, log_index, log_term, commit,
-        #  reject, hint, hint2, n_entries, entry_terms, entry_cc)
+        #  reject, hint, hint2, n_entries)
         (
             rep_want, route, jnp.full((G, P), MSG.REPLICATE, i32), self_gp,
             term_gp, out.send_prev_index + rdelta, out.send_prev_term,
             jnp.maximum(out.send_commit + rdelta, 0), false_gp, zero_gp,
-            zero_gp, out.send_n_entries, rep_terms, rep_cc,
+            zero_gp, out.send_n_entries,
         ),
         (
             # the vote plane serves both election phases: a PRE_CANDIDATE
@@ -1460,7 +1458,7 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
             self_gp, jnp.where(precand_gp, term_gp + 1, term_gp),
             out.vote_last_index[:, None] + rdelta,
             jnp.broadcast_to(out.vote_last_term[:, None], (G, P)), zero_gp,
-            false_gp, out.send_hint, zero_gp, zero_gp, no_ents_gp, no_cc_gp,
+            false_gp, out.send_hint, zero_gp, zero_gp,
         ),
         (
             # log_index carries the lease round tag — an opaque tick stamp
@@ -1470,12 +1468,12 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
             term_gp, jnp.broadcast_to(out.lease_round[:, None], (G, P)),
             zero_gp,
             jnp.maximum(out.send_hb_commit + rdelta, 0), false_gp,
-            out.send_hint, out.send_hint2, zero_gp, no_ents_gp, no_cc_gp,
+            out.send_hint, out.send_hint2, zero_gp,
         ),
         (
             tn_want, route, jnp.full((G, P), MSG.TIMEOUT_NOW, i32), self_gp,
             term_gp, zero_gp, zero_gp, zero_gp, false_gp, zero_gp, zero_gp,
-            zero_gp, no_ents_gp, no_cc_gp,
+            zero_gp,
         ),
         (
             resp_want, resp_dest, out.resp_type,
@@ -1505,8 +1503,7 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
                 jnp.where(is_hbresp, out.resp_hint, 0),
             ),
             jnp.where(is_hbresp, out.resp_hint2, 0),
-            zero_gk, jnp.zeros((G, K, E), i32),
-            jnp.zeros((G, K, E), bool),
+            zero_gk,
         ),
         (
             rir_want, rir_dest, jnp.full((G, R), MSG.READ_INDEX_RESP, i32),
@@ -1514,19 +1511,16 @@ def _route_columns(s: RaftTensors, out: StepOutput, route, rdelta, cfg):
             jnp.broadcast_to(out.term[:, None], (G, R)),
             out.ready_index + rir_delta, zero_gr, zero_gr,
             jnp.zeros((G, R), bool), out.ready_ctx, out.ready_ctx2, zero_gr,
-            jnp.zeros((G, R, E), i32), jnp.zeros((G, R, E), bool),
         ),
     )
 
     def cat(col):
         return jnp.concatenate([k[col].reshape(-1) for k in kinds])
 
-    def cat_e(col):
-        return jnp.concatenate([k[col].reshape(-1, E) for k in kinds])
-
     dest = jnp.where(cat(0), cat(1), -1)
     fields = tuple(cat(c) for c in range(2, 12))
-    return dest, fields, (cat_e(12), cat_e(13))
+    # only Replicate carries entries, and its candidates come first
+    return dest, fields, (rep_terms.reshape(-1, E), rep_cc.reshape(-1, E))
 
 
 def _route_segments(P: int, K: int, R: int) -> Tuple[int, ...]:
@@ -1541,36 +1535,50 @@ def _route_scatter(dest, fields, efields, G: int, K: int):
     """Stable-sort the flattened candidates by destination lane and
     scatter the first K arrivals per destination into a fresh Inbox.
     Returns (inbox, routed) where ``routed`` is the flat per-candidate
-    accepted mask in the ORIGINAL (pre-sort) candidate order."""
+    accepted mask in the ORIGINAL (pre-sort) candidate order.
+
+    ``efields`` are the entry planes of the first ``len(efields[0])``
+    candidates (the Replicate kind); every later candidate carries none.
+
+    Every gather and scatter is sized by the inbox (G x K), not by the
+    candidates: inbox slot (g, k) takes the k-th candidate of lane g's
+    run in the sorted order, which starts where a binary search over
+    the sorted keys puts g. Per-candidate gathers were the router's
+    cost on the chip, and they grow with G x (4P + K + R)."""
     M = dest.shape[0]
-    E = efields[0].shape[1]
-    key = jnp.where(dest >= 0, dest, G)
-    order = jnp.argsort(key, stable=True)
-    skey = key[order]
-    first = jnp.searchsorted(skey, skey, side="left").astype(i32)
-    slot = jnp.arange(M, dtype=i32) - first
-    ok = (skey < G) & (slot < K)
-    row = jnp.where(ok, skey, G)  # G = out of bounds -> dropped by scatter
-    col = jnp.where(ok, slot, 0)
-
-    def scat(init, vals):
-        return init.at[row, col].set(vals[order], mode="drop")
-
-    nxt = Inbox(
-        mtype=scat(jnp.full((G, K), MSG.NONE, i32), fields[0]),
-        from_slot=scat(jnp.zeros((G, K), i32), fields[1]),
-        term=scat(jnp.zeros((G, K), i32), fields[2]),
-        log_index=scat(jnp.zeros((G, K), i32), fields[3]),
-        log_term=scat(jnp.zeros((G, K), i32), fields[4]),
-        commit=scat(jnp.zeros((G, K), i32), fields[5]),
-        reject=scat(jnp.zeros((G, K), bool), fields[6]),
-        hint=scat(jnp.zeros((G, K), i32), fields[7]),
-        hint_high=scat(jnp.zeros((G, K), i32), fields[8]),
-        n_entries=scat(jnp.zeros((G, K), i32), fields[9]),
-        entry_terms=scat(jnp.zeros((G, K, E), i32), efields[0]),
-        entry_cc=scat(jnp.zeros((G, K, E), bool), efields[1]),
+    n_rep = efields[0].shape[0]
+    key = jnp.where(dest >= 0, dest, G).astype(i32)
+    skey, order = jax.lax.sort(
+        (key, jnp.arange(M, dtype=i32)), num_keys=1, is_stable=True
     )
-    routed = jnp.zeros((M,), bool).at[order].set(ok)
+    lanes = jnp.arange(G, dtype=i32)
+    start = jnp.searchsorted(skey, lanes, side="left").astype(i32)
+    pos = jnp.minimum(start[:, None] + jnp.arange(K, dtype=i32), M - 1)
+    ok = skey[pos] == lanes[:, None]  # [G, K]; a short run ends early
+    src = jnp.where(ok, order[pos], 0)
+
+    def pick(default, vals):
+        return jnp.where(ok, vals[src], default)
+
+    rep = ok & (src < n_rep)
+    rsrc = jnp.where(rep, src, 0)
+    nxt = Inbox(
+        mtype=pick(MSG.NONE, fields[0]),
+        from_slot=pick(0, fields[1]),
+        term=pick(0, fields[2]),
+        log_index=pick(0, fields[3]),
+        log_term=pick(0, fields[4]),
+        commit=pick(0, fields[5]),
+        reject=pick(False, fields[6]),
+        hint=pick(0, fields[7]),
+        hint_high=pick(0, fields[8]),
+        n_entries=pick(0, fields[9]),
+        entry_terms=jnp.where(rep[..., None], efields[0][rsrc], 0),
+        entry_cc=rep[..., None] & efields[1][rsrc],
+    )
+    routed = jnp.zeros((M,), bool).at[jnp.where(ok, src, M)].set(
+        True, mode="drop"
+    )
     return nxt, routed
 
 
@@ -1693,10 +1701,13 @@ def _shard_route(
 
     # pack dest + the 10 scalar columns + the 2E entry columns into one
     # i32 slab so the cross-shard exchange is a single transfer
+    Ml = dest.shape[0]
     cols = [dest] + [f.astype(i32) for f in fields]
-    slab = jnp.concatenate(
-        [jnp.stack(cols)] + [ef.astype(i32).T for ef in efields]
-    )  # (C, Ml): dest, 10 scalar rows, then E entry_terms + E entry_cc rows
+    slab = jnp.concatenate([jnp.stack(cols)] + [
+        jnp.pad(ef.astype(i32).T, ((0, 0), (0, Ml - ef.shape[0])))
+        for ef in efields
+    ])  # (C, Ml): dest, 10 scalar rows, then E entry_terms + E entry_cc
+    # rows, filled over the Replicate candidates (the first Gl * P)
     g = jax.lax.all_gather(slab, axis_name, axis=0, tiled=False)  # (n, C, Ml)
 
     # splice per-shard segments back into the GLOBAL kind-major layout:
@@ -1714,10 +1725,8 @@ def _shard_route(
     gdest = gcols[0]
     gfields = list(gcols[1 : 11])
     gfields[6] = gfields[6].astype(bool)  # reject
-    ge_terms = jnp.stack([gcols[11 + e] for e in range(E)], axis=1)
-    ge_cc = jnp.stack(
-        [gcols[11 + E + e] for e in range(E)], axis=1
-    ).astype(bool)
+    ge_terms = gcols[11 : 11 + E, : G * P].T  # the Replicate kind's
+    ge_cc = gcols[11 + E : 11 + 2 * E, : G * P].T.astype(bool)
 
     nxt_g, routed_g = _route_scatter(
         gdest, tuple(gfields), (ge_terms, ge_cc), G, K

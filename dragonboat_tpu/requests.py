@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import random
 import struct
 import threading
 import time
@@ -339,6 +340,14 @@ class RequestState:
         return self._result
 
 
+# the random bases of proposal keys and ReadIndex contexts: seeded once
+# from the system, then drawn without a system call (a bring-up makes
+# eight a replica, and every system call on a busy process waits its turn
+# for the GIL); a forked child draws its own
+_RANDOM = random.Random(os.urandom(32))
+os.register_at_fork(after_in_child=lambda: _RANDOM.seed(os.urandom(32)))
+
+
 class LogicalClock:
     """Tick-driven clock for request GC (cf. requests.go:223-241)."""
 
@@ -375,9 +384,7 @@ class _ProposalShard:
         # per-request key can never collide with the BATCH_KEY_BIT
         # namespace (batch-tracked proposals route by batch id instead).
         self._key_seq = itertools.count(
-            ((int.from_bytes(os.urandom(6), "big") << 16)
-             & ((1 << 61) - 1)) + offset,
-            stride,
+            (_RANDOM.getrandbits(45) << 16) + offset, stride
         )
         self.stopped = False
 
@@ -592,7 +599,7 @@ class PendingReadIndex:
     def next_ctx(self) -> SystemCtx:
         return SystemCtx(
             low=next(self._ctx_seq),
-            high=int.from_bytes(os.urandom(8), "big") | 1,
+            high=_RANDOM.getrandbits(64) | 1,
         )
 
     def bind_queued(self, ctx: SystemCtx) -> bool:

@@ -493,7 +493,7 @@ def test_the_reader_is_one_counter_over_the_other():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    assert spec["per_layer"][-1] == {
+    assert next(m for m in spec["per_layer"] if m["name"] == name) == {
         "name": name, "unit": "share", "better": "higher",
         "source": "program_counter", "layer": "storage",
         "moves": "committed_ops_per_s",
