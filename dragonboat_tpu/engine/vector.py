@@ -84,7 +84,9 @@ from ..core.raft import _make_metadata_entries, _make_witness_snapshot
 from ..core.rate import ENTRY_OVERHEAD_BYTES
 from ..logger import get_logger
 from ..ops.kernel import (
-    make_multi_step_fn,
+    launch_in_slabs,
+    launch_out_slabs,
+    make_packed_multi_step_fn,
     make_sharded_multi_step_fn,
     make_step_fn,
 )
@@ -102,6 +104,8 @@ from ..ops.state import (
     Inbox,
     KernelConfig,
     RaftTensors,
+    RoutePlan,
+    StepOutput,
     init_state,
     lane_seed,
     make_empty_inbox,
@@ -159,16 +163,6 @@ _MESH_LAUNCH_MU = threading.Lock()
 # stage profiler's ratio (see VectorEngine.request_sampler)
 REQUEST_SAMPLE_FLOOR = 8
 
-
-class _NoLock:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NO_LOCK = _NoLock()
 
 MT = MessageType
 
@@ -1464,10 +1458,9 @@ class VectorEngine:
         # them a hard state
         self._m_commit_owed = np.zeros(G, bool)
         self._multi_fn = None
+        self._out_slabs = None  # what a packed K-step launch fetches
         self._resid = None
         self.census = None  # made below, once the planes exist
-        self._np_route = np.full((G, self.kcfg.peers), -1, np.int32)
-        self._np_rdelta = np.zeros((G, self.kcfg.peers), np.int32)
         if self._multi > 1:
             self._build_multi(self._multi)
         self._state: RaftTensors = init_state(self.kcfg)
@@ -1609,7 +1602,9 @@ class VectorEngine:
             )
             name = f"multi_step[g{G}.k{steps}.d{self._mesh_devices}]"
         else:
-            self._multi_fn = make_multi_step_fn(self.kcfg, steps)
+            # one chip: the launch puts two slabs and fetches two
+            self._multi_fn = make_packed_multi_step_fn(self.kcfg, steps)
+            self._out_slabs = launch_out_slabs(self.kcfg)
             name = f"multi_step[g{G}.k{steps}]"
         # no comma in the name: it becomes a Prometheus label value
         compile_watch().register(name, self._multi_fn)
@@ -1647,27 +1642,23 @@ class VectorEngine:
         )
 
     def _alloc_buffers(self) -> None:
-        # numpy staging buffers for the inbox. ONE set in every loop: a
+        # numpy staging buffers for a launch's inputs: the inbox, the tick
+        # plane and the K>1 route/delta planes. ONE set in every loop: a
         # step's output is fetched before the next _pack rewrites them, so
-        # the device has consumed them by then.
-        G, K = self.kcfg.groups, self.kcfg.inbox_depth
-        E = self.kcfg.max_entries_per_msg
-        self._buf = {
-            "mtype": np.full((G, K), MSG.NONE, np.int32),
-            "from_slot": np.zeros((G, K), np.int32),
-            "term": np.zeros((G, K), np.int32),
-            "log_index": np.zeros((G, K), np.int32),
-            "log_term": np.zeros((G, K), np.int32),
-            "commit": np.zeros((G, K), np.int32),
-            "reject": np.zeros((G, K), bool),
-            "hint": np.zeros((G, K), np.int32),
-            "hint_high": np.zeros((G, K), np.int32),
-            "n_entries": np.zeros((G, K), np.int32),
-            "entry_terms": np.zeros((G, K, E), np.int32),
-            "entry_cc": np.zeros((G, K, E), bool),
-        }
-        self._ticks = np.zeros((G,), np.int32)
-        self._host_inbox = Inbox(**{f: self._buf[f] for f in Inbox._fields})
+        # the device has consumed them by then. Every plane is a view
+        # into one of two slabs (ops/slab.py), which a one-chip K-step
+        # launch puts whole; the other launches put the planes.
+        slabs = launch_in_slabs(self.kcfg)
+        self._in_slabs = (
+            np.zeros(slabs.int_shape, np.int32),
+            np.zeros(slabs.bool_shape, bool),
+        )
+        self._host_inbox, self._ticks, self._np_route, self._np_rdelta = (
+            slabs.unpack(*self._in_slabs)
+        )
+        self._host_inbox.mtype.fill(MSG.NONE)
+        self._np_route.fill(-1)
+        self._buf = self._host_inbox._asdict()
         # columnar row staging for _pack: rows accumulate as python column
         # lists and land in the numpy planes as ONE fancy-indexed scatter
         # per plane (_flush_staged_rows) — list appends are ~4x cheaper
@@ -2227,23 +2218,35 @@ class VectorEngine:
         if sampling:
             prof.fold("n.launches", 1)
             prof.fold("n.launch_steps", self._multi)
+            prof.fold("n.seam_buffers", self._seam_arrays())
         t0 = time.monotonic() if sampling else 0.0
+        if self._multi > 1 and self._mesh is None:
+            # K protocol steps on one chip: the staging planes go as
+            # their two slabs, and the outputs come back as two, so the
+            # seam's cost per buffer is paid four times a launch
+            ints, bools = jax.device_put(self._in_slabs)
+            t1 = time.monotonic() if sampling else 0.0
+            self._state, out_ints, out_bools, self._resid = self._multi_fn(
+                self._state, ints, bools, self._resid
+            )
+            if sampling:
+                self._add_seam("put", "launch", t0, t1)
+            o, pl, rc = self._fetch_super((out_ints, out_bools))
+            self._m_resid = rc
+            self._decode_super(work, packs, o, pl)
+            return
         if self._multi > 1:
-            # K protocol steps per launch: the route/delta planes ride
-            # the same batched transfer (small G x P arrays; rebuilt
-            # host-side only when lane topology changes)
+            # K protocol steps per launch over the mesh: the route/delta
+            # planes ride the same batched transfer (small G x P arrays;
+            # rebuilt host-side only when lane topology changes)
             payload = (
                 self._host_inbox, self._ticks,
                 self._np_route, self._np_rdelta,
             )
-            mu = _MESH_LAUNCH_MU if self._mesh is not None else _NO_LOCK
-            with mu:
-                if self._multi_shardings is not None:
-                    inbox, tarr, route, rdelta = jax.device_put(
-                        payload, self._multi_shardings
-                    )
-                else:
-                    inbox, tarr, route, rdelta = jax.device_put(payload)
+            with _MESH_LAUNCH_MU:
+                inbox, tarr, route, rdelta = jax.device_put(
+                    payload, self._multi_shardings
+                )
                 t1 = time.monotonic() if sampling else 0.0
                 self._state, outs, plans, self._resid, resid_count = (
                     self._multi_fn(
@@ -2252,7 +2255,7 @@ class VectorEngine:
                 )
                 if sampling:
                     self._add_seam("put", "launch", t0, t1)
-                o, pl, rc = self._fetch_super(outs, plans, resid_count)
+                o, pl, rc = self._fetch_super((outs, plans, resid_count))
             self._m_resid = rc
             self._decode_super(work, packs, o, pl)
             return
@@ -2290,6 +2293,20 @@ class VectorEngine:
         self.profiler.add(first, t1 - t0)
         self.profiler.add(second, t2 - t1)
 
+    def _seam_arrays(self) -> int:
+        """The arrays the coming launch moves across the seam, put plus
+        fetched: two slabs each way at K steps on one chip; a plane each
+        in the one-step loop (inbox, ticks; StepOutput) and over the mesh
+        (those, route and rdelta; RoutePlan, residual occupancy)."""
+        if self._multi == 1:
+            return len(Inbox._fields) + 1 + len(StepOutput._fields)
+        if self._out_slabs is not None:
+            return 2 + 2
+        return (
+            len(Inbox._fields) + 3 + len(StepOutput._fields)
+            + len(RoutePlan._fields) + 1
+        )
+
     def _fetch_output(self, out) -> dict:
         """ONE consolidated device->host transfer for the whole StepOutput,
         shared by the overlap and non-overlap paths. The planes ship as a
@@ -2314,23 +2331,29 @@ class VectorEngine:
         note_seam_sync()  # runtime sync audit: the ONE blessed transfer
         return o
 
-    def _fetch_super(self, outs, plans, resid_count):
+    def _fetch_super(self, got):
         """The multi-step twin of _fetch_output: ONE consolidated
         device->host transfer for the whole K-step super-step (the
         stacked per-step StepOutput planes, the per-step route plans and
-        the residual-inbox occupancy ship together). This is the other
-        blessed sync seam — it fires once per K protocol steps."""
+        the residual-inbox occupancy ship together): on one chip the
+        launch's two slabs, cut here into views of those planes, over
+        the mesh the planes themselves. This is the other blessed sync
+        seam — it fires once per K protocol steps."""
         prof = self.profiler
         prof.begin("fetch")
         if prof.sampling:
             t0 = time.monotonic()
-            jax.block_until_ready(resid_count)  # ready with the rest
+            jax.block_until_ready(got[-1])  # ready with the rest
             t1 = time.monotonic()
-            o, pl, rc = jax.device_get((outs, plans, resid_count))
+            got = jax.device_get(got)
             self._add_seam("device_wait", "copy", t0, t1)
         else:
-            o, pl, rc = jax.device_get((outs, plans, resid_count))
+            got = jax.device_get(got)
         note_seam_sync()  # runtime sync audit: one transfer per K steps
+        if self._out_slabs is not None:
+            o, pl, occ = self._out_slabs.unpack(*got)
+            got = o, pl, occ[-1]
+        o, pl, rc = got
         return o._asdict(), pl._asdict(), np.array(rc, np.int32)
 
     def _decode_pending(self) -> Optional[dict]:

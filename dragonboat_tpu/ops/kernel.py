@@ -44,7 +44,10 @@ from .state import (
     RaftTensors,
     RoutePlan,
     StepOutput,
+    init_state,
+    make_empty_inbox,
 )
+from .slab import Slabs
 
 i32 = jnp.int32
 
@@ -1624,6 +1627,23 @@ def multi_step_batch(
 
     Returns (state, stacked per-step StepOutput, stacked per-step
     RoutePlan, residual Inbox, residual per-lane occupancy)."""
+    s, (outs, plans), resid_out = _scan_steps(
+        s, inbox, ticks, resid, route, rdelta, cfg, steps,
+        lambda out, plan, nxt: (out, plan),
+    )
+    return s, outs, plans, resid_out, _occupancy(resid_out)
+
+
+def _occupancy(inbox: Inbox) -> jax.Array:
+    """The rows each lane holds in an inbox."""
+    return jnp.sum(inbox.mtype != MSG.NONE, axis=1).astype(i32)
+
+
+def _scan_steps(s, inbox, ticks, resid, route, rdelta, cfg, steps, emit):
+    """multi_step_batch's scan: the residual merged under the host's
+    inbox, then ``steps`` routed steps, each handing ``emit(out, plan,
+    nxt)`` to the stacked result. Returns (state, stacked emits,
+    residual Inbox)."""
     occ = resid.mtype != MSG.NONE
 
     def mg(r, h):
@@ -1638,13 +1658,12 @@ def multi_step_batch(
         st, ibx, tks = carry
         st, out = step_batch(st, ibx, tks, cfg)
         nxt, plan = route_step_output(st, out, route, rdelta, cfg)
-        return (st, nxt, jnp.zeros_like(tks)), (out, plan)
+        return (st, nxt, jnp.zeros_like(tks)), emit(out, plan, nxt)
 
-    (s, resid_out, _), (outs, plans) = jax.lax.scan(
+    (s, resid_out, _), emitted = jax.lax.scan(
         body, (s, inbox0, ticks), None, length=steps
     )
-    resid_count = jnp.sum(resid_out.mtype != MSG.NONE, axis=1).astype(i32)
-    return s, outs, plans, resid_out, resid_count
+    return s, emitted, resid_out
 
 
 @functools.lru_cache(maxsize=None)
@@ -1656,6 +1675,89 @@ def make_multi_step_fn(cfg: KernelConfig, steps: int, donate: bool = True):
     K as a finding). Cached per (cfg, steps, donate)."""
     f = _named(
         functools.partial(multi_step_batch, cfg=cfg, steps=steps),
+        "multi_step_batch",
+    )
+    if donate:
+        return jax.jit(f, donate_argnums=(0, 3))
+    return jax.jit(f)
+
+
+def _launch_in_specs(cfg: KernelConfig):
+    G, P = cfg.groups, cfg.peers
+    gp = jax.ShapeDtypeStruct((G, P), i32)
+    return (
+        jax.eval_shape(lambda: make_empty_inbox(cfg)),
+        jax.ShapeDtypeStruct((G,), i32), gp, gp,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def launch_in_slabs(cfg: KernelConfig) -> Slabs:
+    """What the host puts for a packed K-step launch: (inbox, ticks,
+    route, rdelta) as one int32 and one bool slab of lanes by columns."""
+    return Slabs(_launch_in_specs(cfg), lead=1)
+
+
+def _step_fetched(out, plan, nxt):
+    """What the host fetches of one inner step of a packed launch: its
+    StepOutput, its RoutePlan and the residual occupancy after it (the
+    last step's is the launch's)."""
+    return out, plan, _occupancy(nxt)
+
+
+@functools.lru_cache(maxsize=None)
+def launch_out_slabs(cfg: KernelConfig) -> Slabs:
+    """What the host fetches from a packed K-step launch: each inner
+    step's `_step_fetched` as one flat int32 and one flat bool row, so
+    the launch's slabs are [K, columns] and a step's plane is contiguous
+    on the host. Shaped by tracing one step abstractly."""
+    inbox, ticks, route, rdelta = _launch_in_specs(cfg)
+    _s, fetched, _r = jax.eval_shape(
+        functools.partial(
+            _scan_steps, cfg=cfg, steps=1, emit=_step_fetched
+        ),
+        jax.eval_shape(lambda: init_state(cfg)),
+        inbox, ticks, inbox, route, rdelta,
+    )
+    return Slabs(jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), fetched
+    ))
+
+
+def packed_multi_step_batch(
+    s: RaftTensors,
+    ints: jax.Array,
+    bools: jax.Array,
+    resid: Inbox,
+    cfg: KernelConfig,
+    steps: int,
+):
+    """multi_step_batch with the host's planes put as two slabs and its
+    outputs fetched as two (ops/slab.py), inside this one program: the
+    slabs are cut into the inbox, ticks, route and rdelta at the top,
+    and each inner step's outputs are packed into its row of the output
+    slabs as the scan emits them, so no plane is copied after the scan.
+    State and residual inbox stay device-resident planes. Returns
+    (state, int32 slab, bool slab, residual Inbox)."""
+    inbox, ticks, route, rdelta = launch_in_slabs(cfg).unpack(ints, bools)
+    rows = launch_out_slabs(cfg)
+    s, (out_ints, out_bools), resid = _scan_steps(
+        s, inbox, ticks, resid, route, rdelta, cfg, steps,
+        lambda out, plan, nxt: rows.pack(_step_fetched(out, plan, nxt)),
+    )
+    return s, out_ints, out_bools, resid
+
+
+@functools.lru_cache(maxsize=None)
+def make_packed_multi_step_fn(
+    cfg: KernelConfig, steps: int, donate: bool = True
+):
+    """Jitted packed_multi_step_batch(state, ints, bools, resid) ->
+    (state, ints, bools, resid), the one-chip K-step launch. It keeps
+    multi_step_batch's program name, so a device trace finds the same
+    program. Cached per (cfg, steps, donate)."""
+    f = _named(
+        functools.partial(packed_multi_step_batch, cfg=cfg, steps=steps),
         "multi_step_batch",
     )
     if donate:

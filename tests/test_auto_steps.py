@@ -294,12 +294,14 @@ class _Launches:
             self.one_step_over_parked += 1
         return self._step(*a)
 
-    def multi(self, state, inbox, ticks, resid, route, rdelta):
+    def multi(self, state, ints, bools, resid):
+        # the route table rides the launch's slabs: the host plane is
+        # the view of what this launch put
         self.by_steps[3] += 1
         parked = int(self.core._m_resid.sum())
-        if parked and not (np.asarray(route) >= 0).any():
+        if parked and not (self.core._np_route >= 0).any():
             self.drains.append(parked)
-        return self._multi(state, inbox, ticks, resid, route, rdelta)
+        return self._multi(state, ints, bools, resid)
 
 
 @pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "plain"])

@@ -71,11 +71,12 @@ FAULTED = ("fleet1024x5.drops", "fleet1024x5.churn")
 def _check_save_parts(cell, metrics):
     """What `save` is made of and the progress watch (ISSUE 37): every
     one of the sixteen a number (and `engine.steps_per_launch`, ISSUE
-    35's), nothing shed, no stall where there is no fault, and the six
-    parts together the `save` span but a twentieth."""
+    35's, and `seam.buffers_per_launch`, the arrays a launch moves),
+    nothing shed, no stall where there is no fault, and the six parts
+    together the `save` span but a twentieth."""
     from benchmark.run import load_cell
 
-    assert len(PER_LAUNCH) == 17 and set(STALLS) < set(PER_LAUNCH)
+    assert len(PER_LAUNCH) == 18 and set(STALLS) < set(PER_LAUNCH)
     for name in PER_LAUNCH:
         assert isinstance(metrics[name]["value"], (int, float)), name
     assert metrics["run.spans_dropped"]["value"] == 0
