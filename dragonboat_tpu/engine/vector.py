@@ -679,6 +679,7 @@ class _Lane:
         "cc_inflight",
         "mem_sig",
         "wit_slots",
+        "commit_seen",
     )
 
     def __init__(self, g: int, node: VectorNode, key=None) -> None:
@@ -726,6 +727,9 @@ class _Lane:
         # cf. raft.go:742-756) at every host sender site. Maintained by
         # the same three reconcile paths that maintain mem_sig.
         self.wit_slots: frozenset = frozenset()
+        # the real commit index a sampled pack last saw while this lane
+        # led (n.hot_commit_entries); None until one has
+        self.commit_seen: Optional[int] = None
 
     # ------------------------------------------------------- slot mapping
     def set_slots(self, member_ids) -> Dict[int, int]:
@@ -1245,13 +1249,23 @@ class VectorEngine:
             ),
             readindex_depth=ecfg.readindex_depth if ecfg else 4,
         )
-        if self.kcfg.max_entries_per_msg > self.kcfg.log_window:
+        E, W = self.kcfg.max_entries_per_msg, self.kcfg.log_window
+        if E >= W:
             # the kernel's ring-slot scatter maps each written index to a
-            # unique slot only while a message's span fits the window
+            # unique slot only while a message's span fits the window, and
+            # a follower's pack takes a Replicate only into the room its
+            # window has, W - 1 at most (a slot is kept for a new leader's
+            # noop): a row of W entries would wait there for ever
             raise ValueError(
-                f"max_entries_per_msg ({self.kcfg.max_entries_per_msg}) must "
-                f"not exceed log_window ({self.kcfg.log_window})"
+                f"max_entries_per_msg ({E}) must be below log_window ({W})"
             )
+        # a device window compacts once it is half full, or once it has
+        # no room for a whole row of E entries where that comes first
+        # (E of half a window or more): a follower's pack holds a
+        # Replicate that does not fit until compaction makes room, so
+        # waiting for half full alone left a follower served from the
+        # host log behind for good
+        self._compact_mark = min(W // 2, W - 1 - E)
         # multi-device: shard the group axis over every visible device
         # (SURVEY §2.9.1 — groups are independent Raft instances, so the
         # kernel partitions along G with zero collectives on the hot path)
@@ -2440,6 +2454,9 @@ class VectorEngine:
         # iterations' n.* counters (plain local ints; folded at the end)
         counting = self.profiler.sampling
         n_lanes = n_ents = n_hot = n_cut = n_reads = n_ctxs = 0
+        # the fullest leader lane: (last - commit, commit moved since its
+        # last sampled pack), the most of the first, then of the second
+        hot_wait = hot_moved = 0
         # per-lane mirror reads gathered ONCE as columns (per-element
         # int(arr[g]) reads were a measured hot spot at fleet widths)
         work = list(lanes)
@@ -2451,6 +2468,7 @@ class VectorEngine:
                 self._m_role[w_gs].tolist(),
                 self._m_leader[w_gs].tolist(),
                 self._m_last[w_gs].tolist(),
+                self._m_commit[w_gs].tolist(),
                 self._m_devfirst[w_gs].tolist(),
                 self._m_base[w_gs].tolist(),
                 # multi-step: device-routed residual messages occupy the
@@ -2461,7 +2479,8 @@ class VectorEngine:
         else:
             cols = ()
         for (
-            lane, g_quiesced, g_role, g_leader, g_last, g_devfirst, b, g_resid,
+            lane, g_quiesced, g_role, g_leader, g_last, g_commit, g_devfirst,
+            b, g_resid,
         ) in cols:
             node = lane.node
             g = lane.g
@@ -2521,6 +2540,15 @@ class VectorEngine:
             # PR 26). What waits among the wire messages is made
             # cumulative first, so it never piles up.
             is_leader = g_role == ROLE.LEADER
+            if counting and is_leader:
+                seen = b + g_commit
+                moved = 0
+                if lane.commit_seen is not None:
+                    moved = max(seen - lane.commit_seen, 0)
+                lane.commit_seen = seen
+                wait = g_last - g_commit
+                if wait > hot_wait or (wait == hot_wait and moved > hot_moved):
+                    hot_wait, hot_moved = wait, moved
             wire_end = K
             if is_leader and len(lane.msg_backlog) + k > K - 2:
                 own = bool(lane.staged_reads) + bool(
@@ -2733,6 +2761,8 @@ class VectorEngine:
             fold("n.hot_lane_entries", n_hot)
             fold("n.lanes_window_cut", n_cut)
             fold("n.staged_left", backlog)
+            fold("n.hot_commit_entries", hot_moved)
+            fold("n.hot_uncommitted_rows", -(-hot_wait // E))
             fold("n.reads_bound", n_reads)
             fold("n.read_contexts", n_ctxs)
         self._flush_staged_rows()
@@ -4107,7 +4137,6 @@ class VectorEngine:
 
     # --------------------------------------------------------- maintenance
     def _maintain(self, o) -> None:
-        W = self.kcfg.log_window
         lane_by_g = self._lane_by_g
         # the host-log catch-up sweep, timed on sampled iterations as the
         # sub-span `catchup` of `maintain`
@@ -4181,10 +4210,11 @@ class VectorEngine:
 
                 lane.node.push_take_snapshot_request(SSRequest())
         # device window compaction: advance first_index once the window is
-        # half full; applied entries are recoverable from the host log
+        # past its mark (_compact_mark: half full, or too full for a whole
+        # row); applied entries are recoverable from the host log
         # (catchup path) or a snapshot, so the device needs neither
         used = o["last_index"].astype(np.int64) - self._m_devfirst + 1
-        compact_due = self._m_active & ((used > W // 2) | log_full)
+        compact_due = self._m_active & ((used > self._compact_mark) | log_full)
         adv_mask = np.zeros(self.kcfg.groups, bool)
         adv_first = np.zeros(self.kcfg.groups, np.int32)
         adv_term = np.zeros(self.kcfg.groups, np.int32)

@@ -72,7 +72,7 @@ def test_every_new_metric_is_declared_for_the_cell_alone():
         assert m["workloads"][-1] == CELL
     cell = next(w for w in spec["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "drops128.closed64")
-    assert len(spec["workloads"]) == 9
+    assert len(spec["workloads"]) == 10
     assert sum(w["chips"] == 4 for w in spec["workloads"]) == 1
 
 

@@ -697,6 +697,9 @@ def test_pack_counters(case, stopped_leader):
     core.profiler.sampling = True
     had, _packs = _pack_with(core, lane, staged, free)
     s = core.profiler.samples
+    # the one leader lane is the fullest: what it holds uncommitted, in
+    # rows of E = 8; its commit has moved by nothing it was seen at before
+    waiting = int(core._m_last[lane.g] - core._m_commit[lane.g])
     if not packed:
         # nothing staged a row: no launch follows, nothing is recorded
         assert not had and not any(k.startswith("n.") for k in s)
@@ -712,6 +715,8 @@ def test_pack_counters(case, stopped_leader):
         "staged_left": (1, float(left)),
         "reads_bound": (1, 0.0),
         "read_contexts": (1, 0.0),
+        "hot_commit_entries": (1, 0.0),
+        "hot_uncommitted_rows": (1, float(-(-waiting // 8))),
     }
     assert len(lane.staged_props) == left
 
